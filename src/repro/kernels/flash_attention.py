@@ -9,6 +9,11 @@ Positions are block-index-derived (prefill layout: positions 0..S-1), causal
 and sliding-window masks are applied in-kernel; fully-masked kv blocks are
 skipped (pl.when), which is how the kernel keeps the long-context windowed
 archs sub-quadratic in *work*, not just memory.
+
+Block layout: the head axis is folded into the lane axis — q
+``(B, Sq, H * hd)``, k/v ``(B, Sk, KV * hd)`` — so one head's block is
+``(bq, hd)`` / ``(bk, hd)``, which meets Mosaic's rule that a block's last
+two dims are divisible by (8, 128) or equal the array's own.
 """
 from __future__ import annotations
 
@@ -51,9 +56,12 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
     # whole-block skip test (static per grid step under interpret; cheap on TPU)
     def in_range():
-        q = q_ref[0, :, 0, :].astype(jnp.float32) * scale          # (bq, hd)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)                  # (bk, hd)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)    # (bq, bk)
+        q = q_ref[...].astype(jnp.float32)                         # (bq, hd)
+        k = k_ref[...].astype(jnp.float32)                         # (bk, hd)
+        # dot-then-scale, as the reference does: the MXU takes bf16 q and k
+        # exactly, while a pre-scaled q would be rounded to bf16 on the way
+        # in (Mosaic's default f32 matmul)
+        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
         q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
         k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
         ok = jnp.ones((block_q, block_k), bool)
@@ -62,13 +70,13 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         if window > 0:
             ok &= (q_pos - k_pos) < window
         s = jnp.where(ok, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_ref[...]                                        # (bq, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + p.sum(axis=1)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + jnp.dot(
+        l_ref[...] = l_ref[...] * corr + p.sum(axis=1, keepdims=True)
+        v = v_ref[...].astype(jnp.float32)
+        acc_ref[...] = acc_ref[...] * corr + jnp.dot(
             p, v, preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
@@ -86,7 +94,20 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     @pl.when(ki == nk - 1)
     def _():
         denom = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc_ref[...] / denom[:, None]).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] / denom).astype(o_ref.dtype)
+
+
+def _specs(B, Sq, Sk, H, KV, hd, block_q, block_k):
+    """Grid and per-head block specs over the lane-folded layouts."""
+    G = H // KV
+    grid = (B, H, Sq // block_q, Sk // block_k)
+    q_spec = pl.BlockSpec((None, block_q, hd), lambda b, h, qi, ki: (b, qi, h))
+    kv_spec = pl.BlockSpec((None, block_k, hd),
+                           lambda b, h, qi, ki: (b, ki, h // G))
+    scratch = [pltpu.VMEM((block_q, 1), jnp.float32),
+               pltpu.VMEM((block_q, 1), jnp.float32),
+               pltpu.VMEM((block_q, hd), jnp.float32)]
+    return grid, q_spec, kv_spec, scratch
 
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
@@ -96,29 +117,25 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     B, Sq, H, hd = q.shape
     _, Sk, KV, _ = k.shape
     assert H % KV == 0
-    G = H // KV
     block_q = min(block_q, Sq)
     block_k = min(block_k, Sk)
     assert Sq % block_q == 0 and Sk % block_k == 0
-    grid = (B, H, Sq // block_q, Sk // block_k)
-    scale = 1.0 / math.sqrt(hd)
-    kernel = functools.partial(_kernel, scale=scale, causal=causal, window=window,
+    grid, q_spec, kv_spec, scratch = _specs(B, Sq, Sk, H, KV, hd,
+                                            block_q, block_k)
+    kernel = functools.partial(_kernel, scale=1.0 / math.sqrt(hd),
+                               causal=causal, window=window,
                                block_q=block_q, block_k=block_k)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, 1, hd), lambda b, h, qi, ki: (b, qi, h, 0)),
-            pl.BlockSpec((1, block_k, 1, hd), lambda b, h, qi, ki: (b, ki, h // G, 0)),
-            pl.BlockSpec((1, block_k, 1, hd), lambda b, h, qi, ki: (b, ki, h // G, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, 1, hd), lambda b, h, qi, ki: (b, qi, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Sq, H, hd), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q,), jnp.float32),
-                        pltpu.VMEM((block_q,), jnp.float32),
-                        pltpu.VMEM((block_q, hd), jnp.float32)],
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Sq, H * hd), q.dtype),
+        scratch_shapes=scratch,
         interpret=interpret,
-    )(q, k, v)
+    )(q.reshape(B, Sq, H * hd), k.reshape(B, Sk, KV * hd),
+      v.reshape(B, Sk, KV * hd))
+    return out.reshape(B, Sq, H, hd)
 
 
 def flash_attention_kv(q, k, v, *, causal: bool = True, window: int = 0,
@@ -143,11 +160,11 @@ def flash_attention_kv(q, k, v, *, causal: bool = True, window: int = 0,
     B, Sq, H, hd = q.shape
     _, Sk, KV, _ = k.shape
     assert H % KV == 0
-    G = H // KV
     block_q = min(block_q, Sq)
     block_k = min(block_k, Sk)
     assert Sq % block_q == 0 and Sk % block_k == 0
-    grid = (B, H, Sq // block_q, Sk // block_k)
+    grid, q_spec, kv_spec, scratch = _specs(B, Sq, Sk, H, KV, hd,
+                                            block_q, block_k)
     scale = 1.0 / math.sqrt(hd)
 
     def kernel(q_ref, k_ref, v_ref, o_ref, k_out_ref, v_out_ref,
@@ -156,26 +173,17 @@ def flash_attention_kv(q, k, v, *, causal: bool = True, window: int = 0,
                 scale=scale, causal=causal, window=window, block_q=block_q,
                 block_k=block_k, k_out_ref=k_out_ref, v_out_ref=v_out_ref)
 
-    kv_spec = pl.BlockSpec((1, block_k, 1, hd), lambda b, h, qi, ki: (b, ki, h // G, 0))
+    kv_shape = jax.ShapeDtypeStruct((B, Sk, KV * hd), k.dtype)
     o, k_out, v_out = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, 1, hd), lambda b, h, qi, ki: (b, qi, h, 0)),
-            kv_spec,
-            kv_spec,
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, 1, hd), lambda b, h, qi, ki: (b, qi, h, 0)),
-            kv_spec,
-            kv_spec,
-        ],
-        out_shape=[jax.ShapeDtypeStruct((B, Sq, H, hd), q.dtype),
-                   jax.ShapeDtypeStruct((B, Sk, KV, hd), k.dtype),
-                   jax.ShapeDtypeStruct((B, Sk, KV, hd), v.dtype)],
-        scratch_shapes=[pltpu.VMEM((block_q,), jnp.float32),
-                        pltpu.VMEM((block_q,), jnp.float32),
-                        pltpu.VMEM((block_q, hd), jnp.float32)],
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=[q_spec, kv_spec, kv_spec],
+        out_shape=[jax.ShapeDtypeStruct((B, Sq, H * hd), q.dtype),
+                   kv_shape, kv_shape],
+        scratch_shapes=scratch,
         interpret=interpret,
-    )(q, k, v)
-    return o, k_out, v_out
+    )(q.reshape(B, Sq, H * hd), k.reshape(B, Sk, KV * hd),
+      v.reshape(B, Sk, KV * hd))
+    return (o.reshape(B, Sq, H, hd), k_out.reshape(B, Sk, KV, hd),
+            v_out.reshape(B, Sk, KV, hd))

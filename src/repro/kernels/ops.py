@@ -1,9 +1,12 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-On CPU (this container) kernels execute with ``interpret=True`` — the kernel
-body runs as traced JAX ops so correctness is validated end-to-end; on TPU the
-same calls compile to Mosaic. Wrappers pad inputs to block multiples and crop,
-and fall back to the jnp oracle for degenerate shapes.
+Off the TPU kernels execute with ``interpret=True`` — the kernel body runs
+as traced JAX ops so correctness is validated end-to-end; on TPU the same
+calls compile to Mosaic. Wrappers pad inputs to block multiples and crop.
+Off the TPU, shapes a kernel does not take run the jnp oracle; on the TPU
+they raise, because a silent oracle there would report a kernel-less run
+as a kernel run. Which path serves a model is decided once, from shapes,
+where the engine is built (``attention_kernel_fits``).
 """
 from __future__ import annotations
 
@@ -22,6 +25,22 @@ from repro.kernels import ref as _ref
 
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
+
+
+def attention_kernel_fits(head_dim: int) -> bool:
+    """Can the per-head attention kernels (flash, paged) tile this head
+    width here? On the TPU a head is one block's lane axis, so it must be
+    a multiple of 128; interpret mode tiles any width."""
+    return _interpret() or head_dim % 128 == 0
+
+
+def _oracle(kernel: str, why: str):
+    """Gate every jnp-oracle substitution: allowed in interpret mode only.
+    On the TPU the caller asked for a kernel the shapes do not fit — an
+    engine-build or caller error, raised instead of hidden."""
+    if not _interpret():
+        raise ValueError(f"{kernel}: no TPU kernel for {why}; choose the "
+                         f"einsum path for these shapes instead")
 
 
 def _pad_to(x, mult, axis):
@@ -95,12 +114,14 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     (non-standard positions fall back to the oracle)."""
     B, Sq, H, hd = q.shape
     Sk = k.shape[1]
+    bq, bk = min(block_q, Sq), min(block_k, Sk)
     if q_positions is not None or k_positions is not None:
+        _oracle("flash_attention", "explicit positions")
         return _ref.flash_attention(q, k, v, causal=causal, window=window,
                                     q_positions=q_positions,
                                     k_positions=k_positions)
-    bq, bk = min(block_q, Sq), min(block_k, Sk)
-    if Sq % bq or Sk % bk:
+    if Sq % bq or Sk % bk or not attention_kernel_fits(hd):
+        _oracle("flash_attention", f"q {q.shape} / k {k.shape}")
         return _ref.flash_attention(q, k, v, causal=causal, window=window)
     return _fa.flash_attention(q, k, v, causal=causal, window=window,
                                block_q=bq, block_k=bk, interpret=_interpret())
@@ -113,12 +134,13 @@ def flash_prefill(q, k, v, *, causal: bool = True, window: int = 0,
     """K/V-exporting prefill attention: returns ``(O, K, V)`` where K/V are
     the post-RoPE tiles ready for the serving cache scatter (paged block
     tables or dense rows). On TPU the export rides the kernel's existing
-    VMEM residency (one fused HBM pass); non-block-multiple shapes fall back
-    to the jnp oracle so CPU CI always runs."""
+    VMEM residency (one fused HBM pass); non-block-multiple shapes run the
+    jnp oracle in interpret mode and raise on the TPU."""
     B, Sq, H, hd = q.shape
     Sk = k.shape[1]
     bq, bk = min(block_q, Sq), min(block_k, Sk)
-    if Sq % bq or Sk % bk:
+    if Sq % bq or Sk % bk or not attention_kernel_fits(hd):
+        _oracle("flash_prefill", f"q {q.shape} / k {k.shape}")
         return _ref.flash_attention_kv(q, k, v, causal=causal, window=window)
     return _fa.flash_attention_kv(q, k, v, causal=causal, window=window,
                                   block_q=bq, block_k=bk,
@@ -127,31 +149,25 @@ def flash_prefill(q, k, v, *, causal: bool = True, window: int = 0,
 
 # trace-size guard for the paged kernel: interpret mode inlines one kernel
 # body per grid step (B * H * mps), so an oversized grid would explode trace
-# time on CPU; on TPU the Mosaic grid is free but tiny tiles are not worth
-# steering through the MXU — both ends route to the jnp oracle
+# time off the TPU and routes to the jnp oracle (the Mosaic grid is free)
 _PAGED_MAX_INTERPRET_GRID = 4096
 
 
 def _paged_dispatch_local(q, pool_k, pool_v, block_tables, start, window: int,
                           k_scale=None, v_scale=None):
     """Single-device paged-attention dispatch (also the per-shard body under
-    the tp shard_map — the interpret-grid guard and oracle fallback then see
-    per-shard H, which is the point of passing this in whole)."""
+    the tp shard_map — the interpret-grid guard then sees per-shard H,
+    which is the point of passing this in whole)."""
     B, Sq, H, hd = q.shape
-    ps = pool_k.shape[1]
     mps = block_tables.shape[1]
     sc = dict(k_scale=k_scale, v_scale=v_scale)
-    if _interpret():
-        if B * H * mps > _PAGED_MAX_INTERPRET_GRID:
-            return _ref.paged_attention(q, pool_k, pool_v, block_tables,
-                                        start, window=window, **sc)
-        return _pa.paged_attention(q, pool_k, pool_v, block_tables, start,
-                                   window=window, interpret=True, **sc)
-    if hd % 128 or ps % 8:
-        return _ref.paged_attention(q, pool_k, pool_v, block_tables, start,
-                                    window=window, **sc)
+    if not attention_kernel_fits(hd):
+        _oracle("paged_attention", f"head_dim {hd}")
+    if _interpret() and B * H * mps > _PAGED_MAX_INTERPRET_GRID:
+        return _ref.paged_attention(q, pool_k, pool_v, block_tables,
+                                    start, window=window, **sc)
     return _pa.paged_attention(q, pool_k, pool_v, block_tables, start,
-                               window=window, interpret=False, **sc)
+                               window=window, interpret=_interpret(), **sc)
 
 
 def _squeeze_scale(s):
@@ -242,25 +258,17 @@ def paged_prefill_q8(q, pool_k, pool_v, k_scale, v_scale, block_tables,
 
 def _paged_dispatch_latent(q, pool_c, block_tables, start, scale_dim: int,
                            d_v: int):
-    """MLA latent-page dispatch: same guard ladder as the per-head paged
-    dispatch, but over the single shared latent pool."""
+    """MLA latent-page dispatch: same interpret-grid guard as the per-head
+    paged dispatch, over the single shared latent pool. The latent kernel
+    takes whole latent rows, so every width tiles on the TPU."""
     B, Sq, H, L = q.shape
-    ps = pool_c.shape[1]
     mps = block_tables.shape[1]
-    if _interpret():
-        if B * H * mps > _PAGED_MAX_INTERPRET_GRID:
-            return _ref.paged_attention_latent(q, pool_c, block_tables,
-                                               start, scale_dim=scale_dim,
-                                               d_v=d_v)
-        return _pa.paged_attention_latent(q, pool_c, block_tables, start,
-                                          scale_dim=scale_dim, d_v=d_v,
-                                          interpret=True)
-    if L % 128 or d_v % 128 or ps % 8:
+    if _interpret() and B * H * mps > _PAGED_MAX_INTERPRET_GRID:
         return _ref.paged_attention_latent(q, pool_c, block_tables, start,
                                            scale_dim=scale_dim, d_v=d_v)
     return _pa.paged_attention_latent(q, pool_c, block_tables, start,
                                       scale_dim=scale_dim, d_v=d_v,
-                                      interpret=False)
+                                      interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("scale_dim", "d_v", "mesh",
@@ -308,6 +316,7 @@ def wkv6(r, k, v, w, u, s0, *, chunk: int = 32):
     T = r.shape[1]
     c = min(chunk, T)
     if T % c:
+        _oracle("wkv6", f"T={T} with chunk {c}")
         return _ref.wkv6(r, k, v, w, u, s0)
     y, sT = _ls.wkv6(r, k, v, w, u, s0, chunk=c, interpret=_interpret())
     return y, sT
@@ -318,5 +327,6 @@ def selective_scan(x, dt, b, c, a, h0, *, chunk: int = 64):
     T = x.shape[1]
     ck = min(chunk, T)
     if T % ck:
+        _oracle("selective_scan", f"T={T} with chunk {ck}")
         return _ref.selective_scan(x, dt, b, c, a, h0)
     return _ls.selective_scan(x, dt, b, c, a, h0, chunk=ck, interpret=_interpret())

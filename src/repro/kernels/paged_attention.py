@@ -35,6 +35,16 @@ accumulators (m, l, acc) persist in VMEM scratch across a slot's pages —
 the paper's "accumulators in on-chip RAM" structure, same as the flash
 kernel. GQA shares each K/V block across ``H // KV`` query heads via the
 ``h // G`` index map.
+
+Block layout (Mosaic's rule: the last two dims of every block are
+divisible by (8, 128) or equal the array's own). The head axis is folded
+into the lane axis — q ``(B, Sq, H * hd)``, pools ``(P, ps, KV * hd)`` —
+so one head's block is ``(Sq, hd)`` / ``(ps, hd)``: Sq and ps are whole
+array dims and hd is a lane multiple. The fold is a reshape, which for a
+bf16 pool on the TPU's tiled layout compiles to a relayout copy. The latent pool
+has no head axis (``(P, ps, c + r)``, a whole-row block at any width), and
+its absorbed queries are moved head-major, ``(B, H, Sq, c + r)``, because
+c + r (576 for qwen2.5-32b-mla) is not a lane multiple.
 """
 from __future__ import annotations
 
@@ -49,9 +59,24 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30   # f32 scratch sentinel (never materialized in low precision)
 
 
-def _kernel(bt_ref, start_ref, q_ref, k_ref, v_ref, o_ref,
-            m_ref, l_ref, acc_ref, *, scale: float, window: int,
-            block_q: int, page_size: int):
+def _kernel(*refs, scale: float, window: int, block_q: int, page_size: int,
+            quantized: bool, d_v: int):
+    """One (slot b, head h, page j) step of the online softmax. Operand
+    order follows the pallas_call: scalar prefetch (block table, starts[,
+    k/v page scales]), then q, k[, v], out, then the m/l/acc scratch.
+
+    ``quantized``: int8 pools, DEQUANTIZED in-register right after the DMA
+    with the page's symmetric scale (a scalar-prefetch operand), so HBM
+    only ever moves int8 payload. ``d_v > 0``: MLA latent pages — there is
+    no v pool; values are the leading ``d_v`` columns of the same latent
+    rows, so each page is DMA'd once for both roles."""
+    if quantized:
+        bt_ref, start_ref, ks_ref, vs_ref, q_ref, k_ref, v_ref, o_ref = refs[:8]
+    elif d_v:
+        bt_ref, start_ref, q_ref, k_ref, o_ref = refs[:5]
+    else:
+        bt_ref, start_ref, q_ref, k_ref, v_ref, o_ref = refs[:6]
+    m_ref, l_ref, acc_ref = refs[-3:]
     b = pl.program_id(0)
     j = pl.program_id(2)
     nj = pl.num_programs(2)
@@ -67,8 +92,10 @@ def _kernel(bt_ref, start_ref, q_ref, k_ref, v_ref, o_ref,
     k_start = j * page_size
 
     def visit():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)              # (bq, hd)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)              # (ps, hd)
+        q = q_ref[...].astype(jnp.float32)                     # (bq, hd)
+        k = k_ref[...].astype(jnp.float32)                     # (ps, hd)
+        if quantized:
+            k = k * ks_ref[jnp.maximum(page, 0)]
         # dot-then-scale in f32: the same operation order as the masked-
         # einsum reference, so the degenerate one-page config stays
         # numerically aligned with it
@@ -81,16 +108,21 @@ def _kernel(bt_ref, start_ref, q_ref, k_ref, v_ref, o_ref,
         if window > 0:
             ok &= k_pos > q_pos - window
         s = jnp.where(ok, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
+        m_prev = m_ref[...]                                    # (bq, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
         # explicit zeroing, not exp(sentinel): a row fully masked in THIS
         # page while m is still NEG_INF would otherwise turn exp(0) == 1
         # into garbage mass from rows it may never attend
-        p = jnp.where(ok, jnp.exp(s - m_new[:, None]), 0.0)
+        p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
         corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + p.sum(axis=1)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + jnp.dot(
+        l_ref[...] = l_ref[...] * corr + p.sum(axis=1, keepdims=True)
+        if d_v:
+            v = k[:, :d_v]
+        else:
+            v = v_ref[...].astype(jnp.float32)
+            if quantized:
+                v = v * vs_ref[jnp.maximum(page, 0)]
+        acc_ref[...] = acc_ref[...] * corr + jnp.dot(
             p, v, preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
@@ -106,117 +138,16 @@ def _kernel(bt_ref, start_ref, q_ref, k_ref, v_ref, o_ref,
         # l == 0 (no valid key anywhere — freed slot, all pages skipped)
         # yields exactly 0, matching the reference oracle
         denom = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc_ref[...] / denom[:, None]).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
-def _kernel_q8(bt_ref, start_ref, ks_ref, vs_ref, q_ref, k_ref, v_ref, o_ref,
-               m_ref, l_ref, acc_ref, *, scale: float, window: int,
-               block_q: int, page_size: int):
-    """Int8-page variant: identical online softmax, but the gathered K/V
-    block is DEQUANTIZED in-register right after the DMA — the page's
-    symmetric scale rides in as a scalar-prefetch operand, so HBM only ever
-    moves int8 payload (the ~4x KV-bandwidth win) and no fp32 page is
-    materialized outside VMEM."""
-    b = pl.program_id(0)
-    j = pl.program_id(2)
-    nj = pl.num_programs(2)
-
-    @pl.when(j == 0)
-    def _():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    page = bt_ref[b, j]
-    start = start_ref[b]
-    k_start = j * page_size
-    pg = jnp.maximum(page, 0)
-
-    def visit():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)              # (bq, hd)
-        k = k_ref[0, :, 0, :].astype(jnp.float32) * ks_ref[pg]  # (ps, hd)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        q_pos = start + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, page_size), 0)
-        k_pos = k_start + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, page_size), 1)
-        ok = k_pos <= q_pos
-        if window > 0:
-            ok &= k_pos > q_pos - window
-        s = jnp.where(ok, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
-        p = jnp.where(ok, jnp.exp(s - m_new[:, None]), 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + p.sum(axis=1)
-        v = v_ref[0, :, 0, :].astype(jnp.float32) * vs_ref[pg]
-        acc_ref[...] = acc_ref[...] * corr[:, None] + jnp.dot(
-            p, v, preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
-
-    relevant = (page >= 0) & (k_start <= start + block_q - 1)
-    if window > 0:
-        relevant &= (k_start + page_size - 1) > (start - window)
-    pl.when(relevant)(visit)
-
-    @pl.when(j == nj - 1)
-    def _():
-        denom = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc_ref[...] / denom[:, None]).astype(o_ref.dtype)
-
-
-def _kernel_latent(bt_ref, start_ref, q_ref, k_ref, o_ref,
-                   m_ref, l_ref, acc_ref, *, scale: float,
-                   block_q: int, page_size: int, d_v: int):
-    """MLA latent-page variant: each gathered block is ``(page_size,
-    c_kv + r)`` — one compressed latent row per token, shared by ALL query
-    heads (the absorb path pushed the per-head projections into the query
-    and output einsums). Scores contract the FULL latent row; the value
-    contribution reuses the leading ``d_v`` (= c_kv) columns of the SAME
-    rows, so each page is DMA'd exactly once for both roles — the
-    bandwidth shape MLA exists to buy."""
-    b = pl.program_id(0)
-    j = pl.program_id(2)
-    nj = pl.num_programs(2)
-
-    @pl.when(j == 0)
-    def _():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    page = bt_ref[b, j]
-    start = start_ref[b]
-    k_start = j * page_size
-
-    def visit():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)              # (bq, c+r)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)              # (ps, c+r)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        q_pos = start + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, page_size), 0)
-        k_pos = k_start + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, page_size), 1)
-        ok = k_pos <= q_pos
-        s = jnp.where(ok, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
-        p = jnp.where(ok, jnp.exp(s - m_new[:, None]), 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + p.sum(axis=1)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + jnp.dot(
-            p, k[:, :d_v], preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
-
-    # MLA is full-causal only (no sliding window): skip unallocated pages
-    # and pages wholly beyond the last query row's causal frontier
-    relevant = (page >= 0) & (k_start <= start + block_q - 1)
-    pl.when(relevant)(visit)
-
-    @pl.when(j == nj - 1)
-    def _():
-        denom = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc_ref[...] / denom[:, None]).astype(o_ref.dtype)
+def _scratch(rows: int, width: int):
+    """Online-softmax state: running max and denominator as (rows, 1)
+    columns (2-D, so they tile like every other VMEM operand), and the f32
+    accumulator."""
+    return [pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, width), jnp.float32)]
 
 
 def paged_attention_latent(q, pool_c, block_tables, start, *,
@@ -236,30 +167,29 @@ def paged_attention_latent(q, pool_c, block_tables, start, *,
     P, ps, KV, _ = pool_c.shape
     assert KV == 1, "latent pool carries one shared row per token"
     mps = block_tables.shape[1]
-    scale = 1.0 / math.sqrt(scale_dim)
-    kernel = functools.partial(_kernel_latent, scale=scale,
-                               block_q=Sq, page_size=ps, d_v=d_v)
+    kernel = functools.partial(_kernel, scale=1.0 / math.sqrt(scale_dim),
+                               window=0, block_q=Sq, page_size=ps,
+                               quantized=False, d_v=d_v)
     # one shared latent block per (slot, page) step — every query head h
-    # reads kv head 0 of the page named by the prefetched block table
-    kv_map = lambda b, h, j, bt, st: (jnp.maximum(bt[b, j], 0), 0, 0, 0)
-    q_map = lambda b, h, j, bt, st: (b, 0, h, 0)
+    # reads the page named by the prefetched block table
+    kv_map = lambda b, h, j, bt, st: (jnp.maximum(bt[b, j], 0), 0, 0)
+    q_map = lambda b, h, j, bt, st: (b, h, 0, 0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, H, mps),
-        in_specs=[pl.BlockSpec((1, Sq, 1, L), q_map),
-                  pl.BlockSpec((1, ps, 1, L), kv_map)],
-        out_specs=pl.BlockSpec((1, Sq, 1, d_v), q_map),
-        scratch_shapes=[pltpu.VMEM((Sq,), jnp.float32),
-                        pltpu.VMEM((Sq,), jnp.float32),
-                        pltpu.VMEM((Sq, d_v), jnp.float32)],
+        in_specs=[pl.BlockSpec((None, None, Sq, L), q_map),
+                  pl.BlockSpec((None, ps, L), kv_map)],
+        out_specs=pl.BlockSpec((None, None, Sq, d_v), q_map),
+        scratch_shapes=_scratch(Sq, d_v),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Sq, H, d_v), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, Sq, d_v), q.dtype),
         interpret=interpret,
     )(jnp.asarray(block_tables, jnp.int32), jnp.asarray(start, jnp.int32),
-      q, pool_c)
+      q.transpose(0, 2, 1, 3), pool_c.reshape(P, ps, L))
+    return out.transpose(0, 2, 1, 3)
 
 
 def paged_attention(q, pool_k, pool_v, block_tables, start, *,
@@ -272,57 +202,47 @@ def paged_attention(q, pool_k, pool_v, block_tables, start, *,
     at offset ``r % ps``). Returns (B, Sq, H, hd) in q.dtype.
 
     k_scale/v_scale: optional (P,) f32 per-page symmetric scales for int8
-    pools; when given, the q8 kernel dequantizes each gathered page inside
-    the kernel body (scales prefetched to SMEM alongside the block table)."""
+    pools; when given, the kernel dequantizes each gathered page inside
+    its body (scales prefetched to SMEM alongside the block table)."""
     B, Sq, H, hd = q.shape
     P, ps, KV, _ = pool_k.shape
     assert H % KV == 0
     G = H // KV
     mps = block_tables.shape[1]
-    scale = 1.0 / math.sqrt(hd)
     quantized = k_scale is not None
-    kern = _kernel_q8 if quantized else _kernel
-    kernel = functools.partial(kern, scale=scale, window=window,
-                               block_q=Sq, page_size=ps)
+    kernel = functools.partial(_kernel, scale=1.0 / math.sqrt(hd),
+                               window=window, block_q=Sq, page_size=ps,
+                               quantized=quantized, d_v=0)
     # the kv index maps read the PREFETCHED block table: the page a grid
     # step streams is data-dependent (clamped at 0 for unallocated slots —
     # the body skips those steps entirely, the clamp only keeps the
     # prefetch in bounds). Scalar-prefetch operands land FIRST in the
     # kernel signature and as trailing index-map params; the q8 path adds
     # the two scale tables after (bt, start).
+    prefetch = [jnp.asarray(block_tables, jnp.int32),
+                jnp.asarray(start, jnp.int32)]
     if quantized:
-        kv_map = lambda b, h, j, bt, st, ks, vs: (
-            jnp.maximum(bt[b, j], 0), 0, h // G, 0)
-        q_map = lambda b, h, j, bt, st, ks, vs: (b, 0, h, 0)
-        num_prefetch = 4
-        prefetch = (jnp.asarray(block_tables, jnp.int32),
-                    jnp.asarray(start, jnp.int32),
-                    jnp.asarray(k_scale, jnp.float32),
-                    jnp.asarray(v_scale, jnp.float32))
-    else:
-        kv_map = lambda b, h, j, bt, st: (jnp.maximum(bt[b, j], 0), 0,
-                                          h // G, 0)
-        q_map = lambda b, h, j, bt, st: (b, 0, h, 0)
-        num_prefetch = 2
-        prefetch = (jnp.asarray(block_tables, jnp.int32),
-                    jnp.asarray(start, jnp.int32))
-    kv_spec = pl.BlockSpec((1, ps, 1, hd), kv_map)
-    q_spec = pl.BlockSpec((1, Sq, 1, hd), q_map)
+        prefetch += [jnp.asarray(k_scale, jnp.float32),
+                     jnp.asarray(v_scale, jnp.float32)]
+    kv_map = lambda b, h, j, bt, *_: (jnp.maximum(bt[b, j], 0), 0, h // G)
+    q_map = lambda b, h, j, *_: (b, 0, h)
+    kv_spec = pl.BlockSpec((None, ps, hd), kv_map)
+    q_spec = pl.BlockSpec((None, Sq, hd), q_map)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=num_prefetch,
+        num_scalar_prefetch=len(prefetch),
         grid=(B, H, mps),
         in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=q_spec,
-        scratch_shapes=[pltpu.VMEM((Sq,), jnp.float32),
-                        pltpu.VMEM((Sq,), jnp.float32),
-                        pltpu.VMEM((Sq, hd), jnp.float32)],
+        scratch_shapes=_scratch(Sq, hd),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Sq, H, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Sq, H * hd), q.dtype),
         interpret=interpret,
-    )(*prefetch, q, pool_k, pool_v)
+    )(*prefetch, q.reshape(B, Sq, H * hd), pool_k.reshape(P, ps, KV * hd),
+      pool_v.reshape(P, ps, KV * hd))
+    return out.reshape(B, Sq, H, hd)
 
 
 def paged_attention_head_sharded(dispatch, mesh, axis, q, pool_k, pool_v,
@@ -351,7 +271,6 @@ def paged_attention_head_sharded(dispatch, mesh, axis, q, pool_k, pool_v,
     and the einsum oracle fallback see per-shard grid sizes). The caller
     guarantees the axis size divides both H and KV on whole-GQA-group
     boundaries (see sharding.specs.head_shard_axis)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as SP
 
     heads = SP(None, None, axis, None)    # q/out (B,Sq,H,hd); pools (P,ps,KV,hd)
@@ -363,18 +282,18 @@ def paged_attention_head_sharded(dispatch, mesh, axis, q, pool_k, pool_v,
         def body(q_, pk_, pv_, bt_, st_, ks_, vs_):
             return dispatch(q_, pk_, pv_, bt_, st_, window,
                             k_scale=ks_[:, 0], v_scale=vs_[:, 0])
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(heads, heads, heads, repl2, repl1, scales, scales),
-            out_specs=heads, check_rep=False,
+            out_specs=heads, check_vma=False,
         )(q, pool_k, pool_v, block_tables, start, k_scale, v_scale)
 
     def body(q_, pk_, pv_, bt_, st_):
         return dispatch(q_, pk_, pv_, bt_, st_, window)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(heads, heads, heads, repl2, repl1),
-        out_specs=heads, check_rep=False,
+        out_specs=heads, check_vma=False,
     )(q, pool_k, pool_v, block_tables, start)
 
 
@@ -397,7 +316,6 @@ def paged_attention_latent_head_sharded(dispatch, mesh, axis, q, pool_c,
     (``ops._paged_dispatch_latent`` — passed in so the interpret-grid guard
     sees per-shard H). The caller guarantees the axis size divides H
     (sharding.specs.latent_head_shard_axis)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as SP
 
     heads = SP(None, None, axis, None)    # q (B,Sq,H,c+r) / out (B,Sq,H,d_v)
@@ -407,8 +325,8 @@ def paged_attention_latent_head_sharded(dispatch, mesh, axis, q, pool_c,
 
     def body(q_, pc_, bt_, st_):
         return dispatch(q_, pc_, bt_, st_, scale_dim, d_v)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(heads, repl4, repl2, repl1),
-        out_specs=heads, check_rep=False,
+        out_specs=heads, check_vma=False,
     )(q, pool_c, block_tables, start)

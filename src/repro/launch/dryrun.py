@@ -102,8 +102,8 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool, with_probes: bool,
     shape = configs.get_shape(shape_name)
     model = get_model(cfg)
     if mesh_shape:
-        mesh = jax.make_mesh(tuple(mesh_shape),
-                             ("pod", "data", "model")[-len(mesh_shape):])
+        mesh = specs.make_mesh(mesh_shape,
+                               ("pod", "data", "model")[-len(mesh_shape):])
         mesh_name = "pod" + "x".join(map(str, mesh_shape))
     else:
         mesh = make_production_mesh(multi_pod=multi_pod)

@@ -10,18 +10,13 @@ shapes from the TPU runtime.
 """
 from __future__ import annotations
 
-import jax
+from repro.sharding.specs import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_mesh(shape, axes):
-    """Arbitrary mesh for tests/elastic re-sharding (e.g. (2,2) on 4 CPUs)."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    return make_mesh(shape, axes)
 
 
 def describe(mesh) -> str:

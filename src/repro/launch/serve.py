@@ -166,7 +166,9 @@ class LegacyServer:
         return slot
 
     def _step(self):
-        batch = {"token": jnp.asarray(self.cur_token)}
+        # a copy: on the CPU jnp.asarray may alias cur_token, which the
+        # prefill loop rewrites before this async dispatch has read it
+        batch = {"token": jnp.array(self.cur_token)}
         if self.cfg.cross_attn_every:
             batch["image_embeds"] = jnp.zeros(
                 (self.sc.batch_slots, self.cfg.num_image_tokens, self.cfg.d_model),
@@ -237,6 +239,8 @@ def main():
             ap.add_argument(name, type=type(f.default), default=f.default)
     ap.add_argument("--json", action="store_true", help="print full metrics")
     args = ap.parse_args()
+    from repro.runtime.compile_cache import setup_compile_cache
+    setup_compile_cache()
     sc = ServeConfig(**{f.name: getattr(args, f.name)
                         for f in dataclasses.fields(ServeConfig)})
     stats = run(sc)

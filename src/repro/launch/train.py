@@ -80,8 +80,8 @@ def train(tc: TrainConfig) -> dict:
 
     mesh = None
     if tc.mesh_shape:
-        mesh = jax.make_mesh(tuple(tc.mesh_shape),
-                             ("data", "model")[: len(tc.mesh_shape)])
+        mesh = specs.make_mesh(tc.mesh_shape,
+                               ("data", "model")[: len(tc.mesh_shape)])
 
     mgr = CheckpointManager(tc.ckpt_dir) if tc.ckpt_dir else None
     detector = StragglerDetector()

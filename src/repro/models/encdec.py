@@ -64,7 +64,7 @@ def init(cfg: ArchConfig, key):
     dec_keys = jax.random.split(ks[1], cfg.num_layers)
     return {
         "embed": L.embed_init(ks[2], cfg.padded_vocab, cfg.d_model),
-        "pos_dec": jax.random.normal(ks[3], (8192, cfg.d_model), jnp.float32) * 0.01,
+        "pos_dec": L.scaled_normal(ks[3], (8192, cfg.d_model), 0.01),
         "enc_layers": jax.vmap(lambda kk: _enc_layer_init(kk, cfg))(enc_keys),
         "enc_norm": L.norm_init(cfg.d_model, "layernorm"),
         "dec_layers": jax.vmap(lambda kk: _dec_layer_init(kk, cfg))(dec_keys),

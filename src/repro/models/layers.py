@@ -82,10 +82,38 @@ def apply_rope(x, positions, theta: float):
 
 
 # ---------------------------------------------------------------- dense init
+def scaled_normal(key, shape, std, dtype=jnp.float32):
+    """``std`` times a standard normal draw, with the same bits inside a
+    jit as eagerly: the barrier stops XLA from folding ``std`` into the
+    sampler's own constant factor, which rounds differently."""
+    return jax.lax.optimization_barrier(
+        jax.random.normal(key, shape, dtype)) * std
+
+
 def _dense(key, shape, scale_dim=None, dtype=jnp.float32):
     fan_in = scale_dim if scale_dim is not None else shape[0]
-    std = 1.0 / math.sqrt(fan_in)
-    return jax.random.normal(key, shape, dtype) * std
+    return scaled_normal(key, shape, 1.0 / math.sqrt(fan_in), dtype)
+
+
+# Parameter leaves every step casts to the activation dtype where it uses
+# them (``params[name].astype(x.dtype)``): storing them in that dtype gives
+# the same numbers at half the bytes in bf16. Every other leaf (norm
+# scales, the MoE router, recurrent-state parameters) keeps its dtype: the
+# router and the RWKV decay terms are used in float32.
+CAST_ON_USE = frozenset({
+    "wq", "wk", "wv", "wo", "bq", "bk", "bv", "wkv_a", "wkv_b",
+    "w_up", "w_gate", "w_down", "b_up", "b_down", "w_in", "w_out", "table"})
+
+
+def cast_on_use(params, dtype):
+    """Cast the :data:`CAST_ON_USE` leaves (and the untied unembedding) of
+    a parameter tree to ``dtype``; every other leaf is returned as is."""
+    def cast(path, leaf):
+        keys = [getattr(p, "key", None) for p in path]
+        if keys[-1] in CAST_ON_USE or keys[-2:] == ["unembed", "w"]:
+            return leaf.astype(dtype)
+        return leaf
+    return jax.tree_util.tree_map_with_path(cast, params)
 
 
 # ---------------------------------------------------------------- attention
@@ -259,7 +287,6 @@ def _sdpa_banded_cp(q, k, v, dims: AttnDims, q_chunk: int = 1024):
     whole chunks against (replicated) K/V band slices, so no per-chunk
     resharding collectives occur (hillclimb C iteration 2; iteration 1's
     plain banded form re-sharded a seq-sharded q at every slice)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.sharding import specs as _sp
 
@@ -320,14 +347,13 @@ def _sdpa_banded_cp(q, k, v, dims: AttnDims, q_chunk: int = 1024):
     KV = k.shape[2]
     k_r = k.reshape(B, n_chunks, C, KV, hd)
     v_r = v.reshape(B, n_chunks, C, KV, hd)
-    out = shard_map(
+    out = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(batch_ax, seq_ax, None, None, None),
                   P(batch_ax, seq_ax, None, None, None),
                   P(batch_ax, seq_ax, None, None, None)),
         out_specs=P(batch_ax, seq_ax, None, None, None),
-        check_rep=False)(q_r, k_r,
-                         v_r)
+        check_vma=False)(q_r, k_r, v_r)
     return out.reshape(B, S, H, hd)
 
 
@@ -454,7 +480,6 @@ def attention_decode(params, x, dims: AttnDims, cache_k, cache_v, cache_pos,
               and isinstance(seq_ax, str) and S_max % mesh.shape[seq_ax] == 0)
 
     if use_cp:
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         batch_ax = _sp._resolve_one("batch", mesh)
         n_shards = mesh.shape[seq_ax]
@@ -484,7 +509,7 @@ def attention_decode(params, x, dims: AttnDims, cache_k, cache_v, cache_pos,
             out = (acc_g / jnp.maximum(l_g, 1e-30)[..., None]).astype(qg.dtype)
             return out, ck, cv
 
-        out, cache_k, cache_v = shard_map(
+        out, cache_k, cache_v = jax.shard_map(
             local, mesh=mesh,
             in_specs=(P(batch_ax, None, None, None, None),
                       P(batch_ax, None, None, None),
@@ -494,7 +519,7 @@ def attention_decode(params, x, dims: AttnDims, cache_k, cache_v, cache_pos,
             out_specs=(P(batch_ax, None, None, None, None),
                        P(batch_ax, seq_ax, None, None),
                        P(batch_ax, seq_ax, None, None)),
-            check_rep=False)(qg, k, v, cache_k, cache_v, cache_pos)
+            check_vma=False)(qg, k, v, cache_k, cache_v, cache_pos)
         out = out.transpose(0, 3, 1, 2, 4)       # (B,1,KV,G,hd)
     else:
         if vector_pos:
@@ -1358,7 +1383,7 @@ def moe(params, x, dims: MoEDims):
 def embed_init(key, padded_vocab: int, d_model: int):
     """Table rows are the PADDED vocab (configs.base.ArchConfig.padded_vocab)
     so the vocab dim shards evenly; lm_logits masks the padding columns."""
-    return {"table": jax.random.normal(key, (padded_vocab, d_model), jnp.float32) * 0.02}
+    return {"table": scaled_normal(key, (padded_vocab, d_model), 0.02)}
 
 
 def embed_logical():

@@ -15,7 +15,6 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -58,10 +57,10 @@ def make_compressed_allreduce(mesh, param_specs, dp_axes=("pod", "data")):
         n *= mesh.shape[a]
 
     fn = functools.partial(compressed_psum_tree, axis_names=axes, n_shards=n)
-    return shard_map(fn, mesh=mesh,
-                     in_specs=(param_specs, param_specs),
-                     out_specs=(param_specs, param_specs),
-                     check_rep=False)
+    return jax.shard_map(fn, mesh=mesh,
+                         in_specs=(param_specs, param_specs),
+                         out_specs=(param_specs, param_specs),
+                         check_vma=False)
 
 
 def init_error(params):

@@ -32,8 +32,7 @@ def choose_mesh_shape(n_devices: int, *, model_parallel: int = 16,
 def remesh(n_devices: Optional[int] = None, *, model_parallel: int = 16):
     devs = jax.devices()[: (n_devices or len(jax.devices()))]
     shape, axes = choose_mesh_shape(len(devs), model_parallel=model_parallel)
-    import numpy as np
-    return jax.sharding.Mesh(np.asarray(devs).reshape(shape), axes)
+    return specs.make_mesh(shape, axes, devices=devs)
 
 
 def elastic_restore(manager, model, optimizer, *, mesh, step=None):

@@ -98,8 +98,9 @@ import numpy as np
 
 from repro import configs
 from repro.configs.base import Family
+from repro.kernels import ops as kops
 from repro.launch import steps as steps_mod
-from repro.models.layers import INACTIVE_POS
+from repro.models.layers import INACTIVE_POS, cast_on_use
 from repro.models.registry import Model, get_model, reduced_config
 from repro.serve.kvcache import (PAGED_KERNEL_FAMILIES, PREFIX_CACHE_FAMILIES,
                                  KVBackend, make_backend)
@@ -163,6 +164,15 @@ def _jitted_prefill_chunk(model: Model, compute_dtype, s_max: int,
     if first:
         return jax.jit(fn)
     return jax.jit(fn, donate_argnums=(1,))     # donate the transient cache
+
+
+def _init_params(model: Model, seed: int, compute_dtype, quantize_int8: bool):
+    """The engine's parameter tree, built inside one jit (see ``build``)."""
+    params = model.init(jax.random.PRNGKey(seed))
+    if quantize_int8:
+        from repro.core.quantize import dequantize_params, quantize_params
+        params = dequantize_params(quantize_params(params), compute_dtype)
+    return cast_on_use(params, compute_dtype)
 
 
 def chunk_ladder(chunk_tokens: int) -> List[int]:
@@ -307,7 +317,7 @@ class ServeEngine:
     tokens between decode ticks; ``'scan'`` is the teacher-forced
     one-dispatch scan prefill (the bit-exactness anchor).
     ``prefill_attn_impl='auto'`` resolves to the K/V-exporting flash kernel
-    on TPU and the jnp reference elsewhere.
+    on TPU when the head width tiles, and the jnp reference otherwise.
     """
 
     def __init__(self, model: Model, params, *, batch_slots: int, s_max: int,
@@ -348,8 +358,13 @@ class ServeEngine:
         self.prefill_chunk_tokens = min(int(prefill_chunk_tokens), s_max)
         self.prefill_ladder = chunk_ladder(self.prefill_chunk_tokens)
         if prefill_attn_impl == "auto":
-            prefill_attn_impl = ("pallas" if jax.default_backend() == "tpu"
-                                 else "einsum")
+            # decided once, from the platform and the head width the flash
+            # kernel must tile; the wrappers raise on the TPU rather than
+            # swap in the oracle, so the choice recorded here is the path
+            prefill_attn_impl = (
+                "pallas" if (jax.default_backend() == "tpu"
+                             and kops.attention_kernel_fits(self.cfg.head_dim))
+                else "einsum")
         self.prefill_attn_impl = prefill_attn_impl
         # hard cap on distinct prefill trace shapes: first/cont x ladder x
         # group widths; past it the chunk jit caches are cleared (and the
@@ -454,21 +469,23 @@ class ServeEngine:
         if paged_attn_impl not in ("auto", "kernel", "einsum"):
             raise ValueError(f"paged_attn_impl must be 'auto', 'kernel' or "
                              f"'einsum', got {paged_attn_impl!r}")
-        kernel_ok = self.paged and self.cfg.family in PAGED_KERNEL_FAMILIES
         if paged_attn_impl == "auto":
-            # the backend's dispatch policy; for paged pools the degenerate
-            # one-page-per-slot config (page_size == s_max) is the dense
-            # bit-exactness anchor and has no pages to skip — auto keeps it
-            # on the einsum path so the anchor stays bit-for-bit
+            # the backend's dispatch policy, from the family and shapes; for
+            # paged pools the degenerate one-page-per-slot config (page_size
+            # == s_max) is the dense bit-exactness anchor and has no pages
+            # to skip — auto keeps it on the einsum path so the anchor stays
+            # bit-for-bit
             paged_attn_impl = (self.backend.resolve_attn_impl(
-                self.cfg.family, self.max_pages_per_slot > 1)
+                self.cfg, self.max_pages_per_slot > 1)
                 if self.paged else "einsum")
-        elif paged_attn_impl == "kernel" and not kernel_ok:
-            log.warning("paged_attn_impl='kernel' unsupported here (needs a "
-                        "paged cache on a dense/MoE/VLM/encdec family; got "
-                        "paged=%s family=%s) — using the masked-einsum path",
-                        self.paged, self.cfg.family)
-            paged_attn_impl = "einsum"
+        elif paged_attn_impl == "kernel" and not (
+                self.paged and self.backend.kernel_supports(self.cfg)):
+            raise ValueError(
+                f"paged_attn_impl='kernel' is not available here (needs a "
+                f"paged cache on a dense/MoE/VLM/encdec family whose heads "
+                f"the kernel tiles; got paged={self.paged} "
+                f"family={self.cfg.family} head_dim={self.cfg.head_dim} on "
+                f"{jax.default_backend()}); use 'auto' or 'einsum'")
         self.paged_attn_impl = paged_attn_impl
         # incremental splice: continuation chunks write K/V straight into
         # their reserved pages and attend them through the block table —
@@ -501,7 +518,7 @@ class ServeEngine:
 
     # ------------------------------------------------------------ factory
     @classmethod
-    def build(cls, arch: str = "hymba-1.5b", *, config=None,
+    def build(cls, arch: str = "hymba-1.5b", *, config=None, devices=None,
               **legacy) -> "ServeEngine":
         """Construct model + params from an arch id and a
         :class:`~repro.serve.config.ServeConfig`:
@@ -518,7 +535,12 @@ class ServeEngine:
         TPU). ``config.tp`` builds a 1-axis serving mesh over the first
         ``tp`` local devices (tp=1 is a legal 1-device mesh: it exercises
         the whole mesh code path and is the bit-exactness anchor against
-        mesh=None). ``config.cfg_overrides``: dataclasses.replace fields
+        mesh=None); ``devices`` spans the mesh over those devices instead —
+        ``devices=[d]`` pins a one-chip replica, params and cache, to ``d``.
+        Params are initialised on the device inside one jit, with the
+        weights every step casts on use already in ``compute_dtype``
+        (``layers.cast_on_use``): no float32 copy of the whole tree is ever
+        held. ``config.cfg_overrides``: dataclasses.replace fields
         applied AFTER reduction — reduced configs can shrink num_kv_heads
         to 1, which blocks kv-head sharding; the tp tests/bench override
         the head counts while keeping everything else reduced.
@@ -556,24 +578,29 @@ class ServeEngine:
         # the device-count guard outranks validate(): "you don't have the
         # devices" is the actionable error on a 1-device host even when the
         # reduced config's kv-head count would also reject the tp degree
+        if devices is not None:
+            if config.tp not in (None, len(devices)):
+                raise ValueError(f"tp={config.tp} conflicts with "
+                                 f"{len(devices)} devices given")
+            config = dataclasses.replace(config, tp=len(devices))
         mesh = None
         if config.tp is not None:
             tp = config.tp
-            ndev = len(jax.devices())
-            if tp < 1 or tp > ndev:
-                raise ValueError(f"tp={tp} needs 1..{ndev} local devices "
-                                 "(CPU tests force 8 via XLA_FLAGS="
-                                 "--xla_force_host_platform_device_count=8)")
+            found = jax.devices()
+            if tp < 1 or tp > len(found):
+                raise ValueError(
+                    f"tp={tp} needs 1..{len(found)} local devices; found "
+                    f"{len(found)} x {found[0].platform} "
+                    f"({found[0].device_kind})")
         config.validate(cfg)
+        from repro.sharding import specs as _specs
         if config.tp is not None:
-            from repro.sharding import specs as _specs
-            mesh = _specs.serve_mesh(config.tp)
+            mesh = _specs.serve_mesh(config.tp, devices)
         model = get_model(cfg)
-        params = model.init(jax.random.PRNGKey(config.seed))
-        if config.quantize_int8:
-            from repro.core.quantize import dequantize_params, quantize_params
-            params = dequantize_params(quantize_params(params),
-                                       config.compute_dtype)
+        params = jax.jit(
+            functools.partial(_init_params, model, config.seed,
+                              config.compute_dtype, config.quantize_int8),
+            out_shardings=_specs.replicated(mesh))()
         return cls(model, params, mesh=mesh, **config.engine_kwargs())
 
     # ------------------------------------------------------------ extras
@@ -929,7 +956,7 @@ class ServeEngine:
             plans[slot] = plan
             pairs.append((slot, req))
         if self.paged and pairs:
-            self.cache["block_tables"] = jnp.asarray(self._bt_host)
+            self.cache["block_tables"] = jnp.array(self._bt_host)
         # group by (prompt_len, cached_len): joint prefill needs equal tail
         # shapes AND an equal gather offset across the group's requests
         groups: Dict[tuple, list] = {}
@@ -1056,7 +1083,7 @@ class ServeEngine:
             self.allocator.release(self.slot_pages[slot])
             self.slot_pages[slot] = []
             self._bt_host[slot, :] = -1
-            self.cache["block_tables"] = jnp.asarray(self._bt_host)
+            self.cache["block_tables"] = jnp.array(self._bt_host)
         self.metrics.on_preempt(req.rid)
         self._defer_state = None      # freed pages can change the outcome
         self.scheduler.submit(req)
@@ -1350,7 +1377,7 @@ class ServeEngine:
             self.allocator.release(self.slot_pages[slot])
             self.slot_pages[slot] = []
             self._bt_host[slot, :] = -1
-            self.cache["block_tables"] = jnp.asarray(self._bt_host)
+            self.cache["block_tables"] = jnp.array(self._bt_host)
 
     def _cache_healthy(self) -> bool:
         """True when every resident-cache buffer is live and readable. A
@@ -1524,7 +1551,10 @@ class ServeEngine:
             else:
                 self._consec_prefill_ticks = 0
         if self.running:
-            batch = {"token": jnp.asarray(self.cur_token),
+            # jnp.array, not asarray: on the CPU asarray may alias the host
+            # buffer, which is rewritten below and by admit() while a
+            # dispatch can still read it (the block tables likewise)
+            batch = {"token": jnp.array(self.cur_token),
                      **self._decode_extras()}
             logits, self.cache = self._decode(self.params, self.cache, batch)
             self.metrics.on_decode_step()

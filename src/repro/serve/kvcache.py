@@ -299,9 +299,17 @@ class KVBackend:
         transient cache for a prefix-hit tail prefill (paged only)."""
         raise NotImplementedError(f"{self.name} backend has no pages")
 
-    def resolve_attn_impl(self, family: Family, multi_page: bool) -> str:
-        """'auto' policy: which paged read path serves this config."""
-        return "einsum"
+    def kernel_supports(self, cfg) -> bool:
+        """Can the paged Pallas kernel read this representation for this
+        arch, on this platform? Decided from the family and shapes."""
+        return False
+
+    def resolve_attn_impl(self, cfg, multi_page: bool) -> str:
+        """'auto' policy: which paged read path serves this config. The
+        degenerate one-page-per-slot config (``multi_page`` False) stays on
+        the einsum path: it IS the dense bit-exactness anchor."""
+        return ("kernel" if multi_page and self.kernel_supports(cfg)
+                else "einsum")
 
     def page_meta(self, cache) -> dict:
         """Per-page metadata leaves this representation adds (name ->
@@ -459,12 +467,11 @@ class PagedFP32Backend(KVBackend):
     def seed_prefix(self, model: Model, s_max: int, dtype):
         return _jitted_prefix_seed(model, s_max, dtype)
 
-    def resolve_attn_impl(self, family: Family, multi_page: bool) -> str:
-        # the degenerate one-page-per-slot config stays on the einsum path:
-        # it IS the dense bit-exactness anchor
-        if family in PAGED_KERNEL_FAMILIES and multi_page:
-            return "kernel"
-        return "einsum"
+    def kernel_supports(self, cfg) -> bool:
+        # one head is one block's lane axis: on the TPU its width must tile
+        from repro.kernels.ops import attention_kernel_fits
+        return (cfg.family in PAGED_KERNEL_FAMILIES
+                and attention_kernel_fits(cfg.head_dim))
 
 
 @register_backend
@@ -554,6 +561,10 @@ class PagedLatentBackend(PagedFP32Backend):
         # the head axis of the absorbed queries carries the tp split
         return {}
 
+    def kernel_supports(self, cfg) -> bool:
+        # the latent kernel's blocks are whole latent rows: any width tiles
+        return cfg.family in PAGED_KERNEL_FAMILIES
+
     def init_cache(self, model: Model, batch_slots: int, s_max: int, dtype):
         if getattr(model.cfg, "kv_lora_rank", 0) <= 0:
             raise ValueError(
@@ -569,8 +580,8 @@ def make_backend(spec, *, family: Family, page_size=None, num_pages=None,
     """Resolve an engine ``kv_backend`` spec: None (layout follows
     page_size), a name registered in :data:`BACKENDS` ('dense' | 'paged' |
     'paged_fp32' | 'paged_int8' | 'paged_latent'), or a ready KVBackend
-    instance. Int8 on an unsupported family degrades to fp32 pages with a
-    warning rather than failing — the caller keeps a correct serving path.
+    instance. Int8 on an unsupported family raises: a silent swap to fp32
+    pages would serve something other than what was asked for.
     ``mesh``: optional serving mesh the backend's :meth:`KVBackend.place`
     commits its pool onto. ``num_kv_heads``: when given with a tp>1 mesh,
     checked against the backend's declared layout (a kv-head-sharded pool
@@ -601,10 +612,9 @@ def make_backend(spec, *, family: Family, page_size=None, num_pages=None,
     if page_size is None:
         raise ValueError(f"kv_backend={spec!r} needs page_size")
     if cls is PagedInt8Backend and family not in INT8_KV_FAMILIES:
-        log.warning("paged_int8 KV backend supports %s (got %s); "
-                    "falling back to fp32 pages",
-                    [f.name for f in INT8_KV_FAMILIES], family)
-        cls = PagedFP32Backend
+        raise ValueError(f"kv_backend='paged_int8' supports "
+                         f"{[f.name for f in INT8_KV_FAMILIES]} (got "
+                         f"{family}); use kv_backend='paged'")
     check_tp_support(cls, tp)
     if (tp > 1 and num_kv_heads is not None and _shards_kv_heads(cls)
             and num_kv_heads % tp):

@@ -145,6 +145,7 @@ def _main(argv=None) -> int:
     import argparse
     import json
 
+    from repro.runtime.compile_cache import setup_compile_cache
     from repro.serve.config import ServeConfig
     from repro.serve.engine import ServeEngine
     from repro.serve.scheduler import SchedPolicy
@@ -168,6 +169,7 @@ def _main(argv=None) -> int:
                          "devices; any registered backend composes via "
                          "its sharding hooks)")
     args = ap.parse_args(argv)
+    setup_compile_cache()
 
     policy = None if args.fifo else SchedPolicy(
         drr=True, max_consecutive_prefill_ticks=2, preemption=True,

@@ -16,7 +16,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -71,9 +70,9 @@ def make_pipeline(mesh, stage_fn: Callable, params_spec=None, *,
 
     if params_spec is None:
         params_spec = P(stage_axis)
-    return shard_map(per_stage, mesh=mesh,
-                     in_specs=(params_spec, P()),
-                     out_specs=P(), check_rep=False)
+    return jax.shard_map(per_stage, mesh=mesh,
+                         in_specs=(params_spec, P()),
+                         out_specs=P(), check_vma=False)
 
 
 def bubble_fraction(n_stages: int, n_micro: int) -> float:

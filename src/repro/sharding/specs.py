@@ -261,13 +261,35 @@ def serve_trace(mesh: Optional[Mesh], fn):
     return wrapped
 
 
-def serve_mesh(tp: int) -> Mesh:
-    """Build the canonical 1-axis serving mesh over the first ``tp`` local
-    devices. The axis is named :data:`TP_AXIS`; keeping the construction
-    here means callers (notably the serve engine) never spell the axis name
-    themselves — the backend seam and these helpers own every mesh
-    internal."""
-    return jax.make_mesh((tp,), (TP_AXIS,))
+def make_mesh(shape: Sequence[int], axes: Sequence[str],
+              devices=None) -> Mesh:
+    """THE mesh constructor: every axis typed ``Auto``. ``jax.make_mesh``
+    defaults to ``Explicit`` axes, under which sharding-in-types rejects
+    this code's ``with_sharding_constraint`` calls and mixed-sharding
+    updates; with ``Auto`` axes GSPMD propagates shardings as the logical
+    rules above assume. ``devices`` (optional) pins the mesh to a device
+    list — e.g. the devices of a described topology for a compile-only
+    rehearsal."""
+    shape, axes = tuple(shape), tuple(axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
+
+
+def serve_mesh(tp: int, devices=None) -> Mesh:
+    """Build the canonical 1-axis serving mesh over ``devices`` (default:
+    the first ``tp`` local devices). The axis is named :data:`TP_AXIS`;
+    keeping the construction here means callers (notably the serve engine)
+    never spell the axis name themselves — the backend seam and these
+    helpers own every mesh internal."""
+    devices = list(devices) if devices is not None else jax.devices()[:tp]
+    return make_mesh((tp,), (TP_AXIS,), devices=devices)
+
+
+def replicated(mesh: Optional[Mesh]):
+    """The fully replicated sharding on ``mesh`` (None without a mesh: the
+    default placement), e.g. as a jit's ``out_shardings``."""
+    return None if mesh is None else NamedSharding(mesh, P())
 
 
 def replicate_params(params, mesh: Optional[Mesh]):
@@ -277,7 +299,7 @@ def replicate_params(params, mesh: Optional[Mesh]):
     shards, which is what makes a tp>1 serve tick bitwise equal to tp=1."""
     if mesh is None:
         return params
-    return jax.device_put(params, NamedSharding(mesh, P()))
+    return jax.device_put(params, replicated(mesh))
 
 
 def _is_logical_leaf(v):

@@ -18,7 +18,7 @@ def test_resolve_without_mesh_is_identity():
 def test_rule_filtering():
     """Axes absent from the active mesh drop out of resolved specs."""
     import jax
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = specs.make_mesh((1,), ("data",))
     with specs.use_mesh(mesh):
         p = specs.resolve("batch", "heads", None)
         # 'pod' filtered (absent), 'model' filtered (absent) -> heads -> None
@@ -31,7 +31,7 @@ def test_shard_rank_mismatch_raises():
     import jax
     import jax.numpy as jnp
     import pytest
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = specs.make_mesh((1,), ("model",))
     x = jnp.ones((4, 4))
     with specs.use_mesh(mesh):
         with pytest.raises(ValueError, match="rank mismatch"):
@@ -44,8 +44,8 @@ def test_use_mesh_nesting_restores_outer():
     """Nested use_mesh contexts stack: the inner mesh/rules win inside,
     the outer (or the no-mesh default) is restored on exit."""
     import jax
-    outer = jax.make_mesh((1,), ("data",))
-    inner = jax.make_mesh((1,), ("model",))
+    outer = specs.make_mesh((1,), ("data",))
+    inner = specs.make_mesh((1,), ("model",))
     assert specs.active_mesh() is None
     with specs.use_mesh(outer, specs.DEFAULT_RULES):
         assert specs.active_mesh() is outer
@@ -69,7 +69,7 @@ def test_spec_helpers_on_real_axes(multidevice):
         from jax.sharding import PartitionSpec as P
         from repro.sharding import specs
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = specs.make_mesh((2, 4), ("data", "model"))
         with specs.use_mesh(mesh):
             assert specs.axis_size("heads") == 4          # heads -> model
             assert specs.axis_size("batch") == 2          # (pod,data) -> data
@@ -95,7 +95,7 @@ def test_spec_helpers_on_real_axes(multidevice):
             assert specs.axis_size("heads") == 1          # not in pool rules
 
         # head_shard_axis: resolves only when tp divides BOTH head counts
-        tp_mesh = jax.make_mesh((4,), ("model",))
+        tp_mesh = specs.make_mesh((4,), ("model",))
         with specs.use_mesh(tp_mesh, specs.TP_SERVE_RULES):
             assert specs.head_shard_axis(8, 4) == (tp_mesh, "model")
             assert specs.head_shard_axis(8, 2) == (None, None)   # 2 % 4
@@ -124,7 +124,7 @@ batch = {"tokens": toks, "labels": jnp.roll(toks, -1, 1)}
 
 losses = {}
 for mesh_shape in [None, (2, 4)]:
-    mesh = jax.make_mesh(mesh_shape, ("data", "model")) if mesh_shape else None
+    mesh = specs.make_mesh(mesh_shape, ("data", "model")) if mesh_shape else None
     with specs.use_mesh(mesh):
         state = steps_mod.init_train_state(model, opt, jax.random.PRNGKey(0))
         step = steps_mod.make_train_step(model, opt, compute_dtype=jnp.float32,
@@ -176,7 +176,7 @@ tmp = tempfile.mkdtemp()
 mgr = CheckpointManager(tmp, async_save=False)
 
 def one_step_from(mesh_shape, state=None):
-    mesh = jax.make_mesh(mesh_shape, ("data", "model"))
+    mesh = specs.make_mesh(mesh_shape, ("data", "model"))
     with specs.use_mesh(mesh):
         sds = jax.eval_shape(lambda k: steps_mod.init_train_state(model, opt, k),
                              jax.random.PRNGKey(0))
@@ -191,7 +191,7 @@ def one_step_from(mesh_shape, state=None):
         return float(metrics["loss"])
 
 # train 2 steps on (2,4), checkpoint
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = specs.make_mesh((2, 4), ("data", "model"))
 with specs.use_mesh(mesh):
     state = steps_mod.init_train_state(model, opt, jax.random.PRNGKey(0))
     step = jax.jit(steps_mod.make_train_step(model, opt,
@@ -213,8 +213,9 @@ def test_pipeline_parallel_matches_sequential(multidevice):
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.sharding.pipeline import bubble_fraction, make_pipeline
+from repro.sharding import specs
 
-mesh = jax.make_mesh((4,), ("pod",))
+mesh = specs.make_mesh((4,), ("pod",))
 P_stages, n_micro, B, D = 4, 8, 2, 16
 key = jax.random.PRNGKey(0)
 # stage params: [P, D, D]
@@ -244,8 +245,9 @@ def test_compressed_allreduce(multidevice):
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P, NamedSharding
 from repro.optim.grad_compress import init_error, make_compressed_allreduce
+from repro.sharding import specs
 
-mesh = jax.make_mesh((8,), ("data",))
+mesh = specs.make_mesh((8,), ("data",))
 key = jax.random.PRNGKey(0)
 g_global = jax.random.normal(key, (8, 64, 32))   # per-shard grads
 specs_tree = {"w": P()}                          # grads replicated per shard
@@ -287,7 +289,7 @@ params = model.init(jax.random.PRNGKey(0))
 toks = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0, cfg.vocab_size)
 
 ref, _ = model.forward(params, toks, compute_dtype=jnp.float32)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = specs.make_mesh((2, 4), ("data", "model"))
 with specs.use_mesh(mesh):
     fn = jax.jit(lambda p, t: model.forward(p, t, compute_dtype=jnp.float32)[0])
     got = fn(params, toks)

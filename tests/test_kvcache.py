@@ -95,18 +95,22 @@ def test_make_backend_resolution():
         make_backend("latent_mla", family=fam, page_size=8)
 
 
-def test_int8_unsupported_family_degrades_to_fp32(caplog):
-    """Hybrid's ring carry is not page-reconstructible: int8 on it falls
-    back to fp32 pages with a warning instead of failing, and serving
-    still works end to end."""
+def test_int8_unsupported_family_degrades_to_fp32():
+    """Hybrid's ring carry is not page-reconstructible: int8 on it no
+    longer degrades to fp32 pages behind a log line — both make_backend
+    and the engine build refuse it, naming the supported families, and
+    the fp32 pool it would have swapped in still serves when asked for."""
     fam = configs.get_config("hymba-1.5b").family
     assert fam not in INT8_KV_FAMILIES
-    with caplog.at_level("WARNING", logger="repro.serve"):
-        be = make_backend("paged_int8", family=fam, page_size=8, num_pages=8)
+    with pytest.raises(ValueError, match="paged_int8.*supports"):
+        make_backend("paged_int8", family=fam, page_size=8, num_pages=8)
+    with pytest.raises(ValueError, match="use kv_backend='paged'"):
+        ServeEngine.build("hymba-1.5b", batch_slots=2, s_max=S_MAX,
+                          page_size=PS, kv_backend="paged_int8")
+    be = make_backend("paged", family=fam, page_size=8, num_pages=8)
     assert type(be) is PagedFP32Backend
-    assert any("falling back" in r.message for r in caplog.records)
     eng = ServeEngine.build("hymba-1.5b", batch_slots=2, s_max=S_MAX,
-                            page_size=PS, kv_backend="paged_int8")
+                            page_size=PS, kv_backend="paged")
     assert not eng.backend.quantized
     req = eng.submit(np.arange(1, 9, dtype=np.int32), 4)
     eng.run()

@@ -181,7 +181,16 @@ def test_tp_requires_paged_and_divisible_heads(multidevice):
 
 # --------------------------------------------------- int8 pages under tp
 def _int8_tp_code(arch: str, overrides, tps=(2, 4)) -> str:
+    # The 0.6 gate below was set on the random weights of the original
+    # threefry key stream. jax 0.5 made the partitionable stream the
+    # default, which draws other weights: on those, the reduced MoE's first
+    # token of one prompt sits on a near-tie that int8 rounding at tp=1
+    # already flips away from the fp32 pool (tp=2's finer scale groups
+    # agree with fp32 there). Pinning the stream keeps the gate on the
+    # weights it was set on.
     return f"""
+        import jax
+        jax.config.update("jax_threefry_partitionable", False)
         import numpy as np
         from repro.serve.engine import ServeEngine
 
