@@ -166,9 +166,10 @@ class LegacyServer:
         return slot
 
     def _step(self):
-        # a copy: on the CPU jnp.asarray may alias cur_token, which the
-        # prefill loop rewrites before this async dispatch has read it
-        batch = {"token": jnp.array(self.cur_token)}
+        # a host copy: the CPU backend may read a NumPy array after
+        # jnp.array/jnp.asarray return, and the prefill loop rewrites
+        # cur_token before this async dispatch has read it
+        batch = {"token": jnp.asarray(self.cur_token.copy())}
         if self.cfg.cross_attn_every:
             batch["image_embeds"] = jnp.zeros(
                 (self.sc.batch_slots, self.cfg.num_image_tokens, self.cfg.d_model),

@@ -128,6 +128,18 @@ def _under_mesh(mesh, fn):
     return _specs.serve_trace(mesh, fn)
 
 
+def _spanned(name: str):
+    """Run the method inside ``self.metrics.span(name)``: one profiler span
+    over the whole call, one phase of the engine tick."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def inner(self, *args, **kwargs):
+            with self.metrics.span(name):
+                return fn(self, *args, **kwargs)
+        return inner
+    return wrap
+
+
 # Jitted step functions are cached at module level keyed on the (frozen,
 # hashable) Model so several engine instances over the same architecture —
 # e.g. benchmark repetitions — share one compiled executable instead of
@@ -680,6 +692,7 @@ class ServeEngine:
                          keepdims=True)
         return jnp.where(scaled < thresh, neg, scaled)
 
+    @_spanned("serve.sample")
     def _sample_rows(self, logits) -> np.ndarray:
         """logits: (B, 1, V_padded) -> (B,) sampled token per row."""
         row = logits[:, 0, : self.cfg.vocab_size]
@@ -848,6 +861,7 @@ class ServeEngine:
     def active(self) -> int:
         return sum(1 for r in self.slot_req if r is not None)
 
+    @_spanned("serve.admit")
     def admit(self) -> int:
         """Admit waiting requests into free slots; returns #admitted.
 
@@ -1162,6 +1176,7 @@ class ServeEngine:
                                rcache, logits)
 
     # ------------------------------------------------- chunked prefill
+    @_spanned("serve.prefill")
     def _prefill_tick(self) -> int:
         """Ingest at most ``prefill_chunk_tokens`` prompt positions of
         queued prefill work — the engine's head-of-line bound: between any
@@ -1237,6 +1252,7 @@ class ServeEngine:
                 break               # nobody could spend: credit accrues
         return ingested
 
+    @_spanned("serve.prefill_chunk")
     def _run_chunk(self, job: _PrefillJob) -> Optional[int]:
         """Dispatch ``job``'s next bucketed chunk; on the final chunk,
         splice-and-start the group. Returns the chunk length ingested, or
@@ -1300,6 +1316,7 @@ class ServeEngine:
                 prefix_plans=job.prefix_plans)
         return C
 
+    @_spanned("serve.splice")
     def _splice_and_start(self, slot_ids, reqs, rcache, logits, *,
                           write_floor: int = 0, prefix_plans=None):
         """Complete a group prefill: land its K/V in the resident cache,
@@ -1355,6 +1372,7 @@ class ServeEngine:
             if req.done:
                 self._finish(slot)
 
+    @_spanned("serve.finish")
     def _finish(self, slot: int, state: RequestState = RequestState.DONE):
         """Retire a slot: park its cache position at the INACTIVE_POS
         sentinel (decode drops its writes from now on — freed rows stay
@@ -1524,6 +1542,7 @@ class ServeEngine:
         return sum(1 for r in self.slot_req
                    if r is not None and r.state == RequestState.RUNNING)
 
+    @_spanned("serve.step")
     def step(self) -> int:
         """One engine tick: admit waiting requests, ingest at most one
         prefill-chunk BUDGET of prompt work (the interleave that bounds
@@ -1535,7 +1554,14 @@ class ServeEngine:
         consecutive ticks in which prefill dispatched work while slots were
         decoding — under sustained admission pressure the per-tick chunk
         budget alone bounds each tick's prefill share, but nothing else
-        guarantees decode ever gets a prefill-free tick."""
+        guarantees decode ever gets a prefill-free tick.
+
+        The tick and each of its phases run inside a profiler span from
+        ``self.metrics.span``: ``serve.step`` around ``serve.admit``,
+        ``serve.prefill`` (its ``serve.prefill_chunk`` calls, the last of a
+        job holding ``serve.splice``), ``serve.decode`` (the dispatch),
+        ``serve.sample`` (the read of the sampled tokens) and ``serve.emit``
+        (token bookkeeping, ``serve.finish`` for each retired slot)."""
         self.admit()
         pol = self.policy
         if (pol.max_consecutive_prefill_ticks > 0 and self._jobs
@@ -1551,22 +1577,25 @@ class ServeEngine:
             else:
                 self._consec_prefill_ticks = 0
         if self.running:
-            # jnp.array, not asarray: on the CPU asarray may alias the host
-            # buffer, which is rewritten below and by admit() while a
-            # dispatch can still read it (the block tables likewise)
-            batch = {"token": jnp.array(self.cur_token),
-                     **self._decode_extras()}
-            logits, self.cache = self._decode(self.params, self.cache, batch)
+            with self.metrics.span("serve.decode"):
+                # a host copy: the CPU backend may read a NumPy array
+                # after jnp.array/jnp.asarray return, and cur_token is
+                # rewritten below and by admit()
+                batch = {"token": jnp.asarray(self.cur_token.copy()),
+                         **self._decode_extras()}
+                logits, self.cache = self._decode(self.params, self.cache,
+                                                  batch)
             self.metrics.on_decode_step()
             nxt = self._sample_rows(logits)
-            for slot, req in enumerate(self.slot_req):
-                if req is None or req.state != RequestState.RUNNING:
-                    continue
-                req.tokens.append(int(nxt[slot]))
-                self.cur_token[slot, 0] = int(nxt[slot])
-                self.metrics.on_token(req.rid)
-                if req.done:
-                    self._finish(slot)
+            with self.metrics.span("serve.emit"):
+                for slot, req in enumerate(self.slot_req):
+                    if req is None or req.state != RequestState.RUNNING:
+                        continue
+                    req.tokens.append(int(nxt[slot]))
+                    self.cur_token[slot, 0] = int(nxt[slot])
+                    self.metrics.on_token(req.rid)
+                    if req.done:
+                        self._finish(slot)
         self.admit()        # refill freed slots/pages on the SAME tick
         return self.active
 

@@ -2,6 +2,11 @@
 latency, plus engine-level p50/p95 and throughput. Pure host-side bookkeeping
 — the engine calls the ``on_*`` hooks; ``summary()`` aggregates.
 
+The recorder also names the phases of each engine tick: ``span(name)``
+opens a profiler span (``serve.step``, ``serve.admit``, ...) that lands on
+the profiler's host plane, on the same clock as the device trace, when a
+profiler is running, and costs one no-op context manager when none is.
+
 The clock is injectable so tests can drive deterministic timelines.
 """
 from __future__ import annotations
@@ -11,6 +16,7 @@ import time
 from typing import Callable, Dict, List, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 
 @dataclasses.dataclass
@@ -222,7 +228,8 @@ def merged_summary(recorders: List["MetricsRecorder"],
 
 
 class MetricsRecorder:
-    """Collects request lifecycle timestamps and engine counters."""
+    """Collects request lifecycle timestamps and engine counters, and
+    emits the spans of each engine tick (:meth:`span`)."""
 
     def __init__(self, clock: Callable[[], float] = time.perf_counter):
         self._clock = clock
@@ -252,6 +259,12 @@ class MetricsRecorder:
         """The recorder's clock — engines time prefill chunks with it so
         injected test clocks drive deterministic rates."""
         return self._clock()
+
+    def span(self, name: str) -> TraceAnnotation:
+        """A profiler span over one phase of the engine tick, entered with
+        ``with``. It carries no arguments and reads nothing from the
+        device, so it adds no sync to the tick it names."""
+        return TraceAnnotation(name)
 
     # ------------------------------------------------------------ hooks
     def on_start(self):
