@@ -147,23 +147,35 @@ def flash_prefill(q, k, v, *, causal: bool = True, window: int = 0,
                                   interpret=_interpret())
 
 
-# trace-size guard for the paged kernel: interpret mode inlines one kernel
-# body per grid step (B * H * mps), so an oversized grid would explode trace
-# time off the TPU and routes to the jnp oracle (the Mosaic grid is free)
+# run-time guard for the paged kernels off the TPU: interpret mode executes
+# their work one unit after another, so a problem with more units than this
+# routes to the jnp oracle (on the chip the work is free of the guard). A
+# unit is a grid step of the page-grid kernel (B * H * mps), or one kv
+# head's pass over one block of the decode kernel, counted at a full table
+# (B * KV * ceil(mps / pages per block)).
 _PAGED_MAX_INTERPRET_GRID = 4096
+
+
+def _paged_interpret_units(q, pool_k, block_tables) -> int:
+    B, Sq, H, _ = q.shape
+    ps, KV = pool_k.shape[1:3]
+    mps = block_tables.shape[1]
+    if _pa.takes_decode_kernel(q, pool_k):
+        return B * KV * -(-mps // _pa.decode_pages_per_block(ps))
+    return B * H * mps
 
 
 def _paged_dispatch_local(q, pool_k, pool_v, block_tables, start, window: int,
                           k_scale=None, v_scale=None):
     """Single-device paged-attention dispatch (also the per-shard body under
-    the tp shard_map — the interpret-grid guard then sees per-shard H,
+    the tp shard_map — the interpret guard then sees per-shard heads,
     which is the point of passing this in whole)."""
-    B, Sq, H, hd = q.shape
-    mps = block_tables.shape[1]
+    hd = q.shape[3]
     sc = dict(k_scale=k_scale, v_scale=v_scale)
     if not attention_kernel_fits(hd):
         _oracle("paged_attention", f"head_dim {hd}")
-    if _interpret() and B * H * mps > _PAGED_MAX_INTERPRET_GRID:
+    if (_interpret() and _paged_interpret_units(q, pool_k, block_tables)
+            > _PAGED_MAX_INTERPRET_GRID):
         return _ref.paged_attention(q, pool_k, pool_v, block_tables,
                                     start, window=window, **sc)
     return _pa.paged_attention(q, pool_k, pool_v, block_tables, start,
@@ -207,10 +219,11 @@ def paged_decode(q, pool_k, pool_v, block_tables, cache_pos, *,
     slice of the shared pool; block_tables: (B, mps) int32 (-1 =
     unallocated); cache_pos: (B,) int32 per-slot positions (the new K/V row
     must already be WRITTEN at logical row cache_pos[b] — the write stays a
-    plain block-table scatter outside the kernel). Gathers K/V blocks
-    through the block table inside the kernel and skips fully-masked pages;
-    a freed slot (all--1 table) returns exactly 0. mesh/shard_axis (from
-    specs.head_shard_axis) route through the head-sharded shard_map."""
+    plain block-table scatter outside the kernel). The decode kernel
+    visits only each slot's live pages, read as stored through the block
+    table once for every query head of a kv head; a freed slot (all--1
+    table) returns exactly 0. mesh/shard_axis (from specs.head_shard_axis)
+    route through the head-sharded shard_map."""
     return _paged_dispatch(q, pool_k, pool_v, block_tables, cache_pos,
                            window, mesh=mesh, shard_axis=shard_axis)
 

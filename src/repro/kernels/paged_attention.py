@@ -1,50 +1,54 @@
 """Paged attention: block-table-indirect blockwise softmax over a shared
 KV page pool (the serving engine's vLLM-style cache layout).
 
-The repo's first kernel whose memory access pattern is INDIRECT: K/V blocks
-are not a function of grid indices alone — each (slot, kv-page) grid step
-reads the page named by ``block_tables[slot, page_idx]`` out of the shared
-pool ``(num_pages, page_size, KV, hd)``. The block table and the per-slot
-start positions ride in as SCALAR-PREFETCH operands
-(``pltpu.PrefetchScalarGridSpec``) so the index map can steer each block's
-DMA before the body runs — the same "compute never waits on a dense,
-oversized buffer" dataflow the paper builds around Ultra RAM placement.
+K/V pages are not a function of grid indices alone: each slot reads the
+pages named by ``block_tables[slot, :]`` out of the shared pool
+``(num_pages, page_size, KV, hd)``. The block table and the per-slot
+positions ride in as SCALAR-PREFETCH operands
+(``pltpu.PrefetchScalarGridSpec``), so they steer the DMAs before the body
+computes — the same "compute never waits on a dense, oversized buffer"
+dataflow the paper builds around Ultra RAM placement.
 
-One kernel serves both serving attention shapes:
+Two kernels, chosen by the query shape (:func:`paged_attention`):
 
-* **decode** — Sq == 1, one new query row per slot at position ``start[b]``;
-* **prefill chunk** — Sq == C consecutive prompt positions starting at
-  ``start[b]`` (the engine's incremental per-chunk splice writes the chunk's
-  K/V rows into the pool FIRST, so the kernel reads prior chunks, aliased
-  prefix pages, and the current chunk uniformly through the block table).
+* **decode** (:func:`paged_decode_attention`) — one query row per slot at
+  position ``pos[b]``. Grid ``(B,)``: program b loops over only its slot's
+  live blocks of ``decode_pages_per_block(ps)`` pages, from the first page
+  a sliding window can see to the page holding ``pos[b]``, so its work
+  follows the slot's live pages and not the table's width (s_max). The
+  pool is read as stored from HBM (``memory_space=pl.ANY``): each step
+  DMAs the block's whole ``(ps, KV, hd)`` pages into double-buffered VMEM
+  while the previous block computes, and each kv head's ``G = H // KV``
+  query rows attend that head's rows together, so a page is fetched once
+  for its whole GQA group. A parked slot (``pos >= mps * ps``, all--1
+  table) runs no block.
+* **prefill chunk** (the page-grid kernel) — Sq == C consecutive prompt
+  positions starting at ``start[b]`` (the engine's per-chunk splice writes
+  the chunk's K/V rows into the pool FIRST, so the kernel reads prior
+  chunks, aliased prefix pages, and the current chunk uniformly through
+  the block table). Grid ``(B, H, mps)``, the page index innermost so the
+  online-softmax accumulators persist in VMEM scratch across a slot's
+  pages; GQA shares a K/V block across ``H // KV`` query heads through the
+  ``h // G`` index map. Every program of the grid is issued: ``pl.when``
+  skips the body of a fully-masked page (unallocated, beyond the causal
+  frontier, or wholly behind the window), so its compute, not its grid,
+  follows the live pages. Each live page is fetched once per query head.
 
-Fully-masked pages are SKIPPED (``pl.when``): unallocated block-table slots
-(page id -1), pages wholly beyond the causal frontier
-(``page_start > start + Sq - 1``), and — for windowed layers — pages wholly
-behind the sliding window. Work therefore scales with each slot's LIVE
-pages, not with the block-table span (s_max), which is exactly the
-O(C x s_max) masked-einsum cost this kernel replaces. Partially-filled last
-pages and partially-visible pages are handled by per-row masking inside the
-body; masked probabilities are explicitly zeroed (not just sentinel-masked)
-so a row with no valid key in a visited page contributes nothing, and a row
-with no valid key anywhere (a freed slot parked at INACTIVE_POS with an
-all--1 block table) returns exactly 0 through the ``l == 0`` guard.
+Both mask partially-filled last pages and partially-visible pages per row,
+and zero masked probabilities explicitly (not just sentinel-masked), so a
+row with no valid key in a visited block contributes nothing and a row with
+no valid key anywhere (a freed slot parked at INACTIVE_POS with an all--1
+block table) returns exactly 0 through the ``l == 0`` guard.
 
-Grid: (B, H, mps) with the kv page index innermost so the online-softmax
-accumulators (m, l, acc) persist in VMEM scratch across a slot's pages —
-the paper's "accumulators in on-chip RAM" structure, same as the flash
-kernel. GQA shares each K/V block across ``H // KV`` query heads via the
-``h // G`` index map.
-
-Block layout (Mosaic's rule: the last two dims of every block are
-divisible by (8, 128) or equal the array's own). The head axis is folded
-into the lane axis — q ``(B, Sq, H * hd)``, pools ``(P, ps, KV * hd)`` —
-so one head's block is ``(Sq, hd)`` / ``(ps, hd)``: Sq and ps are whole
-array dims and hd is a lane multiple. The fold is a reshape, which for a
-bf16 pool on the TPU's tiled layout compiles to a relayout copy. The latent pool
-has no head axis (``(P, ps, c + r)``, a whole-row block at any width), and
-its absorbed queries are moved head-major, ``(B, H, Sq, c + r)``, because
-c + r (576 for qwen2.5-32b-mla) is not a lane multiple.
+Page-grid block layout (Mosaic's rule: the last two dims of every block
+are divisible by (8, 128) or equal the array's own). The head axis is
+folded into the lane axis — q ``(B, Sq, H * hd)``, pools
+``(P, ps, KV * hd)`` — so one head's block is ``(Sq, hd)`` / ``(ps, hd)``.
+The fold is a reshape, which for a bf16 pool on the TPU's tiled layout
+compiles to a relayout copy of the layer's pool on every chunk call. The
+latent pool has no head axis (``(P, ps, c + r)``, a whole-row block at any
+width), and its absorbed queries are moved head-major, ``(B, H, Sq, c + r)``,
+because c + r (576 for qwen2.5-32b-mla) is not a lane multiple.
 """
 from __future__ import annotations
 
@@ -150,6 +154,199 @@ def _scratch(rows: int, width: int):
             pltpu.VMEM((rows, width), jnp.float32)]
 
 
+# keys per decode block: each loop step of the decode kernel DMAs this many
+# keys' worth of whole pages (16 pages at page size 16)
+DECODE_BLOCK_KEYS = 256
+
+
+def decode_pages_per_block(page_size: int) -> int:
+    """Whole pages per decode block (at least one)."""
+    return max(1, DECODE_BLOCK_KEYS // page_size)
+
+
+def takes_decode_kernel(q, pool) -> bool:
+    """Does :func:`paged_attention` run this call on the decode kernel?
+    One query row per slot, and a kv-head axis that fills a 32-bit sublane
+    (bf16: KV >= 2, int8: KV >= 4): Mosaic slices no page out of a
+    narrower pool. Only a tp shard's slice of a pool is that narrow; it
+    stays on the page-grid kernel."""
+    return q.shape[1] == 1 and pool.shape[2] * pool.dtype.itemsize >= 4
+
+
+def _decode_kernel(*refs, scale: float, window: int, page_size: int,
+                   ppb: int, mps: int, quantized: bool):
+    """Program b: slot b's decode row for every query head. Operand order:
+    scalar prefetch (block table, positions[, k/v page scales]), q block
+    (KV, G, hd), the K and V pools in HBM, the output block, then scratch
+    (K/V page buffers, DMA semaphores, m/l/acc).
+
+    The loop runs over the slot's live blocks only: blocks of ``ppb``
+    pages from the first page the window can see to the page holding
+    ``pos``. Each step starts the next block's page DMAs into the other
+    buffer before it waits on and computes the current one."""
+    if quantized:
+        bt_ref, pos_ref, ks_ref, vs_ref, q_ref, k_hbm, v_hbm, o_ref = refs[:8]
+    else:
+        bt_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref = refs[:6]
+    kbuf, vbuf, sems, m_ref, l_ref, acc_ref = refs[-6:]
+    kv_heads, G, _ = q_ref.shape
+    keys = ppb * page_size
+    b = pl.program_id(0)
+    pos = pos_ref[b]
+
+    @pl.when(b == 0)
+    def _():
+        # pages past the live span are never copied: their buffer rows
+        # meet p == 0 exactly, which needs them finite, never stale VMEM
+        vbuf[...] = jnp.zeros_like(vbuf)
+
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    # a parked slot (pos >= mps * ps, all--1 table) has no live block
+    live = (pos >= 0) & (pos < mps * page_size)
+    first = 0
+    if window > 0:
+        first = jnp.maximum(pos - window + 1, 0) // page_size
+    last = pos // page_size
+    n_blocks = jnp.where(live, (last - first) // ppb + 1, 0)
+
+    def copies(idx0, p, slot):
+        # an unallocated (-1) entry inside the span copies page 0; the
+        # mask drops its keys
+        src = jnp.maximum(bt_ref[b, idx0 + p], 0)
+        return (pltpu.make_async_copy(k_hbm.at[src], kbuf.at[slot, p],
+                                      sems.at[0, slot]),
+                pltpu.make_async_copy(v_hbm.at[src], vbuf.at[slot, p],
+                                      sems.at[1, slot]))
+
+    def each_live_page(blk, slot, fn):
+        """Apply ``fn`` to the K and V copies of every page of block
+        ``blk`` that lies in the live span (a loop, not ``ppb`` branches)."""
+        idx0 = first + blk * ppb
+
+        def body(p, carry):
+            for c in copies(idx0, p, slot):
+                fn(c)
+            return carry
+        jax.lax.fori_loop(0, jnp.minimum(ppb, last - idx0 + 1), body, 0)
+
+    def table(blk):
+        """Block ``blk``'s page ids (entries past the table read its last)."""
+        idx0 = first + blk * ppb
+        return [bt_ref[b, jnp.minimum(idx0 + p, mps - 1)] for p in range(ppb)]
+
+    def dequant(x, scale_ref, ids):
+        # (ppb, ps, hd) int8 -> f32, each page times its own scale
+        return jnp.concatenate(
+            [x[p] * scale_ref[jnp.maximum(page, 0)]
+             for p, page in enumerate(ids)], axis=0)
+
+    def step(blk, carry):
+        slot = blk % 2
+
+        @pl.when(blk + 1 < n_blocks)
+        def _():
+            each_live_page(blk + 1, 1 - slot, lambda c: c.start())
+
+        each_live_page(blk, slot, lambda c: c.wait())
+        ids = table(blk)
+        col = jax.lax.broadcasted_iota(jnp.int32, (G, keys), 1)
+        k_pos = (first + blk * ppb) * page_size + col
+        ok = k_pos <= pos
+        if window > 0:
+            ok &= k_pos > pos - window
+        for p, page in enumerate(ids):
+            ok &= (col // page_size != p) | (page >= 0)
+        for h in range(kv_heads):
+            q = q_ref[h].astype(jnp.float32)                   # (G, hd)
+            k = kbuf[slot, :, :, h, :].astype(jnp.float32)     # (ppb, ps, hd)
+            v = vbuf[slot, :, :, h, :].astype(jnp.float32)
+            if quantized:
+                k = dequant(k, ks_ref, ids)
+                v = dequant(v, vs_ref, ids)
+            k = k.reshape(keys, -1)
+            v = v.reshape(keys, -1)
+            # f32 dot-then-scale and explicitly zeroed masked
+            # probabilities, as in the page-grid kernel
+            s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+            s = jnp.where(ok, s, NEG_INF)
+            m_prev = m_ref[h]                                  # (G, 1)
+            m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+            p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
+            corr = jnp.exp(m_prev - m_new)
+            l_ref[h] = l_ref[h] * corr + p.sum(axis=1, keepdims=True)
+            acc_ref[h] = acc_ref[h] * corr + jnp.dot(
+                p, v, preferred_element_type=jnp.float32)
+            m_ref[h] = m_new
+        return carry
+
+    @pl.when(n_blocks > 0)
+    def _():
+        each_live_page(0, 0, lambda c: c.start())
+
+    jax.lax.fori_loop(0, n_blocks, step, 0)
+    # l == 0 (no live key: a parked slot) yields exactly 0
+    denom = jnp.maximum(l_ref[...], 1e-30)
+    o_ref[...] = (acc_ref[...] / denom).astype(o_ref.dtype)
+
+
+def paged_decode_attention(q, pool_k, pool_v, block_tables, pos, *,
+                           window: int = 0, interpret: bool = False,
+                           k_scale=None, v_scale=None):
+    """Decode attention over the paged pool, one program per slot.
+
+    q: (B, 1, H, hd); pool_k/pool_v: (P, page_size, KV, hd), read as
+    stored from HBM; block_tables: (B, mps) int32 (-1 = unallocated); pos:
+    (B,) int32, each slot's query position. Returns (B, 1, H, hd).
+
+    Work follows each slot's live pages: the loop visits only the blocks
+    of ``decode_pages_per_block(page_size)`` pages between the window's
+    first visible page and the page holding ``pos``, and each page is
+    copied once for all ``H // KV`` query heads that read it. A slot at
+    ``pos >= mps * page_size`` (parked) runs no block and returns 0."""
+    B, Sq, H, hd = q.shape
+    assert Sq == 1, "decode kernel takes one query row per slot"
+    P, ps, KV, _ = pool_k.shape
+    assert H % KV == 0
+    G = H // KV
+    mps = block_tables.shape[1]
+    ppb = decode_pages_per_block(ps)
+    quantized = k_scale is not None
+    kernel = functools.partial(_decode_kernel, scale=1.0 / math.sqrt(hd),
+                               window=window, page_size=ps, ppb=ppb, mps=mps,
+                               quantized=quantized)
+    prefetch = [jnp.asarray(block_tables, jnp.int32),
+                jnp.asarray(pos, jnp.int32)]
+    if quantized:
+        prefetch += [jnp.asarray(k_scale, jnp.float32),
+                     jnp.asarray(v_scale, jnp.float32)]
+    q_spec = pl.BlockSpec((None, KV, G, hd), lambda b, *_: (b, 0, 0, 0))
+    pool_spec = pl.BlockSpec(memory_space=pl.ANY)
+    buf = pltpu.VMEM((2, ppb, ps, KV, hd), pool_k.dtype)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch),
+        grid=(B,),
+        in_specs=[q_spec, pool_spec, pool_spec],
+        out_specs=q_spec,
+        scratch_shapes=[buf, buf, pltpu.SemaphoreType.DMA((2, 2)),
+                        pltpu.VMEM((KV, G, 1), jnp.float32),
+                        pltpu.VMEM((KV, G, 1), jnp.float32),
+                        pltpu.VMEM((KV, G, hd), jnp.float32)],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
+        # sequential: the buffers are zeroed once, by program 0
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(*prefetch, q.reshape(B, KV, G, hd), pool_k, pool_v)
+    return out.reshape(B, 1, H, hd)
+
+
 def paged_attention_latent(q, pool_c, block_tables, start, *,
                            scale_dim: int, d_v: int, interpret: bool = False):
     """Paged attention over MLA latent pages.
@@ -203,8 +400,17 @@ def paged_attention(q, pool_k, pool_v, block_tables, start, *,
 
     k_scale/v_scale: optional (P,) f32 per-page symmetric scales for int8
     pools; when given, the kernel dequantizes each gathered page inside
-    its body (scales prefetched to SMEM alongside the block table)."""
+    its body (scales prefetched to SMEM alongside the block table).
+
+    One query row per slot (decode) runs :func:`paged_decode_attention`,
+    which visits only each slot's live pages (see
+    :func:`takes_decode_kernel`); a chunk of rows (prefill, speculative
+    verification) runs the page-grid kernel below."""
     B, Sq, H, hd = q.shape
+    if takes_decode_kernel(q, pool_k):
+        return paged_decode_attention(q, pool_k, pool_v, block_tables, start,
+                                      window=window, interpret=interpret,
+                                      k_scale=k_scale, v_scale=v_scale)
     P, ps, KV, _ = pool_k.shape
     assert H % KV == 0
     G = H // KV

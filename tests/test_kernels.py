@@ -208,6 +208,117 @@ def test_paged_attention_degenerate_one_page_spans_s_max():
                                rtol=2e-5, atol=2e-5)
 
 
+def _decode_case(H, KV, ps, mps, pos, hole=None):
+    """q (B, 1, H, 16), pools and a block table covering each slot's
+    ``pos``; ``hole`` = (slot, table index) unallocates one live page."""
+    q, pk, pv, bt = _paged_setup(KEY, len(pos), 1, H, KV, 16,
+                                 sum(p // ps + 1 for p in pos) + 3, ps, mps,
+                                 [p + 1 for p in pos])
+    if hole is not None:
+        bt = bt.at[hole].set(-1)
+    return q, pk, pv, bt, jnp.asarray(pos, jnp.int32)
+
+
+# blocks are pa.DECODE_BLOCK_KEYS (256) keys: positions 255 and 511 end a
+# block exactly, 256 starts one
+@pytest.mark.parametrize("case", [
+    dict(H=4, KV=4, ps=8, mps=48, pos=[19, 300]),             # G 1, part page
+    dict(H=10, KV=2, ps=8, mps=48, pos=[255, 256]),           # G 5, boundary
+    dict(H=7, KV=1, ps=16, mps=40, pos=[100, 511]),           # G 7, boundary
+    dict(H=4, KV=2, ps=8, mps=96, pos=[2, 700, 131]),         # ragged lengths
+    dict(H=4, KV=2, ps=8, mps=96, pos=[40, 600], window=100),
+    dict(H=4, KV=2, ps=8, mps=96, pos=[511, 300], window=256),
+    dict(H=4, KV=2, ps=8, mps=48, pos=[300, 40], hole=(0, 1)),
+    dict(H=8, KV=4, ps=8, mps=48, pos=[77, 260], int8=True),
+], ids=["g1", "g5-block-edge", "g7-block-edge", "ragged", "window",
+        "window-block-edge", "unallocated-live-page", "int8"])
+def test_paged_decode_kernel_matches_ref(case):
+    """The decode kernel (one program per slot, a loop over the slot's live
+    blocks, every page read once for its whole GQA group) against the
+    masked-gather oracle."""
+    from repro.kernels import paged_attention as pa
+    q, pk, pv, bt, pos = _decode_case(case["H"], case["KV"], case["ps"],
+                                      case["mps"], case["pos"],
+                                      case.get("hole"))
+    sc = {}
+    if case.get("int8"):
+        pk, pv = (jnp.clip(jnp.round(x * 40), -127, 127).astype(jnp.int8)
+                  for x in (pk, pv))
+        scale = (1 + jnp.arange(pk.shape[0]) % 3) / 40.0
+        sc = dict(k_scale=scale, v_scale=scale * 0.5)
+    assert pa.takes_decode_kernel(q, pk)
+    w = case.get("window", 0)
+    kernel = jax.jit(pa.paged_decode_attention,
+                     static_argnames=("window", "interpret"))
+    got = kernel(q, pk, pv, bt, pos, window=w, interpret=True, **sc)
+    want = ref.paged_attention(q, pk, pv, bt, pos, window=w, **sc)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
+
+def test_paged_decode_skips_unallocated_and_future_pages():
+    """Decode reads no row past a slot's position and no unallocated page:
+    poisoning every other pool row with huge values changes nothing, and a
+    parked slot (all--1 table) returns exactly zero."""
+    H, KV, ps, mps = 4, 2, 8, 48
+    q, pk, pv, bt, pos = _decode_case(H, KV, ps, mps, [300, 5])
+    base = ops.paged_decode(q, pk, pv, bt, pos)
+    P = pk.shape[0]
+    valid = np.zeros(P * ps, bool)
+    btn = np.asarray(bt)
+    for b, last in enumerate([300, 5]):
+        for r in range(last + 1):
+            valid[btn[b, r // ps] * ps + r % ps] = True
+    keep = jnp.asarray(valid)[:, None, None]
+    poison = [jnp.where(keep, x.reshape(P * ps, KV, 16), 1e9).reshape(x.shape)
+              for x in (pk, pv)]
+    got = ops.paged_decode(q, *poison, bt, pos)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(base))
+    parked = ops.paged_decode(q, pk, pv, jnp.full((2, mps), -1, jnp.int32),
+                              jnp.asarray([1 << 30, (1 << 30) + 7], jnp.int32))
+    assert np.all(np.asarray(parked) == 0)
+
+
+def _pallas_grids(fn, *args) -> list:
+    """The grid of every pallas_call in ``fn``'s jaxpr, nested jits too."""
+    grids = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                grids.append(tuple(eqn.params["grid_mapping"].grid))
+            for p in eqn.params.values():
+                inner = getattr(p, "jaxpr", p)
+                if hasattr(inner, "eqns"):
+                    walk(inner)
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return grids
+
+
+def test_paged_decode_work_follows_live_pages_not_table_width():
+    """Widening the block table with -1 columns (mps 4 -> 64) leaves decode
+    bit-identical: the loop is bounded by each slot's position, not by the
+    table. Routing: ``paged_decode`` (one row) runs the decode kernel, one
+    program per slot; ``paged_prefill`` (a chunk) runs the page-grid
+    kernel, one program per slot, query head and table page."""
+    H, KV, ps = 4, 2, 8
+    q, pk, pv, bt, pos = _decode_case(H, KV, ps, 4, [30, 9])
+    wide = jnp.concatenate([bt, jnp.full((2, 60), -1, jnp.int32)], axis=1)
+    narrow_out = ops.paged_decode(q, pk, pv, bt, pos)
+    wide_out = ops.paged_decode(q, pk, pv, wide, pos)
+    np.testing.assert_array_equal(np.asarray(wide_out),
+                                  np.asarray(narrow_out))
+    assert _pallas_grids(ops.paged_decode, q, pk, pv, wide, pos) == [(2,)]
+    qc = jnp.concatenate([q, q], axis=1)
+    assert _pallas_grids(ops.paged_prefill, qc, pk, pv, wide,
+                         pos - 1) == [(2, H, 64)]
+    # a bf16 pool with one kv head (a tp shard's slice) is narrower than a
+    # 32-bit sublane: its decode stays on the page-grid kernel
+    bf = lambda x: x[:, :, :1].astype(jnp.bfloat16)  # noqa: E731
+    assert _pallas_grids(ops.paged_decode, bf(q), bf(pk), bf(pv), wide,
+                         pos) == [(2, 1, 64)]
+
+
 def test_mask_value_dtype_aware():
     """The -1e30 sentinel satellite: finite in every dtype (fp16's max is
     65504, so the historical constant overflowed to -inf there and a fully

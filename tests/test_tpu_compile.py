@@ -13,7 +13,9 @@ workers collect different tests. Every test here skips when no topology
 can be described. Compiles run with the persistent compilation cache off:
 an executable for an absent chip could be written but never read back.
 """
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -55,12 +57,12 @@ def _compile_hlo(fn, sharding, *shapes) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-def _paged_args(sq, H, KV, hd, pool_dtype):
+def _paged_args(sq, H, KV, hd, pool_dtype, b=B):
     mps = S_MAX // PS
-    P = B * mps
-    return [((B, sq, H, hd), jnp.bfloat16), ((P, PS, KV, hd), pool_dtype),
-            ((P, PS, KV, hd), pool_dtype), ((B, mps), jnp.int32),
-            ((B,), jnp.int32)]
+    P = b * mps
+    return [((b, sq, H, hd), jnp.bfloat16), ((P, PS, KV, hd), pool_dtype),
+            ((P, PS, KV, hd), pool_dtype), ((b, mps), jnp.int32),
+            ((b,), jnp.int32)]
 
 
 @pytest.mark.parametrize("sq", [1, CHUNK], ids=["decode", "chunk"])
@@ -109,3 +111,32 @@ def test_flash_prefill_kernel_compiles(one_chip):
         one_chip, ((B, CHUNK, H, hd), jnp.bfloat16),
         ((B, CHUNK, KV, hd), jnp.bfloat16), ((B, CHUNK, KV, hd), jnp.bfloat16))
     assert "tpu_custom_call" in hlo
+
+
+# the benchmark cells' decode geometries (benchmarks/chip/configs): slots,
+# query heads, kv heads, at head width 128, s_max 4096 and page 16
+DECODE_CELLS = {"qwen2.5-32b": (8, 40, 8), "qwen2.5-7b": (16, 28, 4)}
+_INSTR = re.compile(r"^\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\S* ([\w-]+)\(")
+
+
+@pytest.mark.parametrize("cell", sorted(DECODE_CELLS))
+def test_paged_decode_kernel_reads_pool_as_stored(one_chip, cell):
+    """The decode kernel compiles at the cells' widths and takes both
+    (P, ps, KV, hd) pools straight from the parameters: no instruction
+    the size of a pool (a copy, reshape or relayout) precedes it."""
+    b, H, KV = DECODE_CELLS[cell]
+    args = _paged_args(1, H, KV, 128, jnp.bfloat16, b)
+    hlo = _compile_hlo(
+        lambda q, k, v, bt, st: pa.paged_attention(q, k, v, bt, st),
+        one_chip, *args)
+    assert "tpu_custom_call" in hlo
+    pool_elems = math.prod(args[1][0])
+    pool_sized = {}
+    for line in hlo.splitlines():
+        m = _INSTR.match(line)
+        if m and m[2] and math.prod(map(int, m[2].split(","))) == pool_elems:
+            pool_sized[m[1]] = m[3]
+    assert len(pool_sized) == 2
+    assert set(pool_sized.values()) == {"parameter"}, pool_sized
+    call = next(line for line in hlo.splitlines() if "tpu_custom_call" in line)
+    assert all(f"%{name}" in call for name in pool_sized)
