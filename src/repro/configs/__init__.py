@@ -3,18 +3,19 @@
 Arch ids use the assignment's hyphenated names; module files use underscores.
 """
 from repro.configs.base import (ALL_SHAPES, SHAPES_BY_NAME, ArchConfig, Family,
-                                MemoryStrategy, MoEConfig, ShapeConfig,
+                                MemoryStrategy, MoEConfig, RopeScaling,
+                                ShapeConfig,
                                 TRAIN_4K, PREFILL_32K, DECODE_32K, LONG_500K)
 
 from repro.configs import (moonshot_v1_16b_a3b, dbrx_132b, whisper_large_v3,
                            minicpm_2b, command_r_35b, codeqwen1_5_7b,
                            qwen2_5_32b, qwen2_5_32b_mla, hymba_1_5b, rwkv6_7b,
-                           llama_3_2_vision_11b, resnet20_cifar)
+                           llama_3_2_vision_11b, resnet20_cifar, deepseek_v3)
 
 _ARCHS = {}
 for _m in (moonshot_v1_16b_a3b, dbrx_132b, whisper_large_v3, minicpm_2b,
            command_r_35b, codeqwen1_5_7b, qwen2_5_32b, qwen2_5_32b_mla,
-           hymba_1_5b, rwkv6_7b, llama_3_2_vision_11b):
+           hymba_1_5b, rwkv6_7b, llama_3_2_vision_11b, deepseek_v3):
     _ARCHS[_m.CONFIG.name] = _m.CONFIG
 
 RESNET20 = resnet20_cifar.CONFIG

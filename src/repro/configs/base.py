@@ -31,6 +31,21 @@ class MemoryStrategy(str, enum.Enum):
     COMPILER_LARGE_LOCAL = "compiler_large_local"  # + whole-layer residency planning
 
 
+def replace_nested(obj, updates: dict):
+    """``dataclasses.replace`` that also takes a dict for a field holding a
+    dataclass (``{"moe": {"held": [0, 8]}}`` replaces inside ``moe``), and
+    stores lists as tuples so the result stays hashable."""
+    fields = {}
+    for k, v in updates.items():
+        cur = getattr(obj, k)
+        if isinstance(v, dict) and dataclasses.is_dataclass(cur):
+            v = replace_nested(cur, v)
+        elif isinstance(v, list):
+            v = tuple(v)
+        fields[k] = v
+    return dataclasses.replace(obj, **fields)
+
+
 @dataclasses.dataclass(frozen=True)
 class ShapeConfig:
     """One assigned input shape (seq_len x global_batch)."""
@@ -56,10 +71,35 @@ SHAPES_BY_NAME = {s.name: s for s in ALL_SHAPES}
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
-    num_experts: int
+    num_experts: int          # routed experts the router scores
     top_k: int
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25   # GShard training dispatch only
     router_jitter: float = 0.0
+    d_ff: int = 0             # routed expert width (0 = the arch's d_ff)
+    shared_experts: int = 0   # always-on experts of width d_ff, summed into one
+    scoring: str = "softmax"  # softmax | sigmoid (sigmoid adds a per-expert
+    #                           correction bias for selection only)
+    n_group: int = 1          # group-limited routing: experts in n_group
+    topk_group: int = 1       # groups, top-k drawn from the best topk_group
+    routed_scale: float = 1.0  # gates renormalised over top-k, then scaled
+    # experts this device holds, (first, count); count 0 = all of them. The
+    # router still scores every expert; the layer adds only its held
+    # experts' part (expert parallelism without the exchange)
+    held: Tuple[int, int] = (0, 0)
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeScaling:
+    """YaRN context extension of the RoPE frequencies (DeepSeek-V3's
+    ``rope_scaling`` block): frequencies blend between interpolated and
+    original across the band set by beta_fast/beta_slow, and attention
+    logits are scaled by mscale(factor, mscale_all_dim)**2."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -78,12 +118,16 @@ class ArchConfig:
     tie_embeddings: bool = False
     norm: str = "rmsnorm"   # rmsnorm | layernorm
     rope_theta: float = 10000.0
+    rope_scaling: Optional[RopeScaling] = None
     moe: Optional[MoEConfig] = None
+    # leading dense layers (width d_ff) before the MoE layers
+    first_k_dense: int = 0
     # MLA (multi-head latent attention): >0 => cache a per-token
     # kv_lora_rank-dim latent + a qk_rope_head_dim decoupled RoPE head
     # instead of per-head K/V; head_dim doubles as qk_nope/v head width
     kv_lora_rank: int = 0
     qk_rope_head_dim: int = 0
+    q_lora_rank: int = 0     # >0 => queries via wq_a -> RMSNorm -> wq_b
     # hybrid / ssm
     ssm_state: int = 0
     window: int = 0              # sliding-window size for attention heads (0 = full)
@@ -114,7 +158,10 @@ class ArchConfig:
         hd = self.head_dim
         if self.kv_lora_rank:
             c, r = self.kv_lora_rank, self.qk_rope_head_dim
-            q = self.d_model * self.num_heads * (hd + r)
+            q_out = self.num_heads * (hd + r)
+            ql = self.q_lora_rank
+            q = (self.d_model * ql + ql + ql * q_out if ql
+                 else self.d_model * q_out)
             kv_a = self.d_model * (c + r) + c  # wkv_a + latent rmsnorm
             kv_b = c * self.num_heads * 2 * hd
             o = self.num_heads * hd * self.d_model
@@ -129,11 +176,19 @@ class ArchConfig:
         mult = 3 if gated else 2
         return mult * self.d_model * self.d_ff
 
+    def _moe_ffn_params(self) -> int:
+        """Routed experts (all of them), shared experts and the router."""
+        m = self.moe
+        f = m.d_ff or self.d_ff
+        return (3 * self.d_model * f * (m.num_experts + m.shared_experts)
+                + self.d_model * m.num_experts)
+
     def layer_params(self) -> int:
-        """Params of one decoder layer (dense part + routed experts)."""
+        """Params of one decoder layer (dense part + routed experts); the
+        MoE layer's when the model has one."""
         p = self._attn_params() + 2 * self.d_model  # 2 norms
         if self.moe:
-            p += self.moe.num_experts * self._ffn_params() + self.d_model * self.moe.num_experts
+            p += self._moe_ffn_params()
         else:
             p += self._ffn_params()
         if self.family == Family.SSM:
@@ -147,6 +202,9 @@ class ArchConfig:
         emb = self.vocab_size * self.d_model
         unemb = 0 if self.tie_embeddings else self.vocab_size * self.d_model
         layers = self.num_layers * self.layer_params()
+        if self.moe and self.first_k_dense:
+            layers -= self.first_k_dense * (self._moe_ffn_params()
+                                            - self._ffn_params())
         if self.encoder_layers:
             enc = self.encoder_layers * (self._attn_params() + self._ffn_params(gated=False)
                                          + 2 * self.d_model)
@@ -162,8 +220,12 @@ class ArchConfig:
         """Activated params per token (= total for dense; routed top-k for MoE)."""
         if not self.moe:
             return self.total_params()
-        dense_layer = self._attn_params() + 2 * self.d_model + self.d_model * self.moe.num_experts
-        active_ffn = self.moe.top_k * self._ffn_params()
-        layers = self.num_layers * (dense_layer + active_ffn)
+        m = self.moe
+        f = m.d_ff or self.d_ff
+        dense_layer = self._attn_params() + 2 * self.d_model + self.d_model * m.num_experts
+        active_ffn = (m.top_k + m.shared_experts) * 3 * self.d_model * f
+        n_moe = self.num_layers - self.first_k_dense
+        layers = (n_moe * (dense_layer + active_ffn) + self.first_k_dense
+                  * (self._attn_params() + 2 * self.d_model + self._ffn_params()))
         emb = self.vocab_size * self.d_model * (1 if self.tie_embeddings else 2)
         return emb + layers + self.d_model

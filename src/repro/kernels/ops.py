@@ -34,6 +34,14 @@ def attention_kernel_fits(head_dim: int) -> bool:
     return _interpret() or head_dim % 128 == 0
 
 
+def latent_row_width(width: int) -> int:
+    """Stored width of an MLA latent page row: on the TPU rounded up to
+    whole 128-lane tiles (zeros beyond the row), since the latent kernel
+    DMAs whole rows and Mosaic slices a page out of the pool only in whole
+    lane tiles; in interpret mode the row itself."""
+    return width if _interpret() else -(-width // 128) * 128
+
+
 def _oracle(kernel: str, why: str):
     """Gate every jnp-oracle substitution: allowed in interpret mode only.
     On the TPU the caller asked for a kernel the shapes do not fit — an
@@ -269,59 +277,72 @@ def paged_prefill_q8(q, pool_k, pool_v, k_scale, v_scale, block_tables,
                            mesh=mesh, shard_axis=shard_axis)
 
 
-def _paged_dispatch_latent(q, pool_c, block_tables, start, scale_dim: int,
-                           d_v: int):
-    """MLA latent-page dispatch: same interpret-grid guard as the per-head
-    paged dispatch, over the single shared latent pool. The latent kernel
-    takes whole latent rows, so every width tiles on the TPU."""
-    B, Sq, H, L = q.shape
+def _paged_dispatch_latent(q, pool_c, block_tables, start, layer,
+                           scale_dim: float, d_v: int):
+    """MLA latent-page dispatch, with the same interpret-mode guard as the
+    per-head dispatch: a unit is one query block's pass over one block of
+    pages, counted at a full table. The kernel takes whole latent rows,
+    so every width tiles on the TPU."""
+    B, Sq, H, _ = q.shape
+    ps = pool_c.shape[1]
     mps = block_tables.shape[1]
-    if _interpret() and B * H * mps > _PAGED_MAX_INTERPRET_GRID:
-        return _ref.paged_attention_latent(q, pool_c, block_tables, start,
+    units = (B * (Sq // _pa.latent_block_q(Sq, H))
+             * -(-mps // _pa.decode_pages_per_block(ps)))
+    if _interpret() and units > _PAGED_MAX_INTERPRET_GRID:
+        one = jax.lax.dynamic_index_in_dim(pool_c, layer, 0, keepdims=False)
+        return _ref.paged_attention_latent(q, one, block_tables, start,
                                            scale_dim=scale_dim, d_v=d_v)
-    return _pa.paged_attention_latent(q, pool_c, block_tables, start,
+    return _pa.paged_attention_latent(q, pool_c, block_tables, start, layer,
                                       scale_dim=scale_dim, d_v=d_v,
                                       interpret=_interpret())
 
 
-@functools.partial(jax.jit, static_argnames=("scale_dim", "d_v", "mesh",
-                                             "shard_axis"))
-def paged_decode_latent(q, pool_c, block_tables, cache_pos, *,
-                        scale_dim: int, d_v: int, mesh=None,
-                        shard_axis=None):
-    """Single-token decode attention over MLA latent pages.
-
-    q: (B, 1, H, c+r) ABSORBED queries; pool_c: (P, page_size, 1, c+r) —
-    one shared latent row per token, gathered once per page for both the
-    score contraction and (its leading ``d_v`` columns) the value
-    accumulation. ``scale_dim`` is the logical head width the softmax
-    divides by. Returns (B, 1, H, d_v) in latent space — the caller owns
-    the wkv_b value-half and ``wo`` projections. The latent pool itself
-    has no kv-head axis (it stays replicated under tp); mesh/shard_axis
-    (from specs.latent_head_shard_axis) shard the ABSORBED queries/outputs
-    on their head axis through the latent shard_map wrapper."""
+def _latent(q, pool_c, block_tables, start, layer, *, scale_dim: float,
+            d_v: int, mesh=None, shard_axis=None):
     if mesh is not None and shard_axis is not None:
         return _pa.paged_attention_latent_head_sharded(
             _paged_dispatch_latent, mesh, shard_axis, q, pool_c,
-            block_tables, cache_pos, scale_dim=scale_dim, d_v=d_v)
-    return _paged_dispatch_latent(q, pool_c, block_tables, cache_pos,
+            block_tables, start, layer, scale_dim=scale_dim, d_v=d_v)
+    return _paged_dispatch_latent(q, pool_c, block_tables, start, layer,
                                   scale_dim, d_v)
 
 
-@functools.partial(jax.jit, static_argnames=("scale_dim", "d_v", "mesh",
-                                             "shard_axis"))
-def paged_prefill_latent(q, pool_c, block_tables, start, *,
-                         scale_dim: int, d_v: int, mesh=None,
-                         shard_axis=None):
-    """Continuation-chunk prefill attention over MLA latent pages (see
-    paged_decode_latent). q: (B, C, H, c+r); the chunk's latent rows must
-    already be spliced into the slot's pages."""
-    if mesh is not None and shard_axis is not None:
-        return _pa.paged_attention_latent_head_sharded(
-            _paged_dispatch_latent, mesh, shard_axis, q, pool_c,
-            block_tables, start, scale_dim=scale_dim, d_v=d_v)
-    return _paged_dispatch_latent(q, pool_c, block_tables, start,
-                                  scale_dim, d_v)
+def _named(name: str, fn):
+    """``fn`` under the name its jitted call, and so the kernel's
+    instruction in the compiled program, carries."""
+    @functools.wraps(fn)
+    def named(*args, **kwargs):
+        return fn(*args, **kwargs)
+    named.__name__ = named.__qualname__ = name
+    return named
+
+
+_LATENT_STATIC = ("scale_dim", "d_v", "mesh", "shard_axis")
+
+# The latent calls compile under the per-head calls' names, so a device
+# trace shows every decode call as ``%paged_decode.N`` and every chunk
+# call as ``%paged_prefill.N``, whichever cache the model keeps.
+paged_decode_latent = jax.jit(_named("paged_decode", _latent),
+                              static_argnames=_LATENT_STATIC)
+paged_decode_latent.__doc__ = """Single-token decode attention over MLA
+latent pages (the latent kernel, one query row per head).
+
+q: (B, 1, H, c+r) ABSORBED queries; pool_c: the layer-stacked (L, P,
+page_size, 1, c+r) pool, ``layer`` the layer to read — one shared latent
+row per token, each page read in place once for the score contraction
+and (its leading ``d_v`` columns) the value accumulation of every head. Scores divide by sqrt(``scale_dim``). Returns (B, 1, H, d_v)
+in latent space — the caller owns the wkv_b value-half and ``wo``
+projections. The latent pool itself has no kv-head axis (it stays
+replicated under tp); mesh/shard_axis (from specs.latent_head_shard_axis)
+shard the ABSORBED queries/outputs on their head axis through the latent
+shard_map wrapper."""
+
+paged_prefill_latent = jax.jit(_named("paged_prefill", _latent),
+                               static_argnames=_LATENT_STATIC)
+paged_prefill_latent.__doc__ = """Prompt-chunk attention over MLA latent
+pages (see paged_decode_latent). q: (B, C, H, c+r), row i of slot b at
+position ``start[b] + i``; the chunk's latent rows must already be
+spliced into the slot's pages."""
 
 
 @functools.partial(jax.jit, static_argnames=("chunk",))

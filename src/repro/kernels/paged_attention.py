@@ -9,7 +9,9 @@ positions ride in as SCALAR-PREFETCH operands
 computes — the same "compute never waits on a dense, oversized buffer"
 dataflow the paper builds around Ultra RAM placement.
 
-Two kernels, chosen by the query shape (:func:`paged_attention`):
+Three kernels: two for per-head K/V pools, chosen by the query shape
+(:func:`paged_attention`), and one for MLA latent pages
+(:func:`paged_attention_latent`):
 
 * **decode** (:func:`paged_decode_attention`) — one query row per slot at
   position ``pos[b]``. Grid ``(B,)``: program b loops over only its slot's
@@ -33,6 +35,13 @@ Two kernels, chosen by the query shape (:func:`paged_attention`):
   skips the body of a fully-masked page (unallocated, beyond the causal
   frontier, or wholly behind the window), so its compute, not its grid,
   follows the live pages. Each live page is fetched once per query head.
+* **latent** (:func:`paged_attention_latent`) — decode and prefill chunks
+  over pages of one shared (c + r)-wide latent row per token. The decode
+  kernel's structure with the heads as rows: grid ``(B, Sq // block_q)``,
+  each program a block of ``block_q`` positions times every head, looping
+  over only its slot's live blocks up to the block's causal frontier, the
+  pages DMA'd whole and double-buffered, so a page is read once per query
+  block for all heads. Values are the leading c columns of the same rows.
 
 Both mask partially-filled last pages and partially-visible pages per row,
 and zero masked probabilities explicitly (not just sentinel-masked), so a
@@ -46,9 +55,10 @@ folded into the lane axis — q ``(B, Sq, H * hd)``, pools
 ``(P, ps, KV * hd)`` — so one head's block is ``(Sq, hd)`` / ``(ps, hd)``.
 The fold is a reshape, which for a bf16 pool on the TPU's tiled layout
 compiles to a relayout copy of the layer's pool on every chunk call. The
-latent pool has no head axis (``(P, ps, c + r)``, a whole-row block at any
-width), and its absorbed queries are moved head-major, ``(B, H, Sq, c + r)``,
-because c + r (576 for qwen2.5-32b-mla) is not a lane multiple.
+latent kernel DMAs whole ``(ps, width)`` rows, which Mosaic slices only in
+whole 128-lane tiles: on the TPU the pool stores each 576-wide latent row
+as 640 lanes, zeros beyond it (``ops.latent_row_width``), and the queries
+come padded to match.
 """
 from __future__ import annotations
 
@@ -64,20 +74,16 @@ NEG_INF = -1e30   # f32 scratch sentinel (never materialized in low precision)
 
 
 def _kernel(*refs, scale: float, window: int, block_q: int, page_size: int,
-            quantized: bool, d_v: int):
+            quantized: bool):
     """One (slot b, head h, page j) step of the online softmax. Operand
     order follows the pallas_call: scalar prefetch (block table, starts[,
-    k/v page scales]), then q, k[, v], out, then the m/l/acc scratch.
+    k/v page scales]), then q, k, v, out, then the m/l/acc scratch.
 
     ``quantized``: int8 pools, DEQUANTIZED in-register right after the DMA
     with the page's symmetric scale (a scalar-prefetch operand), so HBM
-    only ever moves int8 payload. ``d_v > 0``: MLA latent pages — there is
-    no v pool; values are the leading ``d_v`` columns of the same latent
-    rows, so each page is DMA'd once for both roles."""
+    only ever moves int8 payload."""
     if quantized:
         bt_ref, start_ref, ks_ref, vs_ref, q_ref, k_ref, v_ref, o_ref = refs[:8]
-    elif d_v:
-        bt_ref, start_ref, q_ref, k_ref, o_ref = refs[:5]
     else:
         bt_ref, start_ref, q_ref, k_ref, v_ref, o_ref = refs[:6]
     m_ref, l_ref, acc_ref = refs[-3:]
@@ -120,12 +126,9 @@ def _kernel(*refs, scale: float, window: int, block_q: int, page_size: int,
         p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
         corr = jnp.exp(m_prev - m_new)
         l_ref[...] = l_ref[...] * corr + p.sum(axis=1, keepdims=True)
-        if d_v:
-            v = k[:, :d_v]
-        else:
-            v = v_ref[...].astype(jnp.float32)
-            if quantized:
-                v = v * vs_ref[jnp.maximum(page, 0)]
+        v = v_ref[...].astype(jnp.float32)
+        if quantized:
+            v = v * vs_ref[jnp.maximum(page, 0)]
         acc_ref[...] = acc_ref[...] * corr + jnp.dot(
             p, v, preferred_element_type=jnp.float32)
         m_ref[...] = m_new
@@ -347,46 +350,174 @@ def paged_decode_attention(q, pool_k, pool_v, block_tables, pos, *,
     return out.reshape(B, 1, H, hd)
 
 
-def paged_attention_latent(q, pool_c, block_tables, start, *,
-                           scale_dim: int, d_v: int, interpret: bool = False):
-    """Paged attention over MLA latent pages.
+# query rows per prefill-chunk program of the latent kernel: a block of
+# chunk positions times every head (4 positions at 128 heads)
+LATENT_BLOCK_ROWS = 512
+
+
+def latent_block_q(chunk: int, heads: int) -> int:
+    """Chunk positions per program of :func:`paged_attention_latent`: the
+    largest power of two that divides ``chunk`` and keeps a block's rows
+    (positions times heads) within :data:`LATENT_BLOCK_ROWS`, at least 1."""
+    bq = 1
+    while chunk % (2 * bq) == 0 and 2 * bq * heads <= LATENT_BLOCK_ROWS:
+        bq *= 2
+    return bq
+
+
+def _latent_kernel(bt_ref, start_ref, layer_ref, q_ref, pool_hbm, o_ref,
+                   kbuf, sems, m_ref, l_ref, acc_ref, *, scale: float,
+                   page_size: int, ppb: int, mps: int, d_v: int):
+    """Program (b, i): query block i of slot b — ``block_q`` consecutive
+    positions times every head, one row each, attending together. The
+    loop runs over the slot's blocks of ``ppb`` latent pages from the
+    first up to the block's causal frontier (its last position): each
+    step starts the next block's page DMAs into the other buffer, then
+    waits on and computes the current one. Scores contract the whole
+    latent row; values are its leading ``d_v`` columns, so a page is
+    read once for both roles and for every head."""
+    block_q, heads, width = q_ref.shape
+    rows = block_q * heads
+    keys = ppb * page_size
+    b = pl.program_id(0)
+    pos0 = start_ref[b] + pl.program_id(1) * block_q
+
+    @pl.when((b == 0) & (pl.program_id(1) == 0))
+    def _():
+        # pages past the live span are never copied: their buffer rows
+        # meet p == 0 exactly, which needs them finite, never stale VMEM
+        kbuf[...] = jnp.zeros_like(kbuf)
+
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    # a parked slot (start >= mps * ps, all--1 table) has no live block
+    live = (pos0 >= 0) & (pos0 < mps * page_size)
+    last = jnp.minimum(pos0 + block_q - 1, mps * page_size - 1) // page_size
+    n_blocks = jnp.where(live, last // ppb + 1, 0)
+
+    def each_live_page(blk, slot, fn):
+        """Apply ``fn`` to the copy of every page of block ``blk`` up to
+        the frontier (an unallocated -1 entry copies page 0; the mask
+        drops its keys)."""
+        def body(p, carry):
+            src = jnp.maximum(bt_ref[b, blk * ppb + p], 0)
+            fn(pltpu.make_async_copy(pool_hbm.at[layer_ref[0], src],
+                                     kbuf.at[slot, p], sems.at[slot]))
+            return carry
+        jax.lax.fori_loop(0, jnp.minimum(ppb, last - blk * ppb + 1), body, 0)
+
+    q = q_ref[...].reshape(rows, width)
+    # row r is position pos0 + r // heads; the mask is built from a column
+    # of row positions and a row of key offsets, broadcast once
+    q_pos = pos0 + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) // heads
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, keys), 1)
+
+    def step(blk, carry):
+        slot = blk % 2
+
+        @pl.when(blk + 1 < n_blocks)
+        def _():
+            each_live_page(blk + 1, 1 - slot, lambda c: c.start())
+
+        each_live_page(blk, slot, lambda c: c.wait())
+        k = kbuf[slot].reshape(keys, width)
+        allocated = col >= 0
+        for p in range(ppb):
+            page = bt_ref[b, jnp.minimum(blk * ppb + p, mps - 1)]
+            allocated &= (col // page_size != p) | (page >= 0)
+        ok = (blk * keys + col <= q_pos) & allocated           # (rows, keys)
+        # f32 dot-then-scale and explicitly zeroed masked probabilities,
+        # as in the per-head kernels
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        s = jnp.where(ok, s, NEG_INF)
+        m_prev = m_ref[...]                                    # (rows, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
+        corr = jnp.exp(m_prev - m_new)
+        l_ref[...] = l_ref[...] * corr + p.sum(axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jnp.dot(
+            p, k[:, :d_v].astype(jnp.float32),
+            preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+        return carry
+
+    @pl.when(n_blocks > 0)
+    def _():
+        each_live_page(0, 0, lambda c: c.start())
+
+    jax.lax.fori_loop(0, n_blocks, step, 0)
+    # l == 0 (no live key: a parked slot) yields exactly 0
+    out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+    o_ref[...] = out.reshape(block_q, heads, d_v).astype(o_ref.dtype)
+
+
+def paged_attention_latent(q, pool_c, block_tables, start, layer=None, *,
+                           scale_dim: float, d_v: int,
+                           interpret: bool = False):
+    """Paged attention over MLA latent pages, for decode and prefill
+    chunks alike.
 
     q: (B, Sq, H, c+r) ABSORBED queries (q_nope pushed through wkv_b's key
-    half, concat decoupled RoPE head); pool_c: (P, page_size, 1, c+r) — one
-    latent row per token, no per-head K/V; block_tables/start as in
-    :func:`paged_attention`. ``scale_dim`` is the logical attention width
-    (qk_nope_head_dim + qk_rope_head_dim) the softmax is scaled by — NOT
-    the latent width the dot products contract over. Values are the leading
-    ``d_v`` (= kv_lora_rank) columns of the same latent rows; output is
-    (B, Sq, H, d_v), still in latent space (the caller applies wkv_b's
-    value half and wo)."""
-    B, Sq, H, L = q.shape
-    P, ps, KV, _ = pool_c.shape
+    half, concat decoupled RoPE head), row i of slot b at position
+    ``start[b] + i``; pool_c: (P, page_size, 1, c+r) — one latent row per
+    token, no per-head K/V — or the layer-stacked (L, P, page_size, 1,
+    c+r) pool with ``layer`` the layer to read (a traced scalar; the
+    kernel addresses that layer's pages in the stack, so no copy of the
+    layer's pool is made); block_tables: (B, mps) int32 (-1 =
+    unallocated). Scores are divided by sqrt(``scale_dim``), the logical
+    attention width (qk_nope + qk_rope, YaRN's temperature folded in) —
+    NOT the latent width the dot products contract over. Values are the
+    leading ``d_v`` (= kv_lora_rank) columns of the same latent rows;
+    output is (B, Sq, H, d_v), still in latent space (the caller applies
+    wkv_b's value half and wo).
+
+    Grid ``(B, Sq // block_q)``: each program takes ``block_q``
+    consecutive positions (:func:`latent_block_q`; 1 in decode) times all
+    H heads as one block of query rows, and loops over only its slot's
+    live blocks of ``decode_pages_per_block(page_size)`` pages, up to the
+    block's causal frontier. Whole ``(page_size, c+r)`` pages are DMA'd
+    from the pool as stored, double-buffered, and every head's rows
+    attend a page together, so a page is read once per query block, not
+    once per head. A slot at ``start >= mps * page_size`` (parked) runs
+    no block and returns exactly 0."""
+    B, Sq, H, width = q.shape
+    if pool_c.ndim == 4:
+        pool_c, layer = pool_c[None], 0
+    Lr, P, ps, KV, _ = pool_c.shape
     assert KV == 1, "latent pool carries one shared row per token"
     mps = block_tables.shape[1]
-    kernel = functools.partial(_kernel, scale=1.0 / math.sqrt(scale_dim),
-                               window=0, block_q=Sq, page_size=ps,
-                               quantized=False, d_v=d_v)
-    # one shared latent block per (slot, page) step — every query head h
-    # reads the page named by the prefetched block table
-    kv_map = lambda b, h, j, bt, st: (jnp.maximum(bt[b, j], 0), 0, 0)
-    q_map = lambda b, h, j, bt, st: (b, h, 0, 0)
+    ppb = decode_pages_per_block(ps)
+    bq = latent_block_q(Sq, H)
+    kernel = functools.partial(_latent_kernel,
+                               scale=1.0 / math.sqrt(scale_dim),
+                               page_size=ps, ppb=ppb, mps=mps, d_v=d_v)
+    rows = bq * H
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, H, mps),
-        in_specs=[pl.BlockSpec((None, None, Sq, L), q_map),
-                  pl.BlockSpec((None, ps, L), kv_map)],
-        out_specs=pl.BlockSpec((None, None, Sq, d_v), q_map),
-        scratch_shapes=_scratch(Sq, d_v),
+        num_scalar_prefetch=3,
+        grid=(B, Sq // bq),
+        in_specs=[pl.BlockSpec((None, bq, H, width),
+                               lambda b, i, *_: (b, i, 0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((None, bq, H, d_v),
+                               lambda b, i, *_: (b, i, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((2, ppb, ps, width), pool_c.dtype),
+                        pltpu.SemaphoreType.DMA((2,)),
+                        *_scratch(rows, d_v)],
     )
-    out = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, Sq, d_v), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Sq, H, d_v), q.dtype),
+        # sequential: the page buffer is zeroed once, by program (0, 0)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(jnp.asarray(block_tables, jnp.int32), jnp.asarray(start, jnp.int32),
-      q.transpose(0, 2, 1, 3), pool_c.reshape(P, ps, L))
-    return out.transpose(0, 2, 1, 3)
+      jnp.reshape(jnp.asarray(layer, jnp.int32), (1,)), q,
+      pool_c.reshape(Lr, P, ps, width))
 
 
 def paged_attention(q, pool_k, pool_v, block_tables, start, *,
@@ -418,7 +549,7 @@ def paged_attention(q, pool_k, pool_v, block_tables, start, *,
     quantized = k_scale is not None
     kernel = functools.partial(_kernel, scale=1.0 / math.sqrt(hd),
                                window=window, block_q=Sq, page_size=ps,
-                               quantized=quantized, d_v=0)
+                               quantized=quantized)
     # the kv index maps read the PREFETCHED block table: the page a grid
     # step streams is data-dependent (clamped at 0 for unallocated slots —
     # the body skips those steps entirely, the clamp only keeps the
@@ -504,7 +635,7 @@ def paged_attention_head_sharded(dispatch, mesh, axis, q, pool_k, pool_v,
 
 
 def paged_attention_latent_head_sharded(dispatch, mesh, axis, q, pool_c,
-                                        block_tables, start, *,
+                                        block_tables, start, layer, *,
                                         scale_dim: int, d_v: int):
     """Tensor-parallel dispatch around the LATENT paged kernel.
 
@@ -525,14 +656,14 @@ def paged_attention_latent_head_sharded(dispatch, mesh, axis, q, pool_c,
     from jax.sharding import PartitionSpec as SP
 
     heads = SP(None, None, axis, None)    # q (B,Sq,H,c+r) / out (B,Sq,H,d_v)
-    repl4 = SP(None, None, None, None)    # pool_c (P,ps,1,c+r)
+    pool = SP(*([None] * pool_c.ndim))    # (L,P,ps,1,c+r), replicated
     repl2 = SP(None, None)
     repl1 = SP(None)
 
-    def body(q_, pc_, bt_, st_):
-        return dispatch(q_, pc_, bt_, st_, scale_dim, d_v)
+    def body(q_, pc_, bt_, st_, layer_):
+        return dispatch(q_, pc_, bt_, st_, layer_, scale_dim, d_v)
     return jax.shard_map(
         body, mesh=mesh,
-        in_specs=(heads, repl4, repl2, repl1),
+        in_specs=(heads, pool, repl2, repl1, SP()),
         out_specs=heads, check_vma=False,
-    )(q, pool_c, block_tables, start)
+    )(q, pool_c, block_tables, start, layer)

@@ -174,7 +174,7 @@ class LegacyServer:
             batch["image_embeds"] = jnp.zeros(
                 (self.sc.batch_slots, self.cfg.num_image_tokens, self.cfg.d_model),
                 jnp.float32)
-        logits, cache = self.decode(self.params, self.cache, batch)
+        logits, cache, *_ = self.decode(self.params, self.cache, batch)
         return logits, cache
 
     def step_all(self) -> int:

@@ -286,9 +286,17 @@ def make_prefill(model: Model, *, compute_dtype=jnp.bfloat16,
     return prefill
 
 
+def _stats_kw(moe_stats: bool) -> dict:
+    """``moe_stats``: the step also returns the MoE layers' counts (rows
+    routed to held experts, held experts hit), summed over layers. Only
+    the transformer family's MoE stacks count them."""
+    return {"return_stats": True} if moe_stats else {}
+
+
 def make_prefill_chunk(model: Model, *, compute_dtype=jnp.bfloat16,
                        s_max: int = 0, cache_dtype=jnp.float32,
-                       first: bool = False, attn_impl: str = "einsum"):
+                       first: bool = False, attn_impl: str = "einsum",
+                       moe_stats: bool = False):
     """Parallel (matmul-wide) chunked prefill step builder — the serving
     engine's fast path; the scan prefill (``make_prefill(return_cache=True)``)
     stays the bit-exactness anchor.
@@ -307,7 +315,8 @@ def make_prefill_chunk(model: Model, *, compute_dtype=jnp.bfloat16,
     ``attn_impl='pallas'`` route chunk-local causal attention through the
     K/V-exporting flash kernel). ``first=False``: returns
     ``chunk(params, cache, batch) -> (logits, cache)`` — a continuation at
-    the traced ``cache['pos']``; callers should donate the cache."""
+    the traced ``cache['pos']``; callers should donate the cache. With
+    ``moe_stats`` both return (logits, cache, counts)."""
     if s_max <= 0 and first:
         raise ValueError("first=True requires s_max > 0")
     from repro.configs.base import Family
@@ -316,6 +325,7 @@ def make_prefill_chunk(model: Model, *, compute_dtype=jnp.bfloat16,
         return model.prefill_chunk(params, batch["tokens"], cache,
                                    compute_dtype=compute_dtype,
                                    attn_impl=attn_impl, first=first,
+                                   **_stats_kw(moe_stats),
                                    **_batch_extras(model, batch))
 
     if not first:
@@ -345,7 +355,8 @@ def make_prefill_chunk(model: Model, *, compute_dtype=jnp.bfloat16,
 
 
 def make_prefill_chunk_paged(model: Model, *, compute_dtype=jnp.bfloat16,
-                             attn_impl: str = "kernel"):
+                             attn_impl: str = "kernel",
+                             moe_stats: bool = False):
     """Incremental paged prefill step builder: each call computes one prompt
     chunk for a group of K slots and splices its per-layer K/V STRAIGHT into
     the resident paged cache's pools through the group's block tables —
@@ -361,23 +372,27 @@ def make_prefill_chunk_paged(model: Model, *, compute_dtype=jnp.bfloat16,
     prefix pages — copy-on-write's no-write half). ``attn_impl='kernel'``
     attends through the block-skipping Pallas kernel, ``'einsum'`` through
     the masked-gather reference. Only families with
-    ``supports_paged_prefill`` (dense/MoE/VLM) accept this path."""
+    ``supports_paged_prefill`` (dense/MoE/VLM) accept this path. With
+    ``moe_stats`` it returns (last_logits, cache, counts)."""
     def chunk(params, cache, batch):
         return model.prefill_chunk_paged(
             params, batch["tokens"], cache, bt_rows=batch["bt"],
             start=batch["start"], write_floor=batch["floor"],
             compute_dtype=compute_dtype, attn_impl=attn_impl,
+            **_stats_kw(moe_stats),
             **_batch_extras(model, batch))
     return chunk
 
 
 def make_decode_step(model: Model, *, compute_dtype=jnp.bfloat16,
-                     paged_attn_impl: Optional[str] = None):
+                     paged_attn_impl: Optional[str] = None,
+                     moe_stats: bool = False):
     """One-token decode against a KV/state cache; cache buffers are donated.
     ``paged_attn_impl`` ('kernel' | 'einsum') selects the paged-cache read
     path for the families that page through ``attention_decode_paged``
     (dense/MoE/VLM/encdec); None keeps each family's default (the
-    masked-einsum reference) — hybrid's ring path has its own gather."""
+    masked-einsum reference) — hybrid's ring path has its own gather.
+    With ``moe_stats`` the step returns (logits, cache, counts)."""
     # function-level import: launch.steps is imported by serve.engine, and
     # serve/__init__ imports engine — a module-level kvcache import here
     # would cycle through the serve package at import time
@@ -388,11 +403,10 @@ def make_decode_step(model: Model, *, compute_dtype=jnp.bfloat16,
         extra["paged_attn_impl"] = paged_attn_impl
 
     def decode(params, cache, batch):
-        logits, cache = model.decode_step(params, batch["token"], cache,
-                                          compute_dtype=compute_dtype,
-                                          **extra,
-                                          **_batch_extras(model, batch))
-        return logits, cache
+        return model.decode_step(params, batch["token"], cache,
+                                 compute_dtype=compute_dtype, **extra,
+                                 **_stats_kw(moe_stats),
+                                 **_batch_extras(model, batch))
     return decode
 
 

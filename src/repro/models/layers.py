@@ -10,7 +10,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -64,16 +64,48 @@ def norm_logical(kind: str):
 
 
 # ---------------------------------------------------------------- RoPE
-def rope_freqs(head_dim: int, theta: float):
-    return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention-temperature factor: 0.1 * mscale * ln(factor) + 1."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
 
 
-def apply_rope(x, positions, theta: float):
-    """x: (..., S, H, hd); positions: (..., S)."""
+def _yarn_blend(head_dim: int, theta: float, scaling):
+    """Per-frequency weight of the ORIGINAL frequency under YaRN (1 keeps
+    it, 0 takes it interpolated by ``factor``): a linear ramp between the
+    dimensions that complete beta_fast and beta_slow rotations over the
+    original context, as DeepSeek-V3's modelling code defines it."""
+    def dim_at(rotations):
+        return (head_dim * math.log(scaling.original_max_position_embeddings
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+    low = max(math.floor(dim_at(scaling.beta_fast)), 0)
+    high = min(math.ceil(dim_at(scaling.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return 1.0 - ramp
+
+
+def rope_freqs(head_dim: int, theta: float, scaling=None):
+    freqs = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+    if scaling is None:
+        return freqs
+    keep = _yarn_blend(head_dim, theta, scaling)
+    return freqs / scaling.factor * (1.0 - keep) + freqs * keep
+
+
+def apply_rope(x, positions, theta: float, scaling=None):
+    """x: (..., S, H, hd); positions: (..., S). ``scaling``: a
+    ``configs.base.RopeScaling`` (YaRN) or None."""
     hd = x.shape[-1]
-    freqs = rope_freqs(hd, theta)  # (hd/2,)
+    freqs = rope_freqs(hd, theta, scaling)  # (hd/2,)
     angles = positions[..., :, None].astype(jnp.float32) * freqs  # (..., S, hd/2)
     sin, cos = jnp.sin(angles), jnp.cos(angles)
+    if scaling is not None and scaling.mscale != scaling.mscale_all_dim:
+        m = (yarn_mscale(scaling.factor, scaling.mscale)
+             / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+        sin, cos = sin * m, cos * m
     sin = sin[..., :, None, :]  # broadcast over heads
     cos = cos[..., :, None, :]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
@@ -101,7 +133,7 @@ def _dense(key, shape, scale_dim=None, dtype=jnp.float32):
 # scales, the MoE router, recurrent-state parameters) keeps its dtype: the
 # router and the RWKV decay terms are used in float32.
 CAST_ON_USE = frozenset({
-    "wq", "wk", "wv", "wo", "bq", "bk", "bv", "wkv_a", "wkv_b",
+    "wq", "wk", "wv", "wo", "bq", "bk", "bv", "wq_a", "wq_b", "wkv_a", "wkv_b",
     "w_up", "w_gate", "w_down", "b_up", "b_down", "w_in", "w_out", "table"})
 
 
@@ -623,6 +655,18 @@ def paged_row_indices(block_tables, page_size: int, n_rows: int):
     return phys, ok
 
 
+def fit_rows(x, width: int):
+    """``x`` with its last axis cut or zero-padded to ``width``: rows
+    moving between a pool stored wider than the model's rows (lane-aligned
+    latent pages on the TPU) and a dense cache."""
+    w = x.shape[-1]
+    if w > width:
+        return x[..., :width]
+    if w < width:
+        return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, width - w)])
+    return x
+
+
 def paged_write_target(block_tables, idx, page_size: int):
     """Write-side block-table lookup shared by every paged decode path.
     idx: (B,) logical row per slot (sequence position, or ring index for the
@@ -1002,6 +1046,8 @@ class MLADims:
     qk_rope_head_dim: int    # r: decoupled RoPE key head width
     head_dim: int            # qk_nope width == value head width
     rope_theta: float = 10000.0
+    q_lora_rank: int = 0     # >0: queries via wq_a -> RMSNorm -> wq_b
+    rope_scaling: Optional[object] = None   # configs.base.RopeScaling
 
     @property
     def latent_dim(self) -> int:
@@ -1009,33 +1055,51 @@ class MLADims:
         return self.kv_lora_rank + self.qk_rope_head_dim
 
     @property
-    def scale_dim(self) -> int:
-        """Softmax scale denominator: the EFFECTIVE per-head query width
-        (qk_nope + rope), not the latent width the absorbed dot runs over."""
-        return self.head_dim + self.qk_rope_head_dim
+    def scale_dim(self) -> float:
+        """Softmax scale denominator: scores are divided by its square
+        root. The EFFECTIVE per-head query width (qk_nope + rope), not the
+        latent width the absorbed dot runs over; under YaRN divided by
+        mscale**4, so that the scale is (qk_nope + rope)**-0.5 * mscale**2
+        as DeepSeek-V3 defines it."""
+        width = self.head_dim + self.qk_rope_head_dim
+        sc = self.rope_scaling
+        if sc is None or not sc.mscale_all_dim:
+            return width
+        return width / yarn_mscale(sc.factor, sc.mscale_all_dim) ** 4
 
 
 def mla_init(key, dims: MLADims):
-    ks = jax.random.split(key, 4)
     D, H = dims.d_model, dims.num_heads
     c, r, hd = dims.kv_lora_rank, dims.qk_rope_head_dim, dims.head_dim
-    return {
-        "wq": _dense(ks[0], (D, H * (hd + r))),
+    ql = dims.q_lora_rank
+    ks = jax.random.split(key, 5 if ql else 4)
+    p = {
         "wkv_a": _dense(ks[1], (D, c + r)),
         "kv_norm": jnp.zeros((c,), jnp.float32),
         "wkv_b": _dense(ks[2], (c, H * 2 * hd), scale_dim=c),
         "wo": _dense(ks[3], (H * hd, D), scale_dim=H * hd),
     }
+    if ql:
+        p.update(wq_a=_dense(ks[0], (D, ql)),
+                 q_norm=jnp.zeros((ql,), jnp.float32),
+                 wq_b=_dense(ks[4], (ql, H * (hd + r))))
+    else:
+        p["wq"] = _dense(ks[0], (D, H * (hd + r)))
+    return p
 
 
 def mla_logical(dims: MLADims):
-    return {
-        "wq": ("fsdp", "heads"),
+    p = {
         "wkv_a": ("fsdp", None),
         "kv_norm": (None,),
         "wkv_b": (None, "heads"),
         "wo": ("heads", "fsdp"),
     }
+    if dims.q_lora_rank:
+        p.update(wq_a=("fsdp", None), q_norm=(None,), wq_b=(None, "heads"))
+    else:
+        p["wq"] = ("fsdp", "heads")
+    return p
 
 
 def _mla_wkv_b(params, dims: MLADims, dtype):
@@ -1049,15 +1113,24 @@ def _mla_wkv_b(params, dims: MLADims, dtype):
     return wb_k, wb_v
 
 
+def mla_queries(params, x):
+    """Per-head queries (B, S, H * (hd + r)) before RoPE and absorption:
+    one ``wq``, or the q-LoRA ``wq_a`` -> RMSNorm -> ``wq_b``."""
+    if "wq_a" in params:
+        ql = rmsnorm(x @ params["wq_a"].astype(x.dtype), params["q_norm"])
+        return ql @ params["wq_b"].astype(x.dtype)
+    return x @ params["wq"].astype(x.dtype)
+
+
 def mla_absorbed_queries(params, x, dims: MLADims, positions):
     """Project x to ABSORBED queries (B, S, H, c_kv + r): the nope half is
     pushed through wb_k into latent space, the rope half gets RoPE; their
     concatenation dots directly against cached latent rows."""
     B, S, _ = x.shape
     H, hd, r = dims.num_heads, dims.head_dim, dims.qk_rope_head_dim
-    q = (x @ params["wq"].astype(x.dtype)).reshape(B, S, H, hd + r)
+    q = mla_queries(params, x).reshape(B, S, H, hd + r)
     q_nope, q_pe = q[..., :hd], q[..., hd:]
-    q_pe = apply_rope(q_pe, positions, dims.rope_theta)
+    q_pe = apply_rope(q_pe, positions, dims.rope_theta, dims.rope_scaling)
     wb_k, _ = _mla_wkv_b(params, dims, x.dtype)
     q_abs = jnp.einsum("bshd,hdc->bshc", q_nope, wb_k)
     return jnp.concatenate([q_abs, q_pe], axis=-1)
@@ -1069,7 +1142,8 @@ def mla_latent_rows(params, x, dims: MLADims, positions):
     c = dims.kv_lora_rank
     kv = x @ params["wkv_a"].astype(x.dtype)     # (B, S, c + r)
     ckv = rmsnorm(kv[..., :c], params["kv_norm"])
-    k_pe = apply_rope(kv[..., None, c:], positions, dims.rope_theta)
+    k_pe = apply_rope(kv[..., None, c:], positions, dims.rope_theta,
+                      dims.rope_scaling)
     return jnp.concatenate([ckv[:, :, None, :], k_pe], axis=-1)
 
 
@@ -1145,29 +1219,52 @@ def mla_attention_prefill_chunk(params, x, dims: MLADims, cache_c, start,
     return _mla_out(params, attn, dims, x), cache_c
 
 
-def mla_attention_decode_paged(params, x, dims: MLADims, pool_c,
+def _latent_write(pool, layer, rows, row_idx, valid):
+    """Scatter latent rows into layer ``layer`` of the layer-stacked pool
+    (L, P, ps, 1, width), in place: rows (..., 1, width) at flattened pool
+    rows ``row_idx`` (...); invalid writes are dropped."""
+    Lr, P, ps = pool.shape[:3]
+    flat = pool.reshape((Lr, P * ps) + pool.shape[3:])
+    idx = jnp.where(valid, row_idx, P * ps)          # OOB -> dropped
+    flat = flat.at[layer, idx].set(rows.astype(flat.dtype), mode="drop")
+    return flat.reshape(pool.shape)
+
+
+def _latent_view(pool, layer, phys, width):
+    """The reference read: layer ``layer``'s rows at flattened pool rows
+    ``phys`` (B, n), cut to the latent's own width."""
+    one = jax.lax.dynamic_index_in_dim(pool, layer, 0, keepdims=False)
+    P, ps = one.shape[:2]
+    return fit_rows(one.reshape((P * ps,) + one.shape[2:])[phys], width)
+
+
+def mla_attention_decode_paged(params, x, dims: MLADims, pool_c, layer,
                                block_tables, cache_pos, positions,
                                impl: str = "einsum"):
     """Single-token MLA decode against a LATENT page pool.
 
-    pool_c: one layer's (P, page_size, 1, c_kv + r) latent pool slice — a
-    page row is the whole per-token cache. Write/gather indirection is the
-    standard block-table machinery (same helpers as the K/V path); the read
-    is the absorbed dot over latent rows, values = the leading c_kv columns
-    of the SAME gathered block. ``impl='kernel'`` routes through the
-    latent-page Pallas kernel (``ops.paged_decode_latent``); 'einsum' is the
-    masked-gather reference, bit-exact with ``mla_attention_decode`` at
-    page_size == s_max. Returns (out, new_pool)."""
+    pool_c: the layer-stacked (L, P, page_size, 1, width) latent pool
+    (width c_kv + r, lane-padded on the TPU) — a page row is the whole
+    per-token cache; ``layer`` picks this layer's pool, which the write
+    and the kernel address in place (no copy of the layer's pool out of
+    the stack and back). Write/gather indirection is the standard
+    block-table machinery; the read is the absorbed dot over latent rows,
+    values = the leading c_kv columns of the SAME rows. ``impl='kernel'``
+    routes through the latent Pallas kernel (``ops.paged_decode_latent``);
+    'einsum' is the masked-gather reference, bit-exact with
+    ``mla_attention_decode`` at page_size == s_max. Returns (out,
+    new_pool)."""
     H, c = dims.num_heads, dims.kv_lora_rank
     q = mla_absorbed_queries(params, x, dims, positions)     # (B,1,H,c+r)
     rows = mla_latent_rows(params, x, dims, positions)       # (B,1,1,c+r)
-    P, ps = pool_c.shape[:2]
+    ps, width = pool_c.shape[2], pool_c.shape[-1]
     B = q.shape[0]
     n_rows = block_tables.shape[1] * ps
     safe_pos = jnp.clip(cache_pos, 0, n_rows - 1)
     w_row, page_ok = paged_write_target(block_tables, safe_pos, ps)
     w_ok = (cache_pos >= 0) & (cache_pos < n_rows) & page_ok
-    pool_c = paged_write_rows(pool_c, rows[:, 0], w_row, w_ok)
+    pool_c = _latent_write(pool_c, layer, fit_rows(rows[:, 0], width),
+                           w_row, w_ok)
 
     if impl == "kernel":
         from repro.kernels import ops as kops
@@ -1175,13 +1272,14 @@ def mla_attention_decode_paged(params, x, dims: MLADims, pool_c,
         # tp shards the ABSORBED queries/outputs on their head axis; the
         # latent pool itself is replicated (no kv-head axis to shard)
         tp_mesh, tp_axis = _sp.latent_head_shard_axis(H)
-        attn = kops.paged_decode_latent(q, pool_c, block_tables, cache_pos,
+        attn = kops.paged_decode_latent(fit_rows(q, width), pool_c,
+                                        block_tables, cache_pos, layer,
                                         scale_dim=dims.scale_dim, d_v=c,
                                         mesh=tp_mesh, shard_axis=tp_axis)
     else:
         qg = q.reshape(B, 1, 1, H, dims.latent_dim)
         phys, ok = paged_row_indices(block_tables, ps, n_rows)
-        view = pool_c.reshape(P * ps, 1, dims.latent_dim)[phys]
+        view = _latent_view(pool_c, layer, phys, dims.latent_dim)
         k_positions = jnp.where(ok, jnp.arange(n_rows)[None, :], INACTIVE_POS)
         m, l, acc = _decode_sdpa_local(qg, view, view[..., :c],
                                        cache_pos[:, None], k_positions, 0,
@@ -1192,19 +1290,20 @@ def mla_attention_decode_paged(params, x, dims: MLADims, pool_c,
 
 
 def mla_attention_prefill_chunk_paged(params, x, dims: MLADims, pool_c,
-                                      block_tables, positions, write_floor,
-                                      impl: str = "kernel"):
-    """Multi-token MLA prefill chunk splicing latent rows DIRECTLY into the
-    page pool (incremental splice) and attending through the block table —
-    the latent twin of ``attention_prefill_chunk_paged``. Rows below
-    ``write_floor`` (aliased prefix pages) are dropped, exactly as in the
-    K/V path: COW materialisation copies latent rows, never per-head K/V.
-    Returns (out (B,C,D), new_pool)."""
+                                      layer, block_tables, positions,
+                                      write_floor, impl: str = "kernel"):
+    """Multi-token MLA prefill chunk splicing latent rows DIRECTLY into
+    layer ``layer`` of the layer-stacked page pool (incremental splice, in
+    place) and attending through the block table — the latent twin of
+    ``attention_prefill_chunk_paged``. Rows below ``write_floor`` (aliased
+    prefix pages) are dropped, exactly as in the K/V path: COW
+    materialisation copies latent rows, never per-head K/V. Returns
+    (out (B,C,D), new_pool)."""
     H, c = dims.num_heads, dims.kv_lora_rank
     q = mla_absorbed_queries(params, x, dims, positions)     # (B,C,H,c+r)
     rows = mla_latent_rows(params, x, dims, positions)       # (B,C,1,c+r)
     B, C = positions.shape
-    P, ps = pool_c.shape[:2]
+    ps, width = pool_c.shape[2], pool_c.shape[-1]
     mps = block_tables.shape[1]
     n_rows = mps * ps
 
@@ -1212,23 +1311,22 @@ def mla_attention_prefill_chunk_paged(params, x, dims: MLADims, pool_c,
         block_tables, jnp.clip(positions // ps, 0, mps - 1), axis=1)
     w_ok = ((page >= 0) & (positions >= write_floor)
             & (positions >= 0) & (positions < n_rows))
-    w_rows = jnp.where(w_ok, page * ps + positions % ps, P * ps)  # drop
-    flat = pool_c.reshape(P * ps, 1, dims.latent_dim)
-    flat = flat.at[w_rows].set(rows.astype(flat.dtype), mode="drop")
-    pool_c = flat.reshape(pool_c.shape)
+    pool_c = _latent_write(pool_c, layer, fit_rows(rows, width),
+                           page * ps + positions % ps, w_ok)
 
     if impl == "kernel":
         from repro.kernels import ops as kops
         from repro.sharding import specs as _sp
         tp_mesh, tp_axis = _sp.latent_head_shard_axis(H)
-        attn = kops.paged_prefill_latent(q, pool_c, block_tables,
-                                         positions[:, 0],
-                                         scale_dim=dims.scale_dim, d_v=c,
-                                         mesh=tp_mesh, shard_axis=tp_axis)
+        attn = kops.paged_prefill_latent(fit_rows(q, width), pool_c,
+                                         block_tables, positions[:, 0],
+                                         layer, scale_dim=dims.scale_dim,
+                                         d_v=c, mesh=tp_mesh,
+                                         shard_axis=tp_axis)
     else:
         qg = q.reshape(B, C, 1, H, dims.latent_dim)
         phys, ok = paged_row_indices(block_tables, ps, n_rows)
-        view = flat[phys]                        # (B, n_rows, 1, c+r)
+        view = _latent_view(pool_c, layer, phys, dims.latent_dim)
         scores = jnp.einsum("bqkgh,bskh->bkgqs", qg, view.astype(q.dtype)
                             ).astype(jnp.float32) / math.sqrt(dims.scale_dim)
         k_pos = jnp.where(ok, jnp.arange(n_rows)[None, :], INACTIVE_POS)
@@ -1289,31 +1387,162 @@ def mlp(params, x, act: str = "silu"):
 @dataclasses.dataclass(frozen=True)
 class MoEDims:
     d_model: int
-    d_ff: int
-    num_experts: int
+    d_ff: int                 # routed expert width
+    num_experts: int          # experts the router scores
     top_k: int
     capacity_factor: float = 1.25
     group_size: int = 128     # tokens per dispatch group (GShard-style)
+    shared_d_ff: int = 0      # width of the always-on shared expert (0: none)
+    scoring: str = "softmax"  # softmax | sigmoid (+ selection-only bias)
+    n_group: int = 1          # group-limited routing (see moe_route)
+    topk_group: int = 1
+    routed_scale: float = 1.0
+    held: Tuple[int, int] = (0, 0)   # (first, count) held here; 0 = all
+
+    @property
+    def held_range(self) -> Tuple[int, int]:
+        return self.held[0], self.held[1] or self.num_experts
+
+    @property
+    def gshard(self) -> bool:
+        """Can the GShard capacity dispatch (:func:`moe`) compute this
+        layer? Only a softmax router over all-held experts with nothing
+        beside them."""
+        return (self.scoring == "softmax" and not self.shared_d_ff
+                and self.n_group == 1 and self.routed_scale == 1.0
+                and self.held_range == (0, self.num_experts))
 
 
 def moe_init(key, dims: MoEDims):
+    """Router over all ``num_experts``; weights of the HELD experts only.
+    Each expert's three matrices are drawn from their key folded with the
+    expert's index, so every share of one layer key holds the same experts
+    as the whole layer. A sigmoid router adds its selection bias (zeros), a
+    shared expert is drawn as a gated MLP from the layer key folded with E."""
     ks = jax.random.split(key, 4)
     E, D, F = dims.num_experts, dims.d_model, dims.d_ff
-    return {
+    first, n = dims.held_range
+    ids = jnp.arange(first, first + n)
+
+    def draw(k, shape, fan_in):
+        return jax.vmap(lambda e: _dense(jax.random.fold_in(k, e), shape,
+                                         scale_dim=fan_in))(ids)
+
+    p = {
         "router": _dense(ks[0], (D, E)),
-        "w_gate": _dense(ks[1], (E, D, F), scale_dim=D),
-        "w_up": _dense(ks[2], (E, D, F), scale_dim=D),
-        "w_down": _dense(ks[3], (E, F, D), scale_dim=F),
+        "w_gate": draw(ks[1], (D, F), D),
+        "w_up": draw(ks[2], (D, F), D),
+        "w_down": draw(ks[3], (F, D), F),
     }
+    if dims.scoring == "sigmoid":
+        p["router_bias"] = jnp.zeros((E,), jnp.float32)
+    if dims.shared_d_ff:
+        p["shared"] = mlp_init(jax.random.fold_in(key, E), D, dims.shared_d_ff)
+    return p
 
 
-def moe_logical():
-    return {
+def moe_logical(dims: Optional[MoEDims] = None):
+    p = {
         "router": (None, None),
         "w_gate": ("expert", "fsdp", None),
         "w_up": ("expert", "fsdp", None),
         "w_down": ("expert", None, "fsdp"),
     }
+    if dims is not None and dims.scoring == "sigmoid":
+        p["router_bias"] = (None,)
+    if dims is not None and dims.shared_d_ff:
+        p["shared"] = mlp_logical()
+    return p
+
+
+def moe_route(params, xt, dims: MoEDims):
+    """Routing of tokens ``xt`` (T, D): (expert ids (T, K), gates (T, K)
+    float32). The router runs in float32 at every output. Softmax scores
+    take their top k; sigmoid scores add the per-expert correction bias
+    for SELECTION only. With ``n_group`` > 1 the experts fall into equal
+    groups, each scored by the sum of its two best biased scores (the best
+    one for softmax), and the top k come from the best ``topk_group``
+    groups. Gates are the chosen experts' unbiased scores, renormalised,
+    times ``routed_scale``."""
+    T = xt.shape[0]
+    E, K, G = dims.num_experts, dims.top_k, dims.n_group
+    logits = jnp.dot(xt.astype(jnp.float32),
+                     params["router"].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)        # (T, E)
+    if dims.scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        choice = scores + params["router_bias"].astype(jnp.float32)
+    else:
+        scores = jax.nn.softmax(logits, axis=-1)
+        choice = scores
+    if G > 1:
+        grouped = choice.reshape(T, G, E // G)
+        if dims.scoring == "sigmoid":
+            g_score = jax.lax.top_k(grouped, 2)[0].sum(-1)      # (T, G)
+        else:
+            g_score = grouped.max(-1)
+        _, g_idx = jax.lax.top_k(g_score, dims.topk_group)
+        keep = jnp.zeros((T, G), bool).at[
+            jnp.arange(T)[:, None], g_idx].set(True)
+        choice = jnp.where(keep[:, :, None], grouped,
+                           -jnp.inf).reshape(T, E)
+    _, idx = jax.lax.top_k(choice, K)
+    gates = jnp.take_along_axis(scores, idx, axis=-1)
+    gates = gates / jnp.clip(gates.sum(-1, keepdims=True), 1e-9)
+    return idx, gates * dims.routed_scale
+
+
+def moe_dropless(params, x, dims: MoEDims):
+    """The MoE layer as serving computes it: every token's routed experts,
+    with no capacity and no drop, so a token's output never depends on
+    the other tokens of its batch. x: (B, S, D) -> (y (B, S, D), counts).
+
+    Only the held experts' part is computed: the (token, expert) pairs
+    routed to a held expert are sorted by expert and run as one grouped
+    matmul per projection (``jax.lax.ragged_dot``) over exactly those
+    rows; pairs routed elsewhere contribute nothing here (on an expert-
+    parallel deployment another device adds them). The shared expert is
+    added once. ``counts`` (2,) int32: rows routed to held experts, and
+    held experts that got at least one row.
+
+    With ``params["layer"]`` the expert weights come stacked over a
+    model's MoE layers, (L, n, ...), and this is layer ``layer``: the
+    grouped matmuls take the whole stack as L * n groups, the other
+    layers' empty, since a slice of the stack as their operand would be a
+    copy of the layer's experts at every call."""
+    B, S, D = x.shape
+    T, K = B * S, dims.top_k
+    xt = x.reshape(T, D)
+    idx, gates = moe_route(params, xt, dims)
+    first, n = dims.held_range
+    local = idx.reshape(-1) - first                              # (T*K,)
+    held = (local >= 0) & (local < n)
+    stacked = params["w_gate"].ndim == 4
+    groups = params["w_gate"].shape[0] * n if stacked else n
+    group = jnp.where(held, local + (params["layer"] * n if stacked else 0),
+                      groups)
+    order = jnp.argsort(group, stable=True)
+    sizes = jnp.sum(jax.nn.one_hot(group, groups + 1, dtype=jnp.int32),
+                    0)[:groups]
+    rows = xt[order // K]                                        # (T*K, D)
+
+    def grouped(a, w):
+        w = w.reshape((groups,) + w.shape[-2:])
+        return jax.lax.ragged_dot(a, w.astype(x.dtype), sizes)
+
+    h = (jax.nn.silu(grouped(rows, params["w_gate"]))
+         * grouped(rows, params["w_up"]))
+    y = grouped(h, params["w_down"])
+    # back to (token, k) order; the sum over a token's k experts runs in
+    # a fixed order, whatever else the batch routed
+    y = jnp.zeros_like(y).at[order].set(y, unique_indices=True)
+    y = jnp.where(held[:, None], y, 0).reshape(T, K, D)
+    out = jnp.einsum("tkd,tk->td", y.astype(jnp.float32), gates)
+    out = out.astype(x.dtype).reshape(B, S, D)
+    if "shared" in params:
+        out = out + mlp(params["shared"], x)
+    counts = jnp.stack([sizes.sum(), (sizes > 0).sum()]).astype(jnp.int32)
+    return out, counts
 
 
 def moe(params, x, dims: MoEDims):

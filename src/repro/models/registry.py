@@ -13,7 +13,7 @@ import jax.numpy as jnp
 from repro.configs.base import ArchConfig, Family, ShapeConfig
 from repro.models import encdec, hybrid, ssm, transformer
 from repro.models.encdec import ENC_LEN
-from repro.models.layers import INACTIVE_POS
+from repro.models.layers import INACTIVE_POS, fit_rows
 
 _FAMILY_MODULES = {
     Family.DENSE: transformer,
@@ -172,7 +172,8 @@ def cache_capacity(cfg: ArchConfig, s_max: int) -> int:
 
 
 def init_paged_cache(model: Model, batch: int, s_max: int, *, page_size: int,
-                     num_pages: int, dtype=jnp.bfloat16):
+                     num_pages: int, dtype=jnp.bfloat16,
+                     row_width: Optional[int] = None):
     """Paged serving cache: the dense per-slot K/V (and hybrid ring
     ``slot_pos``) leaves become shared page pools (L, num_pages, page_size,
     ...) addressed through per-slot ``block_tables`` (B, max_pages_per_slot).
@@ -184,28 +185,33 @@ def init_paged_cache(model: Model, batch: int, s_max: int, *, page_size: int,
     s_max rows (the bit-exactness anchor vs the dense path). Hybrid caches
     additionally carry a ``ring_iota`` (W,) leaf whose shape tells the decode
     path the ring width. The SSM family has no K/V to page — callers should
-    keep it dense."""
+    keep it dense. ``row_width``: the pools' last axis, when rows are
+    stored wider than the model's (zeros beyond it). The dense cache is
+    only shaped, never allocated, for the leaves the pools replace."""
     cfg = model.cfg
     if cfg.family == Family.SSM:
         raise ValueError("rwkv/ssm caches are O(1) in s_max; use init_cache")
     if s_max % page_size:
         raise ValueError(f"s_max {s_max} must be a multiple of page_size "
                          f"{page_size} (paged view == dense view)")
-    dense = model.init_cache(batch, s_max, dtype)
+    shapes = jax.eval_shape(lambda: model.init_cache(batch, s_max, dtype))
+    dense = None
     mps = s_max // page_size
     out = {}
-    for key, leaf in dense.items():
+    for key, leaf in shapes.items():
         if key in ("k", "v"):               # (L, B, C, KV, hd) -> pool
             Lr, _, _, KV, hd = leaf.shape
-            out[key] = jnp.zeros((Lr, num_pages, page_size, KV, hd),
-                                 leaf.dtype)
+            out[key] = jnp.zeros((Lr, num_pages, page_size, KV,
+                                  row_width or hd), leaf.dtype)
         elif key == "slot_pos":             # hybrid ring positions -> pool
             out[key] = jnp.full((leaf.shape[0], num_pages, page_size), -1,
                                 jnp.int32)
         elif key == "pos":
             out[key] = jnp.full((batch,), INACTIVE_POS, jnp.int32)
         else:
-            out[key] = leaf
+            if dense is None:
+                dense = model.init_cache(batch, s_max, dtype)
+            out[key] = dense[key]
     out["block_tables"] = jnp.full((batch, mps), -1, jnp.int32)
     if cfg.family == Family.HYBRID:
         out["ring_iota"] = jnp.arange(cache_capacity(cfg, s_max),
@@ -236,8 +242,11 @@ def insert_cache_rows_paged(cache, request_cache, slots, phys_rows):
             Lr, P, ps = leaf.shape[:3]
             flat = leaf.reshape((Lr, P * ps) + leaf.shape[3:])
             C = phys_rows.shape[1]
-            flat = flat.at[:, phys_rows].set(
-                req[:, :, :C].astype(leaf.dtype), mode="drop")
+            rows = req[:, :, :C]
+            if key in ("k", "v"):           # rows stored wider than req's
+                rows = fit_rows(rows, leaf.shape[-1])
+            flat = flat.at[:, phys_rows].set(rows.astype(leaf.dtype),
+                                             mode="drop")
             out[key] = flat.reshape(leaf.shape)
         elif key == "pos":
             out[key] = leaf.at[slots].set(jnp.asarray(req, leaf.dtype))
@@ -299,7 +308,7 @@ def seed_prefix_cache(model: Model, cache, phys_rows, row_ok, pos,
         pool = cache[key]                   # (L, P, ps, KV, hd)
         Lr, P, ps = pool.shape[:3]
         flat = pool.reshape((Lr, P * ps) + pool.shape[3:])
-        rows = flat[:, idx]                 # (L, K, s_max, KV, hd)
+        rows = fit_rows(flat[:, idx], out[key].shape[-1])
         mask = row_ok.reshape((1,) + row_ok.shape + (1,) * (rows.ndim - 3))
         out[key] = jnp.where(mask, rows, 0).astype(out[key].dtype)
     out["pos"] = jnp.asarray(pos, jnp.int32)
@@ -358,11 +367,16 @@ def reduced_config(cfg: ArchConfig, **overrides) -> ArchConfig:
         ssm_state=cfg.ssm_state and 4,
         kv_lora_rank=8 if cfg.kv_lora_rank else 0,
         qk_rope_head_dim=2 if cfg.qk_rope_head_dim else 0,
+        q_lora_rank=16 if cfg.q_lora_rank else 0,
+        first_k_dense=min(cfg.first_k_dense, 1),
     )
     if cfg.moe:
-        from repro.configs.base import MoEConfig
-        defaults["moe"] = MoEConfig(num_experts=4, top_k=2,
-                                    capacity_factor=cfg.moe.capacity_factor)
+        # grouped routing keeps its structure: 4 groups of 2, the best 2
+        grouped = cfg.moe.n_group > 1
+        defaults["moe"] = dataclasses.replace(
+            cfg.moe, num_experts=8 if grouped else 4, top_k=2,
+            d_ff=32 if cfg.moe.d_ff else 0, held=(0, 0),
+            n_group=4 if grouped else 1, topk_group=2 if grouped else 1)
     if cfg.cross_attn_every:
         defaults["num_layers"] = 2 * cfg.cross_attn_every  # 2 super-layers
     defaults.update(overrides)

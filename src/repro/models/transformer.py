@@ -34,7 +34,8 @@ def _mla_dims(cfg: ArchConfig) -> L.MLADims:
     return L.MLADims(
         d_model=cfg.d_model, num_heads=cfg.num_heads,
         kv_lora_rank=cfg.kv_lora_rank, qk_rope_head_dim=cfg.qk_rope_head_dim,
-        head_dim=cfg.head_dim, rope_theta=cfg.rope_theta)
+        head_dim=cfg.head_dim, rope_theta=cfg.rope_theta,
+        q_lora_rank=cfg.q_lora_rank, rope_scaling=cfg.rope_scaling)
 
 
 def _is_mla(cfg: ArchConfig) -> bool:
@@ -55,9 +56,19 @@ def _cross_dims(cfg: ArchConfig) -> L.AttnDims:
 
 
 def _moe_dims(cfg: ArchConfig) -> L.MoEDims:
-    return L.MoEDims(d_model=cfg.d_model, d_ff=cfg.d_ff,
-                     num_experts=cfg.moe.num_experts, top_k=cfg.moe.top_k,
-                     capacity_factor=cfg.moe.capacity_factor)
+    m = cfg.moe
+    f = m.d_ff or cfg.d_ff
+    return L.MoEDims(d_model=cfg.d_model, d_ff=f,
+                     num_experts=m.num_experts, top_k=m.top_k,
+                     capacity_factor=m.capacity_factor,
+                     shared_d_ff=m.shared_experts * f, scoring=m.scoring,
+                     n_group=m.n_group, topk_group=m.topk_group,
+                     routed_scale=m.routed_scale, held=m.held)
+
+
+def _n_dense(cfg: ArchConfig) -> int:
+    """Leading dense layers of a MoE stack (kept in ``dense_layers``)."""
+    return cfg.first_k_dense if cfg.moe else 0
 
 
 def _remat_policy(remat):
@@ -75,7 +86,7 @@ def _gated(cfg: ArchConfig) -> bool:
 
 
 # ------------------------------------------------------------------ init
-def _layer_init(key, cfg: ArchConfig):
+def _layer_init(key, cfg: ArchConfig, moe: bool = True):
     ks = jax.random.split(key, 4)
     p = {
         "ln1": L.norm_init(cfg.d_model, cfg.norm),
@@ -83,7 +94,7 @@ def _layer_init(key, cfg: ArchConfig):
                  else L.attn_init(ks[0], _attn_dims(cfg))),
         "ln2": L.norm_init(cfg.d_model, cfg.norm),
     }
-    if cfg.moe:
+    if cfg.moe and moe:
         p["moe"] = L.moe_init(ks[1], _moe_dims(cfg))
     else:
         p["mlp"] = L.mlp_init(ks[1], cfg.d_model, cfg.d_ff, gated=_gated(cfg),
@@ -91,15 +102,15 @@ def _layer_init(key, cfg: ArchConfig):
     return p
 
 
-def _layer_logical(cfg: ArchConfig):
+def _layer_logical(cfg: ArchConfig, moe: bool = True):
     p = {
         "ln1": L.norm_logical(cfg.norm),
         "attn": (L.mla_logical(_mla_dims(cfg)) if _is_mla(cfg)
                  else L.attn_logical(_attn_dims(cfg))),
         "ln2": L.norm_logical(cfg.norm),
     }
-    if cfg.moe:
-        p["moe"] = L.moe_logical()
+    if cfg.moe and moe:
+        p["moe"] = L.moe_logical(_moe_dims(cfg))
     else:
         p["mlp"] = L.mlp_logical(gated=_gated(cfg), bias=cfg.mlp_bias)
     return p
@@ -137,8 +148,13 @@ def init(cfg: ArchConfig, key) -> dict:
                     "cross": _cross_init(k2, cfg)}
         params["super"] = _stack(k_layers, n_super, super_init)
     else:
-        params["layers"] = _stack(k_layers, cfg.num_layers,
-                                  lambda kk: _layer_init(kk, cfg))
+        # layer i takes key i of one split, whichever stack holds it
+        keys = jax.random.split(k_layers, cfg.num_layers)
+        k = _n_dense(cfg)
+        if k:
+            params["dense_layers"] = jax.vmap(
+                lambda kk: _layer_init(kk, cfg, moe=False))(keys[:k])
+        params["layers"] = jax.vmap(lambda kk: _layer_init(kk, cfg))(keys[k:])
     if not cfg.tie_embeddings:
         params["unembed"] = {"w": L._dense(k_head, (cfg.d_model, cfg.padded_vocab))}
     return params
@@ -157,6 +173,8 @@ def param_logical(cfg: ArchConfig) -> dict:
                         "cross": stacked(_cross_logical(cfg))}
     else:
         out["layers"] = stacked(_layer_logical(cfg))
+        if _n_dense(cfg):
+            out["dense_layers"] = stacked(_layer_logical(cfg, moe=False))
     if not cfg.tie_embeddings:
         out["unembed"] = {"w": ("fsdp", "vocab")}
     return out
@@ -223,10 +241,14 @@ def _layer_apply(cfg: ArchConfig, lp, x, positions, attn_impl):
     x = x + checkpoint_name(a, "attn_out")
     x = shard(x, "batch", "seq_sp", None)
     h = L.apply_norm(x, lp["ln2"], cfg.norm)
-    if cfg.moe:
+    no_aux = {"moe_aux": jnp.zeros(()), "moe_z": jnp.zeros(())}
+    if "moe" in lp and _moe_dims(cfg).gshard:
+        # training keeps the GShard capacity dispatch and its aux losses
         y, aux = L.moe(lp["moe"], h, _moe_dims(cfg))
+    elif "moe" in lp:
+        y, aux = L.moe_dropless(lp["moe"], h, _moe_dims(cfg))[0], no_aux
     else:
-        y, aux = L.mlp(lp["mlp"], h), {"moe_aux": jnp.zeros(()), "moe_z": jnp.zeros(())}
+        y, aux = L.mlp(lp["mlp"], h), no_aux
     x = shard(x + checkpoint_name(y, "mlp_out"), "batch", "seq_sp", None)
     return x, aux
 
@@ -275,7 +297,12 @@ def forward(params, cfg: ArchConfig, tokens, *, image_embeds=None,
                                         policy=jax.checkpoint_policies.nothing_saveable)
         x, aux = jax.lax.scan(super_body, x, params["super"])
     else:
+        if "dense_layers" in params:
+            x, aux_d = jax.lax.scan(body, x, params["dense_layers"])
         x, aux = jax.lax.scan(body, x, params["layers"])
+        if "dense_layers" in params:
+            aux = jax.tree.map(lambda a, b: jnp.sum(a) + jnp.sum(b), aux,
+                               aux_d)
 
     x = L.apply_norm(x, params["final_norm"], cfg.norm)
     aux = jax.tree.map(jnp.sum, aux)
@@ -316,40 +343,90 @@ def cache_logical(cfg: ArchConfig):
     return {"k": kv, "v": kv, "pos": ()}
 
 
-def _decode_layer(cfg: ArchConfig, lp, x, ck, cv, pos, positions,
-                  block_tables=None, paged_impl: str = "einsum",
-                  kscale=None, vscale=None):
-    """One decode layer: returns (x, new_ck, new_cv). Exposed for roofline
-    probes (launch/probes.py) as well as the decode scan body. When
-    ``block_tables`` is given, ck/cv are one layer's (P, ps, KV, hd) page-pool
-    slice and attention goes through the paged path (models/layers.py);
-    ``paged_impl`` selects the Pallas block-gather kernel or the
-    masked-einsum reference read. ``kscale``/``vscale`` are this layer's
-    (P, tp) per-page per-kv-head-group dequant scales for int8 pools; when
-    given the return grows to (x, ck, cv, kscale, vscale)."""
-    quantized = kscale is not None
-    h = L.apply_norm(x, lp["ln1"], cfg.norm)
-    if block_tables is not None:
-        if quantized:
-            out, ck, cv, kscale, vscale = L.attention_decode_paged(
-                lp["attn"], h, _attn_dims(cfg), ck, cv, block_tables, pos,
-                positions, impl=paged_impl, k_scale=kscale, v_scale=vscale)
-        else:
-            out, ck, cv = L.attention_decode_paged(
-                lp["attn"], h, _attn_dims(cfg), ck, cv, block_tables, pos,
-                positions, impl=paged_impl)
-    else:
-        out, ck, cv = L.attention_decode(lp["attn"], h, _attn_dims(cfg), ck,
-                                         cv, pos, positions)
+# The serving layers. Each takes one layer's cache as a dict of per-layer
+# arrays ("k", and "v" for per-head K/V, "k_scale"/"v_scale" beside int8
+# pools; an MLA latent cache has "k" only) and returns the layer's output,
+# the updated dict and its MoE counts; ``_run_layers`` drives them.
+def _ffn(cfg: ArchConfig, lp, h):
+    """(y, counts) of the layer's MLP, or of its MoE as serving computes
+    it: dropless, only the held experts' part (``layers.moe_dropless``)."""
+    if "moe" in lp:
+        return L.moe_dropless(lp["moe"], h, _moe_dims(cfg))
+    return L.mlp(lp["mlp"], h), jnp.zeros((2,), jnp.int32)
+
+
+def _residual(cfg: ArchConfig, lp, x, attn_fn):
+    """x + attention, then + the feed-forward: (x, cache dict, counts)."""
+    out, c = attn_fn(L.apply_norm(x, lp["ln1"], cfg.norm))
     x = x + out
-    h = L.apply_norm(x, lp["ln2"], cfg.norm)
-    if cfg.moe:
-        y, _ = L.moe(lp["moe"], h, _moe_dims(cfg))
-    else:
-        y = L.mlp(lp["mlp"], h)
-    if quantized:
-        return x + y, ck, cv, kscale, vscale
-    return x + y, ck, cv
+    y, counts = _ffn(cfg, lp, L.apply_norm(x, lp["ln2"], cfg.norm))
+    return x + y, c, counts
+
+
+def _decode_attn(cfg: ArchConfig, lp, h, c, pos, positions, bt, impl,
+                 layer):
+    """One decode token's attention against this layer's cache ``c``:
+    dense rows, or a page pool read through ``bt`` with the Pallas kernel
+    (``impl='kernel'``) or the masked-einsum reference. A latent page pool
+    comes whole (``_run_layers``' ``whole``), addressed at ``layer``."""
+    if _is_mla(cfg):
+        if bt is not None:
+            out, k = L.mla_attention_decode_paged(
+                lp["attn"], h, _mla_dims(cfg), c["k"], layer, bt, pos,
+                positions, impl=impl)
+        else:
+            out, k = L.mla_attention_decode(lp["attn"], h, _mla_dims(cfg),
+                                            c["k"], pos, positions)
+        return out, {"k": k}
+    dims = _attn_dims(cfg)
+    if bt is None:
+        out, k, v = L.attention_decode(lp["attn"], h, dims, c["k"], c["v"],
+                                       pos, positions)
+        return out, {"k": k, "v": v}
+    if "k_scale" in c:
+        out, k, v, ks, vs = L.attention_decode_paged(
+            lp["attn"], h, dims, c["k"], c["v"], bt, pos, positions,
+            impl=impl, k_scale=c["k_scale"], v_scale=c["v_scale"])
+        return out, {"k": k, "v": v, "k_scale": ks, "v_scale": vs}
+    out, k, v = L.attention_decode_paged(lp["attn"], h, dims, c["k"],
+                                         c["v"], bt, pos, positions,
+                                         impl=impl)
+    return out, {"k": k, "v": v}
+
+
+def _chunk_attn(cfg: ArchConfig, lp, h, c, start, positions, use_kernel):
+    """A prompt chunk's attention, its rows written into the request's
+    dense cache ``c`` (the transient prefill path)."""
+    if _is_mla(cfg):
+        out, k = L.mla_attention_prefill_chunk(lp["attn"], h, _mla_dims(cfg),
+                                               c["k"], start, positions)
+        return out, {"k": k}
+    out, k, v = L.attention_prefill_chunk(lp["attn"], h, _attn_dims(cfg),
+                                          c["k"], c["v"], start, positions,
+                                          use_kernel=use_kernel)
+    return out, {"k": k, "v": v}
+
+
+def _chunk_paged_attn(cfg: ArchConfig, lp, h, c, bt, positions, write_floor,
+                      impl, layer):
+    """A prompt chunk's attention, its rows spliced into the page pool
+    (rows below ``write_floor`` dropped) and read through ``bt``; a latent
+    pool comes whole, addressed at ``layer``."""
+    if _is_mla(cfg):
+        out, k = L.mla_attention_prefill_chunk_paged(
+            lp["attn"], h, _mla_dims(cfg), c["k"], layer, bt, positions,
+            write_floor, impl=impl)
+        return out, {"k": k}
+    dims = _attn_dims(cfg)
+    if "k_scale" in c:
+        out, k, v, ks, vs = L.attention_prefill_chunk_paged(
+            lp["attn"], h, dims, c["k"], c["v"], bt, positions, write_floor,
+            impl=impl, k_scale=c["k_scale"], v_scale=c["v_scale"])
+        return out, {"k": k, "v": v, "k_scale": ks, "v_scale": vs}
+    out, k, v = L.attention_prefill_chunk_paged(
+        lp["attn"], h, dims, c["k"], c["v"], bt, positions, write_floor,
+        impl=impl)
+    return out, {"k": k, "v": v}
 
 
 def _index_tree(tree, i):
@@ -357,60 +434,87 @@ def _index_tree(tree, i):
         lambda t: jax.lax.dynamic_index_in_dim(t, i, 0, keepdims=False), tree)
 
 
-# ------------------------------------------------------------- MLA layers
-# The latent-cache twins of _decode_layer / _prefill_chunk_layer(_paged):
-# one "k" latent carry instead of (ck, cv), same residual structure. Kept as
-# separate bodies (and separate fori_loop drivers below) because the carry
-# pytree differs — a dummy "v" leaf would defeat the whole representation.
-def _decode_layer_mla(cfg: ArchConfig, lp, x, ck, pos, positions,
-                      block_tables=None, paged_impl: str = "einsum"):
-    h = L.apply_norm(x, lp["ln1"], cfg.norm)
-    if block_tables is not None:
-        out, ck = L.mla_attention_decode_paged(
-            lp["attn"], h, _mla_dims(cfg), ck, block_tables, pos, positions,
-            impl=paged_impl)
-    else:
-        out, ck = L.mla_attention_decode(lp["attn"], h, _mla_dims(cfg), ck,
-                                         pos, positions)
-    x = x + out
-    h = L.apply_norm(x, lp["ln2"], cfg.norm)
-    y = L.moe(lp["moe"], h, _moe_dims(cfg))[0] if cfg.moe else L.mlp(lp["mlp"], h)
-    return x + y, ck
+_EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
 
 
-def _prefill_chunk_layer_mla(cfg: ArchConfig, lp, x, ck, start, positions):
-    h = L.apply_norm(x, lp["ln1"], cfg.norm)
-    out, ck = L.mla_attention_prefill_chunk(lp["attn"], h, _mla_dims(cfg),
-                                            ck, start, positions)
-    x = x + out
-    h = L.apply_norm(x, lp["ln2"], cfg.norm)
-    y = L.moe(lp["moe"], h, _moe_dims(cfg))[0] if cfg.moe else L.mlp(lp["mlp"], h)
-    return x + y, ck
+def _layer_params(stack, j):
+    """Layer ``j``'s parameters out of a layer stack, but for a MoE
+    layer's expert weights, which stay stacked with the layer's index
+    beside them (``layers.moe_dropless``): XLA's grouped matmul takes its
+    operand whole, so a slice of them would copy the layer's experts."""
+    lp = _index_tree({k: v for k, v in stack.items() if k != "moe"}, j)
+    if "moe" in stack:
+        moe = stack["moe"]
+        lp["moe"] = _index_tree({k: v for k, v in moe.items()
+                                 if k not in _EXPERT_WEIGHTS}, j)
+        lp["moe"].update({k: moe[k] for k in _EXPERT_WEIGHTS}, layer=j)
+    return lp
 
 
-def _prefill_chunk_layer_paged_mla(cfg: ArchConfig, lp, x, pk, bt, positions,
-                                   write_floor, impl):
-    h = L.apply_norm(x, lp["ln1"], cfg.norm)
-    out, pk = L.mla_attention_prefill_chunk_paged(
-        lp["attn"], h, _mla_dims(cfg), pk, bt, positions, write_floor,
-        impl=impl)
-    x = x + out
-    h = L.apply_norm(x, lp["ln2"], cfg.norm)
-    y = L.moe(lp["moe"], h, _moe_dims(cfg))[0] if cfg.moe else L.mlp(lp["mlp"], h)
-    return x + y, pk
+# the cache leaves stacked over layers (everything else is per slot)
+_LAYER_LEAVES = ("k", "v", "k_scale", "v_scale")
 
 
-def _mla_layer_loop(params, cfg: ArchConfig, x, ck0, layer_fn):
-    """fori_loop over layers carrying (x, latent cache) — the MLA driver
-    shared by decode/prefill/paged-prefill (see decode_step's docstring for
-    why fori_loop-with-DUS beats scan here)."""
-    def body(i, carry):
-        x, ck_all = carry
-        lp = _index_tree(params["layers"], i)
-        ck = jax.lax.dynamic_index_in_dim(ck_all, i, 0, keepdims=False)
-        x, ck = layer_fn(lp, x, ck)
-        return x, jax.lax.dynamic_update_index_in_dim(ck_all, ck, i, 0)
-    return jax.lax.fori_loop(0, cfg.num_layers, body, (x, ck0))
+def _run_layers(params, cfg: ArchConfig, x, cache, layer_fn, whole=()):
+    """Every decoder layer over ``x``: a fori_loop per stack (the leading
+    ``dense_layers``, then ``layers``) carrying x, the cache's layer-
+    stacked leaves and the MoE counts summed over layers. Layer i takes
+    slice i of each stacked leaf and updates it back into the stack: a
+    lax.scan over per-layer cache slices would stack fresh output buffers
+    (a full extra cache copy in HBM), because XLA cannot alias scan ys to
+    donated inputs. The leaves named in ``whole`` go to the layer entire,
+    with the layer's index, and come back entire: that layer writes and
+    reads its rows in the stack, so its slice is not copied out and back.
+    ``layer_fn(lp, x, c, i) -> (x, c, counts)`` with ``c`` one layer's
+    dict of those leaves. Returns (x, stacked leaves, counts)."""
+    stacked = {k: cache[k] for k in _LAYER_LEAVES if k in cache}
+
+    def loop(name, lo, hi, carry):
+        def body(i, carry):
+            x, st, counts = carry
+            c = {k: v if k in whole else
+                 jax.lax.dynamic_index_in_dim(v, i, 0, keepdims=False)
+                 for k, v in st.items()}
+            x, c, n = layer_fn(_layer_params(params[name], i - lo), x, c, i)
+            st = {k: c[k] if k in whole else
+                  jax.lax.dynamic_update_index_in_dim(st[k], c[k], i, 0)
+                  for k in st}
+            return x, st, counts + n
+        return jax.lax.fori_loop(lo, hi, body, carry)
+
+    carry = (x, stacked, jnp.zeros((2,), jnp.int32))
+    k = _n_dense(cfg)
+    if k:
+        carry = loop("dense_layers", 0, k, carry)
+    return loop("layers", k, cfg.num_layers, carry)
+
+
+def _logits(params, cfg: ArchConfig, x):
+    x = L.apply_norm(x, params["final_norm"], cfg.norm)
+    w_un = params["unembed"]["w"] if not cfg.tie_embeddings else None
+    return L.lm_logits(params["embed"], x, w_un,
+                       vocab=cfg.vocab_size).astype(jnp.float32)
+
+
+def _decode_layer(cfg: ArchConfig, lp, x, ck, cv, pos, positions,
+                  block_tables=None, paged_impl: str = "einsum",
+                  kscale=None, vscale=None):
+    """One per-head-K/V decode layer: returns (x, new_ck, new_cv). Exposed
+    for roofline probes (launch/probes.py) and the VLM super-layer. When
+    ``block_tables`` is given, ck/cv are one layer's (P, ps, KV, hd)
+    page-pool slice and attention goes through the paged path
+    (models/layers.py); ``paged_impl`` selects the Pallas kernel or the
+    masked-einsum reference read. ``kscale``/``vscale`` are this layer's
+    (P, tp) per-page per-kv-head-group dequant scales for int8 pools; when
+    given the return grows to (x, ck, cv, kscale, vscale)."""
+    c = {"k": ck, "v": cv}
+    if kscale is not None:
+        c.update(k_scale=kscale, v_scale=vscale)
+    x, c, _ = _residual(cfg, lp, x, lambda h: _decode_attn(
+        cfg, lp, h, c, pos, positions, block_tables, paged_impl, None))
+    if kscale is not None:
+        return x, c["k"], c["v"], c["k_scale"], c["v_scale"]
+    return x, c["k"], c["v"]
 
 
 # ------------------------------------------------------- parallel prefill
@@ -421,17 +525,9 @@ def _prefill_chunk_layer(cfg: ArchConfig, lp, x, ck, cv, start, positions,
     chunk positions jointly. Mirrors ``_decode_layer``'s math exactly (same
     residual structure, same masked-softmax validity) so the parallel
     prefill reproduces the scan-prefill anchor's greedy tokens."""
-    h = L.apply_norm(x, lp["ln1"], cfg.norm)
-    out, ck, cv = L.attention_prefill_chunk(lp["attn"], h, _attn_dims(cfg),
-                                            ck, cv, start, positions,
-                                            use_kernel=use_kernel)
-    x = x + out
-    h = L.apply_norm(x, lp["ln2"], cfg.norm)
-    if cfg.moe:
-        y, _ = L.moe(lp["moe"], h, _moe_dims(cfg))
-    else:
-        y = L.mlp(lp["mlp"], h)
-    return x + y, ck, cv
+    x, c, _ = _residual(cfg, lp, x, lambda h: _chunk_attn(
+        cfg, lp, h, {"k": ck, "v": cv}, start, positions, use_kernel))
+    return x, c["k"], c["v"]
 
 
 def _super_prefill_chunk_unrolled(cfg: ArchConfig, sp, x, ck, cv, img, start,
@@ -449,7 +545,7 @@ def _super_prefill_chunk_unrolled(cfg: ArchConfig, sp, x, ck, cv, img, start,
 
 def prefill_chunk(params, cfg: ArchConfig, tokens, cache, *, image_embeds=None,
                   compute_dtype=jnp.bfloat16, attn_impl: str = "einsum",
-                  first: bool = False, **_):
+                  first: bool = False, return_stats: bool = False, **_):
     """Full-width parallel prefill over one prompt chunk.
 
     tokens: (B, C) — C consecutive prompt positions starting at
@@ -459,24 +555,16 @@ def prefill_chunk(params, cfg: ArchConfig, tokens, cache, *, image_embeds=None,
     arithmetic intensity instead of the decode_step-under-scan's one token
     of matmul width per step — and the per-layer post-RoPE K/V land
     directly in the request cache, ready for the engine's (paged) splice.
-    Returns (last-position logits (B, 1, Vp) float32, cache with pos += C);
-    the same output contract as the scan prefill, which stays the
-    bit-exactness anchor."""
+    Returns (last-position logits (B, 1, Vp) float32, cache with pos += C
+    [, MoE counts with ``return_stats``: rows routed to held experts and
+    held experts with a row, summed over layers]); the same output
+    contract as the scan prefill, which stays the bit-exactness anchor."""
     B, C = tokens.shape
     start = jnp.zeros((), jnp.int32) if first else cache["pos"]
     positions = start + jnp.broadcast_to(jnp.arange(C, dtype=jnp.int32), (B, C))
-    use_kernel = first and attn_impl == "pallas"
+    use_kernel = first and attn_impl == "pallas" and not _is_mla(cfg)
     x = L.embed_lookup(params["embed"], tokens, compute_dtype)
-
-    if _is_mla(cfg):
-        x, new_k = _mla_layer_loop(
-            params, cfg, x, cache["k"],
-            lambda lp, x, ck: _prefill_chunk_layer_mla(cfg, lp, x, ck, start,
-                                                       positions))
-        x = L.apply_norm(x[:, -1:], params["final_norm"], cfg.norm)
-        w_un = params["unembed"]["w"] if not cfg.tie_embeddings else None
-        logits = L.lm_logits(params["embed"], x, w_un, vocab=cfg.vocab_size)
-        return logits.astype(jnp.float32), dict(cache, k=new_k, pos=start + C)
+    counts = jnp.zeros((2,), jnp.int32)
 
     if cfg.cross_attn_every:
         assert image_embeds is not None, "VLM prefill needs image_embeds"
@@ -498,59 +586,36 @@ def prefill_chunk(params, cfg: ArchConfig, tokens, cache, *, image_embeds=None,
             return x, ck_all, cv_all
 
         x, ck, cv = jax.lax.fori_loop(0, n_super, body, (x, ck0, cv0))
-        new_k = ck.reshape(cache["k"].shape)
-        new_v = cv.reshape(cache["v"].shape)
+        new = dict(k=ck.reshape(cache["k"].shape),
+                   v=cv.reshape(cache["v"].shape))
     else:
-        def body(i, carry):
-            x, ck_all, cv_all = carry
-            lp = _index_tree(params["layers"], i)
-            ck = jax.lax.dynamic_index_in_dim(ck_all, i, 0, keepdims=False)
-            cv = jax.lax.dynamic_index_in_dim(cv_all, i, 0, keepdims=False)
-            x, ck, cv = _prefill_chunk_layer(cfg, lp, x, ck, cv, start,
-                                             positions, use_kernel)
-            ck_all = jax.lax.dynamic_update_index_in_dim(ck_all, ck, i, 0)
-            cv_all = jax.lax.dynamic_update_index_in_dim(cv_all, cv, i, 0)
-            return x, ck_all, cv_all
+        x, new, counts = _run_layers(
+            params, cfg, x, cache,
+            lambda lp, x, c, i: _residual(cfg, lp, x, lambda h: _chunk_attn(
+                cfg, lp, h, c, start, positions, use_kernel)))
 
-        x, new_k, new_v = jax.lax.fori_loop(
-            0, cfg.num_layers, body, (x, cache["k"], cache["v"]))
-
-    x = L.apply_norm(x[:, -1:], params["final_norm"], cfg.norm)
-    w_un = params["unembed"]["w"] if not cfg.tie_embeddings else None
-    logits = L.lm_logits(params["embed"], x, w_un, vocab=cfg.vocab_size)
-    return logits.astype(jnp.float32), dict(cache, k=new_k, v=new_v,
-                                            pos=start + C)
+    out = (_logits(params, cfg, x[:, -1:]), dict(cache, pos=start + C, **new))
+    return out + (counts,) if return_stats else out
 
 
 # ------------------------------------------------- paged parallel prefill
 def _prefill_chunk_layer_paged(cfg: ArchConfig, lp, x, pk, pv, bt, positions,
                                write_floor, impl, kscale=None, vscale=None):
-    """One layer over a prompt chunk attending the PAGED pool directly:
-    the chunk's K/V rows scatter into the slot's own pages (the incremental
-    splice) and attention reads everything — prior chunks, aliased prefix
-    pages, the current chunk — through the block table. Same residual
-    structure as ``_prefill_chunk_layer``/``_decode_layer``. Int8 pools
-    carry per-layer (P, tp) per-group scales and the return grows
-    accordingly."""
-    quantized = kscale is not None
-    h = L.apply_norm(x, lp["ln1"], cfg.norm)
-    if quantized:
-        out, pk, pv, kscale, vscale = L.attention_prefill_chunk_paged(
-            lp["attn"], h, _attn_dims(cfg), pk, pv, bt, positions,
-            write_floor, impl=impl, k_scale=kscale, v_scale=vscale)
-    else:
-        out, pk, pv = L.attention_prefill_chunk_paged(
-            lp["attn"], h, _attn_dims(cfg), pk, pv, bt, positions,
-            write_floor, impl=impl)
-    x = x + out
-    h = L.apply_norm(x, lp["ln2"], cfg.norm)
-    if cfg.moe:
-        y, _ = L.moe(lp["moe"], h, _moe_dims(cfg))
-    else:
-        y = L.mlp(lp["mlp"], h)
-    if quantized:
-        return x + y, pk, pv, kscale, vscale
-    return x + y, pk, pv
+    """One per-head-K/V layer over a prompt chunk attending the PAGED pool
+    directly: the chunk's K/V rows scatter into the slot's own pages (the
+    incremental splice) and attention reads everything — prior chunks,
+    aliased prefix pages, the current chunk — through the block table.
+    Same residual structure as ``_prefill_chunk_layer``/``_decode_layer``.
+    Int8 pools carry per-layer (P, tp) per-group scales and the return
+    grows accordingly."""
+    c = {"k": pk, "v": pv}
+    if kscale is not None:
+        c.update(k_scale=kscale, v_scale=vscale)
+    x, c, _ = _residual(cfg, lp, x, lambda h: _chunk_paged_attn(
+        cfg, lp, h, c, bt, positions, write_floor, impl, None))
+    if kscale is not None:
+        return x, c["k"], c["v"], c["k_scale"], c["v_scale"]
+    return x, c["k"], c["v"]
 
 
 def _super_prefill_chunk_paged_unrolled(cfg: ArchConfig, sp, x, pk, pv, bt,
@@ -578,10 +643,15 @@ def _super_prefill_chunk_paged_unrolled(cfg: ArchConfig, sp, x, pk, pv, bt,
     return x, jnp.stack(pks), jnp.stack(pvs)
 
 
+def _layer_groups(tree, n_super: int, per: int):
+    """(L, ...) leaves -> (n_super, per, ...): the VLM super-layer view."""
+    return {k: v.reshape(n_super, per, *v.shape[1:]) for k, v in tree.items()}
+
+
 def prefill_chunk_paged(params, cfg: ArchConfig, tokens, cache, *, bt_rows,
                         start, write_floor, image_embeds=None,
                         compute_dtype=jnp.bfloat16, attn_impl: str = "kernel",
-                        **_):
+                        return_stats: bool = False, **_):
     """Full-width prefill over one prompt chunk, spliced into the RESIDENT
     paged cache incrementally (no transient request cache, no completion
     splice — the tentpole path).
@@ -594,235 +664,115 @@ def prefill_chunk_paged(params, cfg: ArchConfig, tokens, cache, *, bt_rows,
     the scatter. Every chunk is uniform (no first/continuation split): the
     chunk writes its K/V rows into the group's pages, then attends the
     pages through the block table, so a prefix-cache hit needs NO gather
-    seeding — aliased pages are read in place. Returns (last-position
-    logits (K, 1, Vp) float32, cache with updated pools); the engine
-    advances the group's ``pos`` at job completion."""
+    seeding — aliased pages are read in place. An int8-backend cache
+    carries (L, P, tp) per-page per-group scale leaves beside the pools,
+    threaded through the layer loop like the pools. Returns
+    (last-position logits (K, 1, Vp) float32, cache with updated pools
+    [, MoE counts with ``return_stats``]); the engine advances the group's
+    ``pos`` at job completion."""
     K, C = tokens.shape
     start = jnp.asarray(start, jnp.int32)
     write_floor = jnp.asarray(write_floor, jnp.int32)
     positions = start + jnp.broadcast_to(jnp.arange(C, dtype=jnp.int32),
                                          (K, C))
     x = L.embed_lookup(params["embed"], tokens, compute_dtype)
-    # an int8-backend cache carries (L, P, tp) per-page per-group scale
-    # leaves alongside the pools; the scales thread through the layer loop
-    # exactly like the pools do. Gated at trace time, so the fp32 jaxpr is
-    # unchanged.
-    quantized = "k_scale" in cache
-    scales = {}
-
-    if _is_mla(cfg):
-        x, new_k = _mla_layer_loop(
-            params, cfg, x, cache["k"],
-            lambda lp, x, pk: _prefill_chunk_layer_paged_mla(
-                cfg, lp, x, pk, bt_rows, positions, write_floor, attn_impl))
-        x = L.apply_norm(x[:, -1:], params["final_norm"], cfg.norm)
-        w_un = params["unembed"]["w"] if not cfg.tie_embeddings else None
-        logits = L.lm_logits(params["embed"], x, w_un, vocab=cfg.vocab_size)
-        return logits.astype(jnp.float32), dict(cache, k=new_k)
+    counts = jnp.zeros((2,), jnp.int32)
 
     if cfg.cross_attn_every:
         assert image_embeds is not None, "VLM prefill needs image_embeds"
         img = image_embeds.astype(compute_dtype)
         per = cfg.cross_attn_every
         n_super = cfg.num_layers // per
-        pk0 = cache["k"].reshape(n_super, per, *cache["k"].shape[1:])
-        pv0 = cache["v"].reshape(n_super, per, *cache["v"].shape[1:])
+        leaves = {k: cache[k] for k in _LAYER_LEAVES if k in cache}
+        quantized = "k_scale" in leaves
 
-        if quantized:
-            ks0 = cache["k_scale"].reshape(n_super, per,
-                                           *cache["k_scale"].shape[1:])
-            vs0 = cache["v_scale"].reshape(n_super, per,
-                                           *cache["v_scale"].shape[1:])
-
-            def bodyq(i, carry):
-                x, pk_all, pv_all, ks_all, vs_all = carry
-                sp = _index_tree(params["super"], i)
-                idx = lambda t: jax.lax.dynamic_index_in_dim(
-                    t, i, 0, keepdims=False)
-                x, pk, pv, ks, vs = _super_prefill_chunk_paged_unrolled(
-                    cfg, sp, x, idx(pk_all), idx(pv_all), bt_rows, img,
-                    positions, write_floor, attn_impl, idx(ks_all),
-                    idx(vs_all))
-                upd = jax.lax.dynamic_update_index_in_dim
-                return (x, upd(pk_all, pk, i, 0), upd(pv_all, pv, i, 0),
-                        upd(ks_all, ks, i, 0), upd(vs_all, vs, i, 0))
-
-            x, pk, pv, ks, vs = jax.lax.fori_loop(
-                0, n_super, bodyq, (x, pk0, pv0, ks0, vs0))
-            scales = dict(k_scale=ks.reshape(cache["k_scale"].shape),
-                          v_scale=vs.reshape(cache["v_scale"].shape))
-        else:
-            def body(i, carry):
-                x, pk_all, pv_all = carry
-                sp = _index_tree(params["super"], i)
-                pk = jax.lax.dynamic_index_in_dim(pk_all, i, 0, keepdims=False)
-                pv = jax.lax.dynamic_index_in_dim(pv_all, i, 0, keepdims=False)
-                x, pk, pv = _super_prefill_chunk_paged_unrolled(
-                    cfg, sp, x, pk, pv, bt_rows, img, positions, write_floor,
-                    attn_impl)
-                pk_all = jax.lax.dynamic_update_index_in_dim(pk_all, pk, i, 0)
-                pv_all = jax.lax.dynamic_update_index_in_dim(pv_all, pv, i, 0)
-                return x, pk_all, pv_all
-
-            x, pk, pv = jax.lax.fori_loop(0, n_super, body, (x, pk0, pv0))
-        new_k = pk.reshape(cache["k"].shape)
-        new_v = pv.reshape(cache["v"].shape)
-    elif quantized:
-        def bodyq(i, carry):
-            x, pk_all, pv_all, ks_all, vs_all = carry
-            lp = _index_tree(params["layers"], i)
-            idx = lambda t: jax.lax.dynamic_index_in_dim(t, i, 0,
-                                                         keepdims=False)
-            x, pk, pv, ks, vs = _prefill_chunk_layer_paged(
-                cfg, lp, x, idx(pk_all), idx(pv_all), bt_rows, positions,
-                write_floor, attn_impl, idx(ks_all), idx(vs_all))
-            upd = jax.lax.dynamic_update_index_in_dim
-            return (x, upd(pk_all, pk, i, 0), upd(pv_all, pv, i, 0),
-                    upd(ks_all, ks, i, 0), upd(vs_all, vs, i, 0))
-
-        x, new_k, new_v, ks, vs = jax.lax.fori_loop(
-            0, cfg.num_layers, bodyq,
-            (x, cache["k"], cache["v"], cache["k_scale"], cache["v_scale"]))
-        scales = dict(k_scale=ks, v_scale=vs)
-    else:
         def body(i, carry):
-            x, pk_all, pv_all = carry
-            lp = _index_tree(params["layers"], i)
-            pk = jax.lax.dynamic_index_in_dim(pk_all, i, 0, keepdims=False)
-            pv = jax.lax.dynamic_index_in_dim(pv_all, i, 0, keepdims=False)
-            x, pk, pv = _prefill_chunk_layer_paged(cfg, lp, x, pk, pv,
-                                                   bt_rows, positions,
-                                                   write_floor, attn_impl)
-            pk_all = jax.lax.dynamic_update_index_in_dim(pk_all, pk, i, 0)
-            pv_all = jax.lax.dynamic_update_index_in_dim(pv_all, pv, i, 0)
-            return x, pk_all, pv_all
+            x, st = carry
+            sp = _index_tree(params["super"], i)
+            c = {k: jax.lax.dynamic_index_in_dim(v, i, 0, keepdims=False)
+                 for k, v in st.items()}
+            out = _super_prefill_chunk_paged_unrolled(
+                cfg, sp, x, c["k"], c["v"], bt_rows, img, positions,
+                write_floor, attn_impl, c.get("k_scale"), c.get("v_scale"))
+            x = out[0]
+            c = dict(zip(("k", "v", "k_scale", "v_scale")
+                         if quantized else ("k", "v"), out[1:]))
+            st = {k: jax.lax.dynamic_update_index_in_dim(st[k], c[k], i, 0)
+                  for k in st}
+            return x, st
 
-        x, new_k, new_v = jax.lax.fori_loop(
-            0, cfg.num_layers, body, (x, cache["k"], cache["v"]))
+        x, st = jax.lax.fori_loop(0, n_super, body,
+                                  (x, _layer_groups(leaves, n_super, per)))
+        new = {k: v.reshape(cache[k].shape) for k, v in st.items()}
+    else:
+        x, new, counts = _run_layers(
+            params, cfg, x, cache,
+            lambda lp, x, c, i: _residual(cfg, lp, x, lambda h: (
+                _chunk_paged_attn(cfg, lp, h, c, bt_rows, positions,
+                                  write_floor, attn_impl, i))),
+            whole=("k",) if _is_mla(cfg) else ())
 
-    x = L.apply_norm(x[:, -1:], params["final_norm"], cfg.norm)
-    w_un = params["unembed"]["w"] if not cfg.tie_embeddings else None
-    logits = L.lm_logits(params["embed"], x, w_un, vocab=cfg.vocab_size)
-    return logits.astype(jnp.float32), dict(cache, k=new_k, v=new_v, **scales)
+    out = (_logits(params, cfg, x[:, -1:]), dict(cache, **new))
+    return out + (counts,) if return_stats else out
 
 
 def decode_step(params, cfg: ArchConfig, token, cache, *, image_embeds=None,
-                compute_dtype=jnp.bfloat16, paged_attn_impl: str = "einsum"):
-    """token: (B, 1) int32. Returns (logits (B,1,V), new cache).
+                compute_dtype=jnp.bfloat16, paged_attn_impl: str = "einsum",
+                return_stats: bool = False):
+    """token: (B, 1) int32. Returns (logits (B,1,V), new cache[, MoE counts
+    with ``return_stats``]).
 
     Layers run in a fori_loop carrying the FULL (L,B,S,KV,hd) cache with
-    in-place dynamic updates — a lax.scan over per-layer cache slices stacks
-    fresh output buffers (a full extra cache copy in HBM) because XLA cannot
-    alias scan ys to donated inputs.
+    in-place dynamic updates (``_run_layers``).
 
     cache["pos"] may be a scalar (lockstep batch) or a (B,) per-slot vector
     (serving engine with continuous batching). A cache carrying a
     "block_tables" leaf is PAGED (models/registry.py::init_paged_cache):
     "k"/"v" are (L, P, page_size, KV, hd) page pools and decode routes
     through the block-table-indirect attention path — through the Pallas
-    block-gather kernel with ``paged_attn_impl='kernel'``, the masked-einsum
-    reference otherwise."""
+    kernel with ``paged_attn_impl='kernel'``, the masked-einsum reference
+    otherwise. An int8-backend cache carries (L, P, tp) per-page per-group
+    scale leaves that thread through the loop like the pools."""
     B = token.shape[0]
     pos = cache["pos"]
     bt = cache.get("block_tables")
     positions = L.decode_positions(pos, B)
     x = L.embed_lookup(params["embed"], token, compute_dtype)
-    # int8-backend caches carry (L, P, tp) per-page per-group scale leaves;
-    # see prefill_chunk_paged — trace-time gate, fp32 jaxpr unchanged
-    quantized = bt is not None and "k_scale" in cache
-    scales = {}
-
-    if _is_mla(cfg):
-        x, new_k = _mla_layer_loop(
-            params, cfg, x, cache["k"],
-            lambda lp, x, ck: _decode_layer_mla(cfg, lp, x, ck, pos,
-                                                positions, bt,
-                                                paged_attn_impl))
-        x = L.apply_norm(x, params["final_norm"], cfg.norm)
-        w_un = params["unembed"]["w"] if not cfg.tie_embeddings else None
-        logits = L.lm_logits(params["embed"], x, w_un, vocab=cfg.vocab_size)
-        return logits.astype(jnp.float32), dict(cache, k=new_k, pos=pos + 1)
+    counts = jnp.zeros((2,), jnp.int32)
 
     if cfg.cross_attn_every:
         assert image_embeds is not None
         img = image_embeds.astype(compute_dtype)
         per = cfg.cross_attn_every
         n_super = cfg.num_layers // per
-        ck0 = cache["k"].reshape(n_super, per, *cache["k"].shape[1:])
-        cv0 = cache["v"].reshape(n_super, per, *cache["v"].shape[1:])
+        leaves = {k: cache[k] for k in _LAYER_LEAVES if k in cache}
+        quantized = bt is not None and "k_scale" in leaves
 
-        if quantized:
-            ks0 = cache["k_scale"].reshape(n_super, per,
-                                           *cache["k_scale"].shape[1:])
-            vs0 = cache["v_scale"].reshape(n_super, per,
-                                           *cache["v_scale"].shape[1:])
-
-            def bodyq(i, carry):
-                x, ck_all, cv_all, ks_all, vs_all = carry
-                sp = _index_tree(params["super"], i)
-                idx = lambda t: jax.lax.dynamic_index_in_dim(
-                    t, i, 0, keepdims=False)
-                x, ck, cv, ks, vs = _super_decode_unrolled(
-                    cfg, sp, x, idx(ck_all), idx(cv_all), img, pos, positions,
-                    bt, paged_attn_impl, idx(ks_all), idx(vs_all))
-                upd = jax.lax.dynamic_update_index_in_dim
-                return (x, upd(ck_all, ck, i, 0), upd(cv_all, cv, i, 0),
-                        upd(ks_all, ks, i, 0), upd(vs_all, vs, i, 0))
-
-            x, ck, cv, ks, vs = jax.lax.fori_loop(
-                0, n_super, bodyq, (x, ck0, cv0, ks0, vs0))
-            scales = dict(k_scale=ks.reshape(cache["k_scale"].shape),
-                          v_scale=vs.reshape(cache["v_scale"].shape))
-        else:
-            def body(i, carry):
-                x, ck_all, cv_all = carry
-                sp = _index_tree(params["super"], i)
-                ck = jax.lax.dynamic_index_in_dim(ck_all, i, 0, keepdims=False)
-                cv = jax.lax.dynamic_index_in_dim(cv_all, i, 0, keepdims=False)
-                x, ck, cv = _super_decode_unrolled(cfg, sp, x, ck, cv, img,
-                                                   pos, positions, bt,
-                                                   paged_attn_impl)
-                ck_all = jax.lax.dynamic_update_index_in_dim(ck_all, ck, i, 0)
-                cv_all = jax.lax.dynamic_update_index_in_dim(cv_all, cv, i, 0)
-                return x, ck_all, cv_all
-
-            x, ck, cv = jax.lax.fori_loop(0, n_super, body, (x, ck0, cv0))
-        new_k = ck.reshape(cache["k"].shape)
-        new_v = cv.reshape(cache["v"].shape)
-    elif quantized:
-        def bodyq(i, carry):
-            x, ck_all, cv_all, ks_all, vs_all = carry
-            lp = _index_tree(params["layers"], i)
-            idx = lambda t: jax.lax.dynamic_index_in_dim(t, i, 0,
-                                                         keepdims=False)
-            x, ck, cv, ks, vs = _decode_layer(
-                cfg, lp, x, idx(ck_all), idx(cv_all), pos, positions, bt,
-                paged_attn_impl, idx(ks_all), idx(vs_all))
-            upd = jax.lax.dynamic_update_index_in_dim
-            return (x, upd(ck_all, ck, i, 0), upd(cv_all, cv, i, 0),
-                    upd(ks_all, ks, i, 0), upd(vs_all, vs, i, 0))
-
-        x, new_k, new_v, ks, vs = jax.lax.fori_loop(
-            0, cfg.num_layers, bodyq,
-            (x, cache["k"], cache["v"], cache["k_scale"], cache["v_scale"]))
-        scales = dict(k_scale=ks, v_scale=vs)
-    else:
         def body(i, carry):
-            x, ck_all, cv_all = carry
-            lp = _index_tree(params["layers"], i)
-            ck = jax.lax.dynamic_index_in_dim(ck_all, i, 0, keepdims=False)
-            cv = jax.lax.dynamic_index_in_dim(cv_all, i, 0, keepdims=False)
-            x, ck, cv = _decode_layer(cfg, lp, x, ck, cv, pos, positions, bt,
-                                      paged_attn_impl)
-            ck_all = jax.lax.dynamic_update_index_in_dim(ck_all, ck, i, 0)
-            cv_all = jax.lax.dynamic_update_index_in_dim(cv_all, cv, i, 0)
-            return x, ck_all, cv_all
+            x, st = carry
+            sp = _index_tree(params["super"], i)
+            c = {k: jax.lax.dynamic_index_in_dim(v, i, 0, keepdims=False)
+                 for k, v in st.items()}
+            out = _super_decode_unrolled(
+                cfg, sp, x, c["k"], c["v"], img, pos, positions, bt,
+                paged_attn_impl, c.get("k_scale") if quantized else None,
+                c.get("v_scale") if quantized else None)
+            x = out[0]
+            c = dict(zip(("k", "v", "k_scale", "v_scale")
+                         if quantized else ("k", "v"), out[1:]))
+            st = {k: jax.lax.dynamic_update_index_in_dim(st[k], c[k], i, 0)
+                  for k in c}
+            return x, st
 
-        x, new_k, new_v = jax.lax.fori_loop(
-            0, cfg.num_layers, body, (x, cache["k"], cache["v"]))
+        x, st = jax.lax.fori_loop(0, n_super, body,
+                                  (x, _layer_groups(leaves, n_super, per)))
+        new = {k: v.reshape(cache[k].shape) for k, v in st.items()}
+    else:
+        x, new, counts = _run_layers(
+            params, cfg, x, cache,
+            lambda lp, x, c, i: _residual(cfg, lp, x, lambda h: _decode_attn(
+                cfg, lp, h, c, pos, positions, bt, paged_attn_impl, i)),
+            whole=("k",) if _is_mla(cfg) and bt is not None else ())
 
-    x = L.apply_norm(x, params["final_norm"], cfg.norm)
-    w_un = params["unembed"]["w"] if not cfg.tie_embeddings else None
-    logits = L.lm_logits(params["embed"], x, w_un, vocab=cfg.vocab_size)
-    new_cache = dict(cache, k=new_k, v=new_v, pos=pos + 1, **scales)
-    return logits.astype(jnp.float32), new_cache
+    out = (_logits(params, cfg, x), dict(cache, pos=pos + 1, **new))
+    return out + (counts,) if return_stats else out

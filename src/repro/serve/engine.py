@@ -97,7 +97,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import configs
-from repro.configs.base import Family
+from repro.configs.base import Family, replace_nested
 from repro.kernels import ops as kops
 from repro.launch import steps as steps_mod
 from repro.models.layers import INACTIVE_POS, cast_on_use
@@ -146,11 +146,16 @@ def _spanned(name: str):
 # re-tracing per instance (compile time would otherwise dominate short runs).
 # The (hashable) mesh is part of every key: a mesh trace bakes shard_map
 # calls into the jaxpr, so mesh and no-mesh engines must never share one.
+def _moe_stats(model: Model) -> bool:
+    """Do this model's serving steps return MoE counts (a third output)?"""
+    return model.cfg.moe is not None
+
+
 @functools.lru_cache(maxsize=64)
 def _jitted_decode(model: Model, compute_dtype, paged_impl=None, mesh=None):
     return jax.jit(_under_mesh(mesh, steps_mod.make_decode_step(
-        model, compute_dtype=compute_dtype, paged_attn_impl=paged_impl)),
-        donate_argnums=(1,))
+        model, compute_dtype=compute_dtype, paged_attn_impl=paged_impl,
+        moe_stats=_moe_stats(model))), donate_argnums=(1,))
 
 
 @functools.lru_cache(maxsize=64)
@@ -172,7 +177,8 @@ def _jitted_prefill_chunk(model: Model, compute_dtype, s_max: int,
     clears these caches if a caller defeats the bucketing."""
     fn = _under_mesh(mesh, steps_mod.make_prefill_chunk(
         model, compute_dtype=compute_dtype, s_max=s_max,
-        cache_dtype=cache_dtype, first=first, attn_impl=attn_impl))
+        cache_dtype=cache_dtype, first=first, attn_impl=attn_impl,
+        moe_stats=_moe_stats(model)))
     if first:
         return jax.jit(fn)
     return jax.jit(fn, donate_argnums=(1,))     # donate the transient cache
@@ -247,7 +253,8 @@ def _jitted_prefill_chunk_paged(model: Model, compute_dtype, attn_impl: str,
     like the transient chunk path. The resident cache is donated: the pools
     update in place each chunk instead of round-tripping a transient copy."""
     fn = _under_mesh(mesh, steps_mod.make_prefill_chunk_paged(
-        model, compute_dtype=compute_dtype, attn_impl=attn_impl))
+        model, compute_dtype=compute_dtype, attn_impl=attn_impl,
+        moe_stats=_moe_stats(model)))
     return jax.jit(fn, donate_argnums=(1,))
 
 
@@ -552,10 +559,11 @@ class ServeEngine:
         Params are initialised on the device inside one jit, with the
         weights every step casts on use already in ``compute_dtype``
         (``layers.cast_on_use``): no float32 copy of the whole tree is ever
-        held. ``config.cfg_overrides``: dataclasses.replace fields
-        applied AFTER reduction — reduced configs can shrink num_kv_heads
-        to 1, which blocks kv-head sharding; the tp tests/bench override
-        the head counts while keeping everything else reduced.
+        held. ``config.cfg_overrides``: ``configs.base.replace_nested``
+        fields applied AFTER reduction — reduced configs can shrink
+        num_kv_heads to 1, which blocks kv-head sharding; the tp
+        tests/bench override the head counts while keeping everything
+        else reduced.
 
         DEPRECATED spelling: ``build(arch, page_size=..., s_max=...)`` —
         the pre-ServeConfig kwarg surface. Still accepted (each kwarg maps
@@ -586,7 +594,7 @@ class ServeEngine:
         if config.reduced:
             cfg = reduced_config(cfg)
         if config.cfg_overrides:
-            cfg = dataclasses.replace(cfg, **config.cfg_overrides)
+            cfg = replace_nested(cfg, config.cfg_overrides)
         # the device-count guard outranks validate(): "you don't have the
         # devices" is the actionable error on a 1-device host even when the
         # reduced config's kv-head count would also reject the tp degree
@@ -693,15 +701,22 @@ class ServeEngine:
         return jnp.where(scaled < thresh, neg, scaled)
 
     @_spanned("serve.sample")
-    def _sample_rows(self, logits) -> np.ndarray:
-        """logits: (B, 1, V_padded) -> (B,) sampled token per row."""
+    def _sample_rows(self, logits, moe_counts=None) -> np.ndarray:
+        """logits: (B, 1, V_padded) -> (B,) sampled token per row.
+        ``moe_counts``: the step's MoE counts, read to the host in the same
+        transfer as the sampled rows and recorded."""
         row = logits[:, 0, : self.cfg.vocab_size]
         if self.temperature <= 0.0:
-            return np.asarray(jnp.argmax(row, axis=-1), np.int32)
-        key = jax.random.fold_in(self._key, self._events)
-        self._events += 1
-        toks = jax.random.categorical(
-            key, self._filter_logits(row / self.temperature), axis=-1)
+            toks = jnp.argmax(row, axis=-1)
+        else:
+            key = jax.random.fold_in(self._key, self._events)
+            self._events += 1
+            toks = jax.random.categorical(
+                key, self._filter_logits(row / self.temperature), axis=-1)
+        if moe_counts is None:
+            return np.asarray(toks, np.int32)
+        toks, counts = jax.device_get((toks, moe_counts))
+        self.metrics.on_moe(*counts)
         return np.asarray(toks, np.int32)
 
     # ------------------------------------------------------------ paging
@@ -1272,7 +1287,7 @@ class ServeEngine:
                                          jnp.int32),
                     "floor": jnp.asarray(job.write_floor, jnp.int32),
                     **self._prefill_extras(K)}
-                logits, self.cache = self._chunk_paged_fn()(
+                logits, self.cache, *stats = self._chunk_paged_fn()(
                     self.params, self.cache, batch)
             else:
                 # a prefix-seeded job already has its transient cache
@@ -1281,12 +1296,14 @@ class ServeEngine:
                 self._note_prefill_trace(first, K, C)
                 batch = {"tokens": toks, **self._prefill_extras(K)}
                 if first:
-                    logits, job.cache = self._chunk_fn(True)(self.params,
-                                                             batch)
+                    logits, job.cache, *stats = self._chunk_fn(True)(
+                        self.params, batch)
                 else:
-                    logits, job.cache = self._chunk_fn(False)(
+                    logits, job.cache, *stats = self._chunk_fn(False)(
                         self.params, job.cache, batch)
             jax.block_until_ready(logits)
+            if stats:
+                self.metrics.on_moe(*jax.device_get(stats[0]))
         except Exception as err:  # noqa: BLE001 — released, not resumed
             log.exception("prefill chunk failed for rids %s; releasing "
                           "the job", [r.rid for r in job.reqs])
@@ -1583,10 +1600,10 @@ class ServeEngine:
                 # rewritten below and by admit()
                 batch = {"token": jnp.asarray(self.cur_token.copy()),
                          **self._decode_extras()}
-                logits, self.cache = self._decode(self.params, self.cache,
-                                                  batch)
+                logits, self.cache, *stats = self._decode(
+                    self.params, self.cache, batch)
             self.metrics.on_decode_step()
-            nxt = self._sample_rows(logits)
+            nxt = self._sample_rows(logits, *stats)
             with self.metrics.span("serve.emit"):
                 for slot, req in enumerate(self.slot_req):
                     if req is None or req.state != RequestState.RUNNING:

@@ -543,7 +543,9 @@ class PagedLatentBackend(PagedFP32Backend):
     leaf; the generic splice/COW/seed machinery is key-generic, so this
     backend inherits every representation op from the fp32 pool — COW
     copies a latent row, never per-head K/V. Block tables, the allocator,
-    and the prefix index are untouched: a page is a page.
+    and the prefix index are untouched: a page is a page. On the TPU a
+    pool row is stored lane-aligned (``ops.latent_row_width``: 576 -> 640,
+    zeros beyond the latent), the layout the kernel DMAs whole rows from.
 
     Under tensor parallelism the latent pool REPLICATES (see
     :meth:`pool_axes`) and tp instead shards the ABSORBED queries/outputs
@@ -572,7 +574,14 @@ class PagedLatentBackend(PagedFP32Backend):
                 f"(kv_lora_rank > 0); {model.cfg.name!r} caches per-head "
                 f"K/V — use kv_backend='paged' (its pages would hold the "
                 f"same rows anyway)")
-        return super().init_cache(model, batch_slots, s_max, dtype)
+        from repro.kernels import ops as kops
+        cfg = model.cfg
+        cache = init_paged_cache(
+            model, batch_slots, s_max, page_size=self.page_size,
+            num_pages=self.num_pages, dtype=dtype,
+            row_width=kops.latent_row_width(cfg.kv_lora_rank
+                                            + cfg.qk_rope_head_dim))
+        return self.place(cache, self.mesh)
 
 
 def make_backend(spec, *, family: Family, page_size=None, num_pages=None,

@@ -198,7 +198,8 @@ _SUMMED_COUNTERS = (
     "prefill_chunk_tokens", "prefill_wall_s", "prefix_lookups",
     "prefix_hits", "prefix_hit_tokens", "prefix_pages_shared",
     "prefix_cow_copies", "prefix_evictions", "preemptions",
-    "shed_requests", "starvation_guard_skips")
+    "shed_requests", "starvation_guard_skips", "moe_steps",
+    "moe_rows_routed", "moe_experts_hit")
 
 
 def merged_summary(recorders: List["MetricsRecorder"],
@@ -252,6 +253,11 @@ class MetricsRecorder:
         self.preemptions = 0                  # RUNNING slots paused+re-queued
         self.shed_requests = 0                # admission control gave up early
         self.starvation_guard_skips = 0       # prefill ticks skipped for decode
+        # MoE layers, summed over layers and steps: rows routed to the
+        # experts held here, and held experts that got a row (per layer)
+        self.moe_steps = 0
+        self.moe_rows_routed = 0
+        self.moe_experts_hit = 0
         self._t_start: Optional[float] = None
         self._t_stop: Optional[float] = None
 
@@ -375,6 +381,13 @@ class MetricsRecorder:
     def on_decode_step(self):
         self.decode_steps += 1
 
+    def on_moe(self, rows: int, experts: int):
+        """One decode or prefill-chunk step's MoE counts (summed over its
+        layers), read with the step's own device-to-host transfer."""
+        self.moe_steps += 1
+        self.moe_rows_routed += int(rows)
+        self.moe_experts_hit += int(experts)
+
     # ------------------------------------------------------------ summary
     def summary(self, slo: Optional[SLO] = None) -> dict:
         recs = list(self.requests.values())
@@ -435,6 +448,9 @@ class MetricsRecorder:
                 "cow_copies": self.prefix_cow_copies,
                 "evictions": self.prefix_evictions,
             },
+            "moe": {"steps": self.moe_steps,
+                    "rows_routed": self.moe_rows_routed,
+                    "experts_hit": self.moe_experts_hit},
             "queue_wait_s": {"mean": float(np.mean(waits)) if waits
                              else float("nan"),
                              "p50": percentile(waits, 50),

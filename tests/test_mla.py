@@ -124,6 +124,79 @@ def test_latent_kernel_freed_slot_exact_zero():
     assert np.abs(np.asarray(out)[0]).max() > 0
 
 
+def _latent_case(rng, B, H, width, ps, mps, ends, dtype=jnp.float32):
+    """A pool whose slots hold pages up to ``ends[b]`` (exclusive; 0 = a
+    parked slot with an all--1 table), in scattered page order."""
+    P = B * mps + 3
+    pool = jnp.asarray(rng.standard_normal((P, ps, 1, width)), dtype)
+    bt = np.full((B, mps), -1, np.int32)
+    free = list(rng.permutation(P))
+    for b, end in enumerate(ends):
+        for j in range(-(-end // ps)):
+            bt[b, j] = free.pop()
+    return pool, jnp.asarray(bt)
+
+
+@pytest.mark.parametrize("H", [16, 128])
+def test_latent_kernel_decode_ragged_and_parked(H):
+    """Decode: one query row per head at ragged live lengths (a partial
+    last page, a block edge, one page, a long slot) and a parked slot,
+    against the einsum oracle; the parked slot returns exactly 0."""
+    rng = np.random.default_rng(H)
+    ps, mps, c, r = 8, 48, 32, 16
+    ends = [13, 128, 1, 300, 0]
+    pool, bt = _latent_case(rng, len(ends), H, c + r, ps, mps, ends)
+    pos = jnp.asarray([max(e - 1, 0) if e else mps * ps for e in ends],
+                      jnp.int32)
+    q = jnp.asarray(rng.standard_normal((len(ends), 1, H, c + r)),
+                    jnp.float32)
+    want = kref.paged_attention_latent(q, pool, bt, pos, scale_dim=20.5,
+                                       d_v=c)
+    got = paged_attention_latent(q, pool, bt, pos, scale_dim=20.5, d_v=c,
+                                 interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+    assert (np.asarray(got)[-1] == 0).all()
+
+
+@pytest.mark.parametrize("H", [16, 128])
+def test_latent_kernel_chunk_frontier_inside_a_page(H):
+    """A prefill chunk whose rows start and end mid-page: each query block
+    reads up to its own causal frontier, rows of one block see different
+    numbers of keys, and a slot holding only the chunk's own rows works."""
+    rng = np.random.default_rng(H + 1)
+    ps, mps, c, r, C = 8, 16, 32, 16, 12
+    starts = [21, 0, 90]
+    pool, bt = _latent_case(rng, 3, H, c + r, ps, mps,
+                            [s + C for s in starts])
+    q = jnp.asarray(rng.standard_normal((3, C, H, c + r)), jnp.float32)
+    st = jnp.asarray(starts, jnp.int32)
+    want = kref.paged_attention_latent(q, pool, bt, st, scale_dim=24,
+                                       d_v=c)
+    got = paged_attention_latent(q, pool, bt, st, scale_dim=24, d_v=c,
+                                 interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
+
+def test_latent_kernel_reads_lane_padded_rows():
+    """Rows stored wider than the latent (zeros beyond it, as the TPU's
+    pool keeps them) with equally padded queries give the unpadded
+    result."""
+    rng = np.random.default_rng(3)
+    ps, mps, c, r, H = 8, 8, 24, 8, 4
+    pool, bt = _latent_case(rng, 2, H, c + r, ps, mps, [19, 40])
+    pos = jnp.asarray([18, 39], jnp.int32)
+    q = jnp.asarray(rng.standard_normal((2, 1, H, c + r)), jnp.float32)
+    want = paged_attention_latent(q, pool, bt, pos, scale_dim=16, d_v=c,
+                                  interpret=True)
+    got = paged_attention_latent(L.fit_rows(q, 128), L.fit_rows(pool, 128),
+                                 bt, pos, scale_dim=16, d_v=c,
+                                 interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-6, atol=1e-6)
+
+
 # ---------------------------------------------------- serving equivalence
 def _serve(model, params, **kw):
     eng = ServeEngine(model, params, batch_slots=2, s_max=S_MAX, **kw)

@@ -13,6 +13,7 @@ workers collect different tests. Every test here skips when no topology
 can be described. Compiles run with the persistent compilation cache off:
 an executable for an absent chip could be written but never read back.
 """
+import dataclasses
 import math
 import os
 import re
@@ -88,19 +89,70 @@ def test_paged_int8_kernel_compiles(one_chip, sq):
     assert "tpu_custom_call" in hlo
 
 
+def _stored_width(cfg) -> int:
+    """A latent page row as the TPU's pool stores it: c + r rounded up to
+    whole 128-lane tiles (``ops.latent_row_width`` on the chip)."""
+    return -(-(cfg.kv_lora_rank + cfg.qk_rope_head_dim) // 128) * 128
+
+
+def _latent_hlo(one_chip, cfg, b, sq, s_max, layers=None):
+    """The latent kernel over one layer's pool, or with ``layers`` over
+    the layer-stacked pool at a traced layer index (the served form)."""
+    width = _stored_width(cfg)
+    mps = s_max // PS
+    stack = (layers,) if layers else ()
+    args = [((b, sq, cfg.num_heads, width), jnp.bfloat16),
+            (stack + (b * mps, PS, 1, width), jnp.bfloat16),
+            ((b, mps), jnp.int32), ((b,), jnp.int32), ((), jnp.int32)]
+    hlo = _compile_hlo(
+        lambda q, c, bt, st, i: pa.paged_attention_latent(
+            q, c, bt, st, i if layers else None,
+            scale_dim=cfg.head_dim + cfg.qk_rope_head_dim,
+            d_v=cfg.kv_lora_rank),
+        one_chip, *args)
+    return hlo, math.prod(args[1][0])
+
+
 @pytest.mark.parametrize("sq", [1, CHUNK], ids=["decode", "chunk"])
 def test_paged_latent_kernel_compiles(one_chip, sq):
-    cfg = configs.get_config("qwen2.5-32b-mla")
-    width = cfg.kv_lora_rank + cfg.qk_rope_head_dim      # 576 lanes
-    mps = S_MAX // PS
-    hlo = _compile_hlo(
-        lambda q, c, bt, st: pa.paged_attention_latent(
-            q, c, bt, st, scale_dim=cfg.head_dim + cfg.qk_rope_head_dim,
-            d_v=cfg.kv_lora_rank),
-        one_chip, ((B, sq, cfg.num_heads, width), jnp.bfloat16),
-        ((B * mps, PS, 1, width), jnp.bfloat16), ((B, mps), jnp.int32),
-        ((B,), jnp.int32))
+    cfg = configs.get_config("qwen2.5-32b-mla")   # 576 lanes, stored as 640
+    hlo, _ = _latent_hlo(one_chip, cfg, B, sq, S_MAX)
     assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("sq,b", [(1, 64), (CHUNK, 1)],
+                         ids=["decode", "chunk"])
+def test_latent_kernel_compiles_at_deepseek_widths(one_chip, sq, b):
+    """The latent kernel at DeepSeek-V3's cell: 128 heads, 576-wide rows
+    (stored as 640), 64 slots of s_max 8192 in decode and a 256-row chunk,
+    reading layer i of the cell's 5-layer stacked pool: the pool goes to
+    the kernel straight from the parameters, with no copy of a layer."""
+    cfg = configs.get_config("deepseek-v3")
+    hlo, pool_elems = _latent_hlo(one_chip, cfg, b, sq, 8192, layers=5)
+    assert "tpu_custom_call" in hlo
+    sized = [m[3] for m in map(_INSTR.match, hlo.splitlines())
+             if m and m[2] and math.prod(map(int, m[2].split(",")))
+             in (pool_elems, pool_elems // 5)]      # the stack, or a layer
+    assert sized and set(sized) <= {"parameter", "bitcast"}, sized
+
+
+@pytest.mark.parametrize("tokens", [64, CHUNK], ids=["decode", "chunk"])
+def test_expert_matmul_compiles_at_deepseek_widths(one_chip, tokens):
+    """The dropless MoE layer at DeepSeek-V3's widths, 8 of 256 experts
+    held: its grouped expert matmuls compile as the TPU's ragged dot."""
+    from repro.models import layers as L
+    from repro.models.transformer import _moe_dims
+    cfg = configs.get_config("deepseek-v3")
+    dims = dataclasses.replace(_moe_dims(cfg), held=(0, 8))
+    shapes = jax.eval_shape(lambda: L.moe_init(jax.random.PRNGKey(0), dims))
+    leaves, tree = jax.tree.flatten(shapes)
+    hlo = _compile_hlo(
+        lambda x, *w: L.moe_dropless(jax.tree.unflatten(tree, w), x,
+                                     dims)[0],
+        one_chip, ((tokens, 1, cfg.d_model), jnp.bfloat16),
+        *[(l.shape, jnp.bfloat16 if l.ndim == 3 else l.dtype)
+          for l in leaves])
+    assert "ragged" in hlo
 
 
 def test_flash_prefill_kernel_compiles(one_chip):
