@@ -166,27 +166,30 @@ _PAGED_MAX_INTERPRET_GRID = 4096
 
 def _paged_interpret_units(q, pool_k, block_tables) -> int:
     B, Sq, H, _ = q.shape
-    ps, KV = pool_k.shape[1:3]
+    ps, KV = pool_k.shape[-3:-1]
     mps = block_tables.shape[1]
     if _pa.takes_decode_kernel(q, pool_k):
         return B * KV * -(-mps // _pa.decode_pages_per_block(ps))
     return B * H * mps
 
 
-def _paged_dispatch_local(q, pool_k, pool_v, block_tables, start, window: int,
-                          k_scale=None, v_scale=None):
-    """Single-device paged-attention dispatch (also the per-shard body under
-    the tp shard_map — the interpret guard then sees per-shard heads,
-    which is the point of passing this in whole)."""
+def _paged_dispatch_local(q, pool_k, pool_v, block_tables, start, layer,
+                          window: int, k_scale=None, v_scale=None):
+    """Single-device paged-attention dispatch over layer ``layer`` of the
+    layer-stacked pools (also the per-shard body under the tp shard_map —
+    the interpret guard then sees per-shard heads, which is the point of
+    passing this in whole)."""
     hd = q.shape[3]
     sc = dict(k_scale=k_scale, v_scale=v_scale)
     if not attention_kernel_fits(hd):
         _oracle("paged_attention", f"head_dim {hd}")
     if (_interpret() and _paged_interpret_units(q, pool_k, block_tables)
             > _PAGED_MAX_INTERPRET_GRID):
-        return _ref.paged_attention(q, pool_k, pool_v, block_tables,
+        one_k, one_v = (jax.lax.dynamic_index_in_dim(p, layer, 0, False)
+                        for p in (pool_k, pool_v))
+        return _ref.paged_attention(q, one_k, one_v, block_tables,
                                     start, window=window, **sc)
-    return _pa.paged_attention(q, pool_k, pool_v, block_tables, start,
+    return _pa.paged_attention(q, pool_k, pool_v, block_tables, start, layer,
                                window=window, interpret=_interpret(), **sc)
 
 
@@ -200,15 +203,22 @@ def _squeeze_scale(s):
     return s
 
 
-def _paged_dispatch(q, pool_k, pool_v, block_tables, start, window: int,
-                    k_scale=None, v_scale=None, mesh=None, shard_axis=None):
+def _paged_dispatch(q, pool_k, pool_v, block_tables, start, layer,
+                    window: int, k_scale=None, v_scale=None, mesh=None,
+                    shard_axis=None):
+    """The kernels take the layer-stacked (L, P, ps, KV, hd) pools and the
+    layer to read; one layer's (P, ps, KV, hd) pools (``layer`` unused)
+    are read as a stack of one at 0 — a bitcast, no copy."""
+    if pool_k.ndim == 4:
+        pool_k, pool_v, layer = pool_k[None], pool_v[None], 0
     if mesh is not None and shard_axis is not None:
         return _pa.paged_attention_head_sharded(
             _paged_dispatch_local, mesh, shard_axis, q, pool_k, pool_v,
-            block_tables, start, window=window,
+            block_tables, start, layer, window=window,
             k_scale=k_scale, v_scale=v_scale)
     return _paged_dispatch_local(q, pool_k, pool_v, block_tables, start,
-                                 window, k_scale=_squeeze_scale(k_scale),
+                                 layer, window,
+                                 k_scale=_squeeze_scale(k_scale),
                                  v_scale=_squeeze_scale(v_scale))
 
 
@@ -219,12 +229,15 @@ def _paged_dispatch(q, pool_k, pool_v, block_tables, start, window: int,
 # head-shard decision at their own trace time (sharding.specs.head_shard_axis)
 # and pass it down explicitly.
 @functools.partial(jax.jit, static_argnames=("window", "mesh", "shard_axis"))
-def paged_decode(q, pool_k, pool_v, block_tables, cache_pos, *,
+def paged_decode(q, pool_k, pool_v, block_tables, cache_pos, layer=None, *,
                  window: int = 0, mesh=None, shard_axis=None):
     """Single-token decode attention against a paged KV cache.
 
-    q: (B, 1, H, hd); pool_k/pool_v: (P, page_size, KV, hd) — one layer's
-    slice of the shared pool; block_tables: (B, mps) int32 (-1 =
+    q: (B, 1, H, hd); pool_k/pool_v: the layer-stacked (L, P, page_size,
+    KV, hd) pools with ``layer`` the layer to read (a traced scalar: the
+    kernel reads that layer's pages in place, with no copy of the layer's
+    pool), or one layer's (P, page_size, KV, hd) pools (``layer``
+    unused); block_tables: (B, mps) int32 (-1 =
     unallocated); cache_pos: (B,) int32 per-slot positions (the new K/V row
     must already be WRITTEN at logical row cache_pos[b] — the write stays a
     plain block-table scatter outside the kernel). The decode kernel
@@ -232,47 +245,52 @@ def paged_decode(q, pool_k, pool_v, block_tables, cache_pos, *,
     table once for every query head of a kv head; a freed slot (all--1
     table) returns exactly 0. mesh/shard_axis (from specs.head_shard_axis)
     route through the head-sharded shard_map."""
-    return _paged_dispatch(q, pool_k, pool_v, block_tables, cache_pos,
+    return _paged_dispatch(q, pool_k, pool_v, block_tables, cache_pos, layer,
                            window, mesh=mesh, shard_axis=shard_axis)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "mesh", "shard_axis"))
-def paged_prefill(q, pool_k, pool_v, block_tables, start, *,
+def paged_prefill(q, pool_k, pool_v, block_tables, start, layer=None, *,
                   window: int = 0, mesh=None, shard_axis=None):
     """Continuation-chunk prefill attention against a paged KV cache.
 
     q: (B, C, H, hd) — C consecutive prompt positions, row i of slot b at
-    position ``start[b] + i``; the chunk's post-RoPE K/V rows must already
+    position ``start[b] + i``; pool_k/pool_v as in ``paged_decode``: the
+    layer-stacked pools and ``layer``, or one layer's pools (the page-grid
+    kernel reads the layer's pool out of the stack, folded for its
+    blocks); the chunk's post-RoPE K/V rows must already
     be spliced into the slot's pages (the engine's incremental per-chunk
     scatter), so prior chunks, aliased prefix pages, and the current chunk
     are all read uniformly through the block table. Causal masking is
     ``k_pos <= q_pos`` over the slot's logical rows; pages wholly beyond
     the chunk's causal frontier (or unallocated) are skipped, so mask work
     scales with the slot's LIVE pages instead of O(C x s_max)."""
-    return _paged_dispatch(q, pool_k, pool_v, block_tables, start, window,
-                           mesh=mesh, shard_axis=shard_axis)
+    return _paged_dispatch(q, pool_k, pool_v, block_tables, start, layer,
+                           window, mesh=mesh, shard_axis=shard_axis)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "mesh", "shard_axis"))
 def paged_decode_q8(q, pool_k, pool_v, k_scale, v_scale, block_tables,
-                    cache_pos, *, window: int = 0, mesh=None,
+                    cache_pos, layer=None, *, window: int = 0, mesh=None,
                     shard_axis=None):
-    """paged_decode over INT8 pools: pool_k/pool_v are int8, k_scale/v_scale
-    are (P,) — or per-kv-head-group (P, tp) — f32 per-page symmetric
-    scales. Dequant happens inside the kernel's gather (scales prefetched
-    to SMEM) — HBM traffic stays int8. mesh/shard_axis (from
+    """paged_decode over INT8 pools: pool_k/pool_v are int8 (stacked with
+    ``layer``, or one layer's), k_scale/v_scale are the read layer's (P,)
+    — or per-kv-head-group (P, tp) — f32 per-page symmetric scales.
+    Dequant happens inside the kernel's gather (scales prefetched to SMEM)
+    — HBM traffic stays int8. mesh/shard_axis (from
     specs.head_shard_axis) route through the head-sharded shard_map, where
     each shard dequantizes with its own group's scale column."""
-    return _paged_dispatch(q, pool_k, pool_v, block_tables, cache_pos,
+    return _paged_dispatch(q, pool_k, pool_v, block_tables, cache_pos, layer,
                            window, k_scale=k_scale, v_scale=v_scale,
                            mesh=mesh, shard_axis=shard_axis)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "mesh", "shard_axis"))
 def paged_prefill_q8(q, pool_k, pool_v, k_scale, v_scale, block_tables,
-                     start, *, window: int = 0, mesh=None, shard_axis=None):
+                     start, layer=None, *, window: int = 0, mesh=None,
+                     shard_axis=None):
     """paged_prefill over INT8 pools (see paged_decode_q8)."""
-    return _paged_dispatch(q, pool_k, pool_v, block_tables, start,
+    return _paged_dispatch(q, pool_k, pool_v, block_tables, start, layer,
                            window, k_scale=k_scale, v_scale=v_scale,
                            mesh=mesh, shard_axis=shard_axis)
 

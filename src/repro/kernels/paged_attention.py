@@ -3,8 +3,10 @@ KV page pool (the serving engine's vLLM-style cache layout).
 
 K/V pages are not a function of grid indices alone: each slot reads the
 pages named by ``block_tables[slot, :]`` out of the shared pool
-``(num_pages, page_size, KV, hd)``. The block table and the per-slot
-positions ride in as SCALAR-PREFETCH operands
+``(num_pages, page_size, KV, hd)``, or out of layer ``layer`` of the
+layer-stacked pool ``(L, num_pages, page_size, KV, hd)`` the serving steps
+keep. The block table, the per-slot positions and the layer index ride in
+as SCALAR-PREFETCH operands
 (``pltpu.PrefetchScalarGridSpec``), so they steer the DMAs before the body
 computes — the same "compute never waits on a dense, oversized buffer"
 dataflow the paper builds around Ultra RAM placement.
@@ -22,7 +24,8 @@ Three kernels: two for per-head K/V pools, chosen by the query shape
   DMAs the block's whole ``(ps, KV, hd)`` pages into double-buffered VMEM
   while the previous block computes, and each kv head's ``G = H // KV``
   query rows attend that head's rows together, so a page is fetched once
-  for its whole GQA group. A parked slot (``pos >= mps * ps``, all--1
+  for its whole GQA group. A layer-stacked pool is addressed in place at
+  the prefetched layer index. A parked slot (``pos >= mps * ps``, all--1
   table) runs no block.
 * **prefill chunk** (the page-grid kernel) — Sq == C consecutive prompt
   positions starting at ``start[b]`` (the engine's per-chunk splice writes
@@ -54,7 +57,9 @@ are divisible by (8, 128) or equal the array's own). The head axis is
 folded into the lane axis — q ``(B, Sq, H * hd)``, pools
 ``(P, ps, KV * hd)`` — so one head's block is ``(Sq, hd)`` / ``(ps, hd)``.
 The fold is a reshape, which for a bf16 pool on the TPU's tiled layout
-compiles to a relayout copy of the layer's pool on every chunk call. The
+compiles to a relayout copy of the layer's pool on every chunk call; out
+of a layer-stacked pool the layer is sliced out first (XLA does not fuse
+the slice into the relayout), and nothing is written back. The
 latent kernel DMAs whole ``(ps, width)`` rows, which Mosaic slices only in
 whole 128-lane tiles: on the TPU the pool stores each 576-wide latent row
 as 640 lanes, zeros beyond it (``ops.latent_row_width``), and the queries
@@ -172,25 +177,27 @@ def takes_decode_kernel(q, pool) -> bool:
     One query row per slot, and a kv-head axis that fills a 32-bit sublane
     (bf16: KV >= 2, int8: KV >= 4): Mosaic slices no page out of a
     narrower pool. Only a tp shard's slice of a pool is that narrow; it
-    stays on the page-grid kernel."""
-    return q.shape[1] == 1 and pool.shape[2] * pool.dtype.itemsize >= 4
+    stays on the page-grid kernel. ``pool`` is one layer's or the
+    layer-stacked pool (the kv-head axis is second to last in both)."""
+    return q.shape[1] == 1 and pool.shape[-2] * pool.dtype.itemsize >= 4
 
 
 def _decode_kernel(*refs, scale: float, window: int, page_size: int,
                    ppb: int, mps: int, quantized: bool):
     """Program b: slot b's decode row for every query head. Operand order:
-    scalar prefetch (block table, positions[, k/v page scales]), q block
-    (KV, G, hd), the K and V pools in HBM, the output block, then scratch
-    (K/V page buffers, DMA semaphores, m/l/acc).
+    scalar prefetch (block table, positions, layer[, k/v page scales]), q
+    block (KV, G, hd), the layer-stacked K and V pools in HBM, the output
+    block, then scratch (K/V page buffers, DMA semaphores, m/l/acc).
 
     The loop runs over the slot's live blocks only: blocks of ``ppb``
     pages from the first page the window can see to the page holding
     ``pos``. Each step starts the next block's page DMAs into the other
     buffer before it waits on and computes the current one."""
+    bt_ref, pos_ref, layer_ref = refs[:3]
     if quantized:
-        bt_ref, pos_ref, ks_ref, vs_ref, q_ref, k_hbm, v_hbm, o_ref = refs[:8]
+        ks_ref, vs_ref, q_ref, k_hbm, v_hbm, o_ref = refs[3:9]
     else:
-        bt_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref = refs[:6]
+        q_ref, k_hbm, v_hbm, o_ref = refs[3:7]
     kbuf, vbuf, sems, m_ref, l_ref, acc_ref = refs[-6:]
     kv_heads, G, _ = q_ref.shape
     keys = ppb * page_size
@@ -219,9 +226,10 @@ def _decode_kernel(*refs, scale: float, window: int, page_size: int,
         # an unallocated (-1) entry inside the span copies page 0; the
         # mask drops its keys
         src = jnp.maximum(bt_ref[b, idx0 + p], 0)
-        return (pltpu.make_async_copy(k_hbm.at[src], kbuf.at[slot, p],
+        layer = layer_ref[0]
+        return (pltpu.make_async_copy(k_hbm.at[layer, src], kbuf.at[slot, p],
                                       sems.at[0, slot]),
-                pltpu.make_async_copy(v_hbm.at[src], vbuf.at[slot, p],
+                pltpu.make_async_copy(v_hbm.at[layer, src], vbuf.at[slot, p],
                                       sems.at[1, slot]))
 
     def each_live_page(blk, slot, fn):
@@ -295,14 +303,18 @@ def _decode_kernel(*refs, scale: float, window: int, page_size: int,
     o_ref[...] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
-def paged_decode_attention(q, pool_k, pool_v, block_tables, pos, *,
+def paged_decode_attention(q, pool_k, pool_v, block_tables, pos, layer, *,
                            window: int = 0, interpret: bool = False,
                            k_scale=None, v_scale=None):
     """Decode attention over the paged pool, one program per slot.
 
-    q: (B, 1, H, hd); pool_k/pool_v: (P, page_size, KV, hd), read as
-    stored from HBM; block_tables: (B, mps) int32 (-1 = unallocated); pos:
-    (B,) int32, each slot's query position. Returns (B, 1, H, hd).
+    q: (B, 1, H, hd); pool_k/pool_v: the layer-stacked (L, P, page_size,
+    KV, hd) pools, read as stored from HBM, with ``layer`` the layer to
+    read (a traced scalar, prefetched: the kernel DMAs that layer's pages
+    straight out of the stack, so no copy of the layer's pool is made);
+    block_tables: (B, mps) int32 (-1 = unallocated); pos: (B,) int32, each
+    slot's query position; k_scale/v_scale: optional (P,) f32 page scales
+    of the layer read (int8 pools). Returns (B, 1, H, hd).
 
     Work follows each slot's live pages: the loop visits only the blocks
     of ``decode_pages_per_block(page_size)`` pages between the window's
@@ -311,7 +323,7 @@ def paged_decode_attention(q, pool_k, pool_v, block_tables, pos, *,
     ``pos >= mps * page_size`` (parked) runs no block and returns 0."""
     B, Sq, H, hd = q.shape
     assert Sq == 1, "decode kernel takes one query row per slot"
-    P, ps, KV, _ = pool_k.shape
+    _, P, ps, KV, _ = pool_k.shape
     assert H % KV == 0
     G = H // KV
     mps = block_tables.shape[1]
@@ -321,7 +333,8 @@ def paged_decode_attention(q, pool_k, pool_v, block_tables, pos, *,
                                window=window, page_size=ps, ppb=ppb, mps=mps,
                                quantized=quantized)
     prefetch = [jnp.asarray(block_tables, jnp.int32),
-                jnp.asarray(pos, jnp.int32)]
+                jnp.asarray(pos, jnp.int32),
+                jnp.reshape(jnp.asarray(layer, jnp.int32), (1,))]
     if quantized:
         prefetch += [jnp.asarray(k_scale, jnp.float32),
                      jnp.asarray(v_scale, jnp.float32)]
@@ -520,10 +533,12 @@ def paged_attention_latent(q, pool_c, block_tables, start, layer=None, *,
       pool_c.reshape(Lr, P, ps, width))
 
 
-def paged_attention(q, pool_k, pool_v, block_tables, start, *,
+def paged_attention(q, pool_k, pool_v, block_tables, start, layer, *,
                     window: int = 0, interpret: bool = False,
                     k_scale=None, v_scale=None):
-    """q: (B, Sq, H, hd); pool_k/pool_v: (P, page_size, KV, hd);
+    """q: (B, Sq, H, hd); pool_k/pool_v: the layer-stacked (L, P,
+    page_size, KV, hd) pools with ``layer`` the layer to read (a traced
+    scalar);
     block_tables: (B, mps) int32 page ids (-1 = unallocated);
     start: (B,) int32 — the position of each slot's FIRST query row (query
     row i is at ``start[b] + i``; logical key row r lives in page ``r // ps``
@@ -534,14 +549,18 @@ def paged_attention(q, pool_k, pool_v, block_tables, start, *,
     its body (scales prefetched to SMEM alongside the block table).
 
     One query row per slot (decode) runs :func:`paged_decode_attention`,
-    which visits only each slot's live pages (see
-    :func:`takes_decode_kernel`); a chunk of rows (prefill, speculative
-    verification) runs the page-grid kernel below."""
+    which visits only each slot's live pages and reads a stacked pool in
+    place (see :func:`takes_decode_kernel`); a chunk of rows (prefill,
+    speculative verification) runs the page-grid kernel below, over the
+    layer's pool read out of the stack and folded."""
     B, Sq, H, hd = q.shape
     if takes_decode_kernel(q, pool_k):
         return paged_decode_attention(q, pool_k, pool_v, block_tables, start,
-                                      window=window, interpret=interpret,
+                                      layer, window=window,
+                                      interpret=interpret,
                                       k_scale=k_scale, v_scale=v_scale)
+    pool_k, pool_v = (jax.lax.dynamic_index_in_dim(p, layer, 0, False)
+                      for p in (pool_k, pool_v))
     P, ps, KV, _ = pool_k.shape
     assert H % KV == 0
     G = H // KV
@@ -583,16 +602,18 @@ def paged_attention(q, pool_k, pool_v, block_tables, start, *,
 
 
 def paged_attention_head_sharded(dispatch, mesh, axis, q, pool_k, pool_v,
-                                 block_tables, start, *, window: int = 0,
-                                 k_scale=None, v_scale=None):
+                                 block_tables, start, layer, *,
+                                 window: int = 0, k_scale=None,
+                                 v_scale=None):
     """Tensor-parallel head-shard dispatch around the paged kernel.
 
     ``pallas_call`` lowers to a CustomCall that GSPMD cannot partition, so
     the tp serve path wraps the local dispatch in an explicit ``shard_map``:
-    q and both pools split on their head axes over the ``axis`` mesh axis
-    (the pool leaves are already RESIDENT with exactly this sharding, so no
-    data moves for them); block tables and start positions are replicated —
-    page ids are shard-invariant. The q8 page scales arrive as (P, tp)
+    q and both layer-stacked (L, P, ps, KV, hd) pools split on their head
+    axes over the ``axis`` mesh axis (the pool leaves are already RESIDENT
+    with exactly this sharding, so no data moves for them); block tables,
+    start positions and the layer index are replicated — page ids are
+    shard-invariant. The q8 page scales arrive as (P, tp)
     tables — one column per kv-head GROUP, resident sharded on the group
     axis alongside their kv heads — so each shard slices out its own (P, 1)
     column and squeezes it to the (P,) layout the local dispatch expects:
@@ -610,28 +631,31 @@ def paged_attention_head_sharded(dispatch, mesh, axis, q, pool_k, pool_v,
     boundaries (see sharding.specs.head_shard_axis)."""
     from jax.sharding import PartitionSpec as SP
 
-    heads = SP(None, None, axis, None)    # q/out (B,Sq,H,hd); pools (P,ps,KV,hd)
+    heads = SP(None, None, axis, None)    # q/out (B,Sq,H,hd)
+    pools = SP(None, None, None, axis, None)   # (L,P,ps,KV,hd)
     repl1 = SP(None)
     repl2 = SP(None, None)
+    layer = jnp.asarray(layer, jnp.int32)
 
     if k_scale is not None:
         scales = SP(None, axis)           # (P, tp) -> per-shard (P, 1)
-        def body(q_, pk_, pv_, bt_, st_, ks_, vs_):
-            return dispatch(q_, pk_, pv_, bt_, st_, window,
+        def body(q_, pk_, pv_, bt_, st_, layer_, ks_, vs_):
+            return dispatch(q_, pk_, pv_, bt_, st_, layer_, window,
                             k_scale=ks_[:, 0], v_scale=vs_[:, 0])
         return jax.shard_map(
             body, mesh=mesh,
-            in_specs=(heads, heads, heads, repl2, repl1, scales, scales),
+            in_specs=(heads, pools, pools, repl2, repl1, SP(), scales,
+                      scales),
             out_specs=heads, check_vma=False,
-        )(q, pool_k, pool_v, block_tables, start, k_scale, v_scale)
+        )(q, pool_k, pool_v, block_tables, start, layer, k_scale, v_scale)
 
-    def body(q_, pk_, pv_, bt_, st_):
-        return dispatch(q_, pk_, pv_, bt_, st_, window)
+    def body(q_, pk_, pv_, bt_, st_, layer_):
+        return dispatch(q_, pk_, pv_, bt_, st_, layer_, window)
     return jax.shard_map(
         body, mesh=mesh,
-        in_specs=(heads, heads, heads, repl2, repl1),
+        in_specs=(heads, pools, pools, repl2, repl1, SP()),
         out_specs=heads, check_vma=False,
-    )(q, pool_k, pool_v, block_tables, start)
+    )(q, pool_k, pool_v, block_tables, start, layer)
 
 
 def paged_attention_latent_head_sharded(dispatch, mesh, axis, q, pool_c,

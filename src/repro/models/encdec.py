@@ -211,15 +211,17 @@ def _decode_layer(cfg, lp, x, ck, cv, xk, xv, pos, positions, enc_pos,
                   block_tables=None, paged_impl: str = "einsum"):
     """One decoder decode layer (self-attn against cache + cross-attn).
     Exposed for roofline probes. With ``block_tables``, ck/cv are one layer's
-    (P, ps, KV, hd) page-pool slices (paged self-attn KV; the cross-attn
+    (P, ps, KV, hd) page-pool slices, handed to the paged path as a stack
+    of one (paged self-attn KV; the cross-attn
     xk/xv stay dense per slot — they are written once at prefill and fixed
     at ENC_LEN, so paging buys nothing); ``paged_impl`` selects the Pallas
     block-gather kernel or the masked-einsum reference read."""
     h = L.apply_norm(x, lp["ln1"], "layernorm")
     if block_tables is not None:
         out, ck, cv = L.attention_decode_paged(
-            lp["attn"], h, _self_dims(cfg, True), ck, cv, block_tables, pos,
-            positions, impl=paged_impl)
+            lp["attn"], h, _self_dims(cfg, True), ck[None], cv[None],
+            block_tables, pos, positions, impl=paged_impl, layer=0)
+        ck, cv = ck[0], cv[0]
     else:
         out, ck, cv = L.attention_decode(lp["attn"], h, _self_dims(cfg, True),
                                          ck, cv, pos, positions)

@@ -289,9 +289,11 @@ def _window_attn_decode_paged(lp, h, cfg, pool_k, pool_v, pool_spos,
     ridx = jnp.where(pos < L.INACTIVE_POS, pos % ring_w, 0)
     w_row, page_ok = L.paged_write_target(block_tables, ridx, ps)
     w_ok = (pos < L.INACTIVE_POS) & page_ok
-    pool_k = L.paged_write_rows(pool_k, k[:, 0], w_row, w_ok)
-    pool_v = L.paged_write_rows(pool_v, v[:, 0], w_row, w_ok)
-    pool_spos = L.paged_write_rows(pool_spos, pos, w_row, w_ok)
+    def write(pool, rows):               # one layer's pool: a stack of one
+        return L.paged_write_layer(pool[None], 0, rows, w_row, w_ok)[0]
+    pool_k = write(pool_k, k[:, 0])
+    pool_v = write(pool_v, v[:, 0])
+    pool_spos = write(pool_spos, pos)
 
     # read: gather the W-row ring view through the block table
     phys, ok = L.paged_row_indices(block_tables, ps, ring_w)

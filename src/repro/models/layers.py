@@ -680,16 +680,25 @@ def paged_write_target(block_tables, idx, page_size: int):
     return page * page_size + idx % page_size, page >= 0
 
 
-def paged_write_rows(pool, rows, row_idx, valid):
-    """Scatter one new row per slot into a flattened page pool.
-    pool: (P, ps, ...) -> returns same shape; rows: (B, ...) new values;
-    row_idx: (B,) flattened pool row per slot; valid: (B,) bool (invalid
-    writes are dropped — freed slots, unallocated pages)."""
-    P, ps = pool.shape[:2]
-    flat = pool.reshape((P * ps,) + pool.shape[2:])
+def paged_write_layer(pool, layer, rows, row_idx, valid):
+    """Scatter rows into layer ``layer`` of a layer-stacked page pool
+    (L, P, ps, ...), in place: rows (..., *pool.shape[3:]) at flattened
+    pool rows ``row_idx`` (...); valid (...) bool — invalid writes (freed
+    slots, unallocated pages) are dropped. No copy of the layer's pool is
+    made."""
+    Lr, P, ps = pool.shape[:3]
+    flat = pool.reshape((Lr, P * ps) + pool.shape[3:])
     idx = jnp.where(valid, row_idx, P * ps)          # OOB -> dropped
-    flat = flat.at[idx].set(rows.astype(flat.dtype), mode="drop")
+    flat = flat.at[layer, idx].set(rows.astype(flat.dtype), mode="drop")
     return flat.reshape(pool.shape)
+
+
+def paged_layer_view(pool, layer, phys):
+    """The reference read: layer ``layer``'s rows of a layer-stacked pool
+    (L, P, ps, ...) at flattened pool rows ``phys`` (B, n)."""
+    one = jax.lax.dynamic_index_in_dim(pool, layer, 0, keepdims=False)
+    P, ps = one.shape[:2]
+    return one.reshape((P * ps,) + one.shape[2:])[phys]
 
 
 # ------------------------------------------- int8 page writes (q8 backend)
@@ -724,8 +733,10 @@ def _dequant_page_block(pool_pg, scale_pg):
     return (g * scale_pg[:, None, :, None, None]).reshape(B, ps, KV, hd)
 
 
-def paged_append_row_q8(pool, scale, rows, block_tables, safe_pos, valid):
-    """Decode-append one K/V row per slot into an INT8 page pool.
+def paged_append_row_q8(pool, scale, rows, block_tables, safe_pos, valid,
+                        layer):
+    """Decode-append one K/V row per slot into layer ``layer`` of a
+    layer-stacked INT8 page pool.
 
     The page is a quantization block: appending a row changes the page's
     max-abs, so the slot's CURRENT page is dequantized (one page per slot —
@@ -733,10 +744,11 @@ def paged_append_row_q8(pool, scale, rows, block_tables, safe_pos, valid):
     page re-quantized with fresh symmetric per-group scales. Rows past the
     append offset are treated as stale (recycled-page payload) and zeroed.
     Invalid writes (freed slots, unallocated pages) drop both the page and
-    its scale update. pool: (P, ps, KV, hd) int8; scale: (P, T) f32 — one
+    its scale update. pool: (L, P, ps, KV, hd) int8, the page read and
+    set at ``[layer, page]`` in place; scale: the layer's (P, T) f32 — one
     column per kv-head group (T = serving tp degree, 1 when unsharded);
     rows: (B, KV, hd); safe_pos: (B,) clipped positions; valid: (B,)."""
-    P, ps = pool.shape[:2]
+    P, ps = pool.shape[1:3]
     mps = block_tables.shape[1]
     B = rows.shape[0]
     T = scale.shape[-1]
@@ -744,33 +756,44 @@ def paged_append_row_q8(pool, scale, rows, block_tables, safe_pos, valid):
         block_tables, jnp.clip(safe_pos // ps, 0, mps - 1)[:, None],
         axis=1)[:, 0]
     pg = jnp.clip(page, 0, P - 1)
-    blk = _dequant_page_block(pool[pg], scale[pg])
+    blk = _dequant_page_block(pool[layer, pg], scale[pg])
     off = safe_pos % ps
     blk = blk.at[jnp.arange(B), off].set(rows.astype(jnp.float32))
     content = jnp.arange(ps)[None, :] <= off[:, None]
     q, new_scale = _requant_page(blk, content, T)
-    tgt = jnp.where(valid & (page >= 0), pg, P)      # OOB -> dropped
-    pool = pool.at[tgt].set(q, mode="drop")
-    scale = scale.at[tgt].set(new_scale, mode="drop")
+    ok = valid & (page >= 0)
+
+    # page by page, in place: an XLA scatter of whole int8 pages carries
+    # the pool through the layer loop in another layout than the kernel
+    # reads, and relayouts it every layer
+    def write(i, pool):
+        at = (layer, pg[i], 0, 0, 0)
+        old = jax.lax.dynamic_slice(pool, at, (1, 1) + pool.shape[2:])
+        new = jnp.where(ok[i], q[i][None, None], old)
+        return jax.lax.dynamic_update_slice(pool, new, at)
+    pool = jax.lax.fori_loop(0, B, write, pool)
+    scale = scale.at[jnp.where(ok, pg, P)].set(new_scale, mode="drop")
     return pool, scale
 
 
 def paged_splice_chunk_q8(pool, scale, rows, block_tables, positions,
-                          write_floor):
-    """Chunk-splice C rows per slot into an INT8 page pool (the incremental
-    prefill splice, quantized). Visits each logical page the chunk overlaps
-    (a static loop of at most C//ps + 2 pages), overlays the chunk's rows on
-    the page's dequantized live content, and re-quantizes the whole page —
+                          write_floor, layer):
+    """Chunk-splice C rows per slot into layer ``layer`` of a layer-stacked
+    INT8 page pool (the incremental prefill splice, quantized). Visits each
+    logical page the chunk overlaps (a static loop of at most C//ps + 2
+    pages), overlays the chunk's rows on the page's dequantized live
+    content, and re-quantizes the whole page —
     so a COW-rematerialised partial page gets its fresh scales here, exactly
     once. Pages the chunk does NOT write (aliased prefix pages below
     ``write_floor``, including a full-hit's recomputed last row) are left
     untouched: their payload AND scales stay shared.
 
-    pool: (P, ps, KV, hd) int8; scale: (P, T) f32 — one column per kv-head
-    group (T = serving tp degree, 1 when unsharded); rows: (B, C, KV, hd);
+    pool: (L, P, ps, KV, hd) int8, pages read and set at ``[layer, page]``
+    in place; scale: the layer's (P, T) f32 — one column per kv-head group
+    (T = serving tp degree, 1 when unsharded); rows: (B, C, KV, hd);
     positions: (B, C) absolute query positions (contiguous, shared start);
     write_floor: scalar first writable logical row."""
-    P, ps = pool.shape[:2]
+    P, ps = pool.shape[1:3]
     B, C = positions.shape
     mps = block_tables.shape[1]
     n_rows = mps * ps
@@ -783,7 +806,7 @@ def paged_splice_chunk_q8(pool, scale, rows, block_tables, positions,
             block_tables, jnp.clip(lpg, 0, mps - 1)[:, None], axis=1)[:, 0]
         in_range = (lpg < mps) & (page >= 0)
         pg = jnp.clip(page, 0, P - 1)
-        blk = _dequant_page_block(pool[pg], scale[pg])
+        blk = _dequant_page_block(pool[layer, pg], scale[pg])
         row_pos = lpg[:, None] * ps + jnp.arange(ps)[None, :]   # (B, ps)
         ci = row_pos - start                          # chunk-relative index
         from_chunk = ((ci >= 0) & (ci < C) & (row_pos >= write_floor)
@@ -795,7 +818,7 @@ def paged_splice_chunk_q8(pool, scale, rows, block_tables, positions,
         q, new_scale = _requant_page(blk, content, T)
         writable = from_chunk.any(axis=1) & in_range
         tgt = jnp.where(writable, pg, P)
-        pool = pool.at[tgt].set(q, mode="drop")
+        pool = pool.at[layer, tgt].set(q, mode="drop")
         scale = scale.at[tgt].set(new_scale, mode="drop")
     return pool, scale
 
@@ -817,15 +840,19 @@ def dequant_paged_view(view, phys, scale, page_size: int, dtype):
 
 def attention_decode_paged(params, x, dims: AttnDims, pool_k, pool_v,
                            block_tables, cache_pos, positions,
-                           impl: str = "einsum", *, k_scale=None,
+                           impl: str = "einsum", *, layer, k_scale=None,
                            v_scale=None):
     """Single-token decode against a PAGED KV cache (vLLM-style block tables).
 
-    x: (B,1,D); pool_k/pool_v: (P, page_size, KV, hd) — ONE layer's slice of
-    the shared page pool (no batch axis: memory scales with allocated pages,
-    not slots x s_max); block_tables: (B, mps) int32, -1 = unallocated;
-    cache_pos: (B,) per-slot positions (the paged path is serving-only, so
-    positions are always a vector). Returns (out, new_pool_k, new_pool_v).
+    x: (B,1,D); pool_k/pool_v: the layer-stacked (L, P, page_size, KV, hd)
+    page pools (no batch axis: memory scales with allocated pages, not
+    slots x s_max) with ``layer`` this layer's index — the write scatters
+    at ``[layer, row]`` and the kernel reads the layer's pages in the
+    stack, so no copy of the layer's pool is made (a caller holding one
+    layer's pools passes them as a stack of one at 0); block_tables: (B,
+    mps) int32, -1 = unallocated; cache_pos: (B,) per-slot positions (the
+    paged path is serving-only, so positions are always a vector). Returns
+    (out, new_pool_k, new_pool_v).
 
     Writes go through block-table indirection: slot b's new K/V row lands in
     page block_tables[b, pos//ps] at offset pos % ps; writes from slots whose
@@ -849,14 +876,14 @@ def attention_decode_paged(params, x, dims: AttnDims, pool_k, pool_v,
     same dot-then-scale f32 operation order).
 
     ``k_scale``/``v_scale``: optional (P, T) f32 per-page per-kv-head-group
-    symmetric scales (T = serving tp degree, 1 when unsharded) — the
-    int8-backend path. The new row's write re-quantizes the slot's
-    current page in place (``paged_append_row_q8``), reads dequantize
-    per-page (inside the Pallas kernel's gather on the kernel path, each
-    tp shard using its own group's scale column), and the return grows to
-    (out, pool_k, pool_v, k_scale, v_scale)."""
+    symmetric scales of this layer (T = serving tp degree, 1 when
+    unsharded) — the int8-backend path. The new row's write re-quantizes
+    the slot's current page in place (``paged_append_row_q8``), reads
+    dequantize per-page (inside the Pallas kernel's gather on the kernel
+    path, each tp shard using its own group's scale column), and the
+    return grows to (out, pool_k, pool_v, k_scale, v_scale)."""
     q, k, v = _qkv(params, x, dims, positions)
-    P, ps, KV, hd = pool_k.shape
+    ps, KV, hd = pool_k.shape[2:]
     B = q.shape[0]
     mps = block_tables.shape[1]
     n_rows = mps * ps
@@ -870,12 +897,14 @@ def attention_decode_paged(params, x, dims: AttnDims, pool_k, pool_v,
     w_ok = (cache_pos >= 0) & (cache_pos < n_rows) & page_ok
     if quantized:
         pool_k, k_scale = paged_append_row_q8(pool_k, k_scale, k[:, 0],
-                                              block_tables, safe_pos, w_ok)
+                                              block_tables, safe_pos, w_ok,
+                                              layer)
         pool_v, v_scale = paged_append_row_q8(pool_v, v_scale, v[:, 0],
-                                              block_tables, safe_pos, w_ok)
+                                              block_tables, safe_pos, w_ok,
+                                              layer)
     else:
-        pool_k = paged_write_rows(pool_k, k[:, 0], w_row, w_ok)
-        pool_v = paged_write_rows(pool_v, v[:, 0], w_row, w_ok)
+        pool_k = paged_write_layer(pool_k, layer, k[:, 0], w_row, w_ok)
+        pool_v = paged_write_layer(pool_v, layer, v[:, 0], w_row, w_ok)
 
     if impl == "kernel":
         from repro.kernels import ops as kops
@@ -886,22 +915,20 @@ def attention_decode_paged(params, x, dims: AttnDims, pool_k, pool_v,
         tp_mesh, tp_axis = _sp.head_shard_axis(H, KV)
         if quantized:
             out = kops.paged_decode_q8(q, pool_k, pool_v, k_scale, v_scale,
-                                       block_tables, cache_pos,
+                                       block_tables, cache_pos, layer,
                                        window=dims.window,
                                        mesh=tp_mesh, shard_axis=tp_axis)
         else:
             out = kops.paged_decode(q, pool_k, pool_v, block_tables,
-                                    cache_pos, window=dims.window,
+                                    cache_pos, layer, window=dims.window,
                                     mesh=tp_mesh, shard_axis=tp_axis)
         out = out.reshape(B, 1, H * hd)
     else:
         # ---- gather each slot's logical view and attend
         qg = q.reshape(B, 1, KV, G, hd)
         phys, ok = paged_row_indices(block_tables, ps, n_rows)
-        flat_k = pool_k.reshape(P * ps, KV, hd)
-        flat_v = pool_v.reshape(P * ps, KV, hd)
-        view_k = flat_k[phys]                        # (B, n_rows, KV, hd)
-        view_v = flat_v[phys]
+        view_k = paged_layer_view(pool_k, layer, phys)   # (B, n_rows, KV, hd)
+        view_v = paged_layer_view(pool_v, layer, phys)
         if quantized:
             view_k = dequant_paged_view(view_k, phys, k_scale, ps, q.dtype)
             view_v = dequant_paged_view(view_v, phys, v_scale, ps, q.dtype)
@@ -923,13 +950,15 @@ def attention_decode_paged(params, x, dims: AttnDims, pool_k, pool_v,
 
 def attention_prefill_chunk_paged(params, x, dims: AttnDims, pool_k, pool_v,
                                   block_tables, positions, write_floor,
-                                  impl: str = "kernel", *, k_scale=None,
-                                  v_scale=None):
+                                  impl: str = "kernel", *, layer,
+                                  k_scale=None, v_scale=None):
     """Multi-token prefill-chunk attention DIRECTLY against the paged pool —
     the incremental-splice counterpart of ``attention_prefill_chunk``.
 
-    x: (B, C, D); pool_k/pool_v: one layer's (P, ps, KV, hd) pool slice;
-    block_tables: (B, mps) rows for the chunk's slots; positions: (B, C)
+    x: (B, C, D); pool_k/pool_v: the layer-stacked (L, P, ps, KV, hd)
+    pools with ``layer`` this layer's index (the chunk's rows scatter at
+    ``[layer, row]`` in place); block_tables: (B, mps) rows for the
+    chunk's slots; positions: (B, C)
     absolute query positions (row i at ``positions[:, 0] + i`` — the engine
     groups jobs so a chunk's positions are contiguous and share a start);
     write_floor: scalar — the first logical row this request may WRITE.
@@ -943,19 +972,21 @@ def attention_prefill_chunk_paged(params, x, dims: AttnDims, pool_k, pool_v,
     first chunk runs. Attention then reads prior chunks, aliased prefix
     pages, and the current chunk uniformly through the block table:
     ``impl='kernel'`` uses the block-skipping Pallas kernel
-    (``ops.paged_prefill``); ``impl='einsum'`` is the masked-gather
+    (``ops.paged_prefill``, which copies the layer's pool out of the stack
+    and folds it for its blocks); ``impl='einsum'`` is the masked-gather
     reference over the full block-table span. Returns
     (out (B, C, H*hd) @ wo, new_pool_k, new_pool_v).
 
     ``k_scale``/``v_scale``: optional (P, T) f32 per-page per-kv-head-group
-    scales (T = serving tp degree) — the int8 backend. The splice
+    scales of this layer (T = serving tp degree) — the int8 backend. The
+    splice
     re-quantizes each page the chunk writes
     (``paged_splice_chunk_q8``; untouched aliased prefix pages keep their
     shared scale), reads dequantize per-page, and the return grows to
     (out, pool_k, pool_v, k_scale, v_scale)."""
     q, k, v = _qkv(params, x, dims, positions)
     B, C, KV, hd = k.shape
-    P, ps = pool_k.shape[:2]
+    ps = pool_k.shape[2]
     mps = block_tables.shape[1]
     n_rows = mps * ps
     H = dims.num_heads
@@ -965,24 +996,18 @@ def attention_prefill_chunk_paged(params, x, dims: AttnDims, pool_k, pool_v,
     if quantized:
         pool_k, k_scale = paged_splice_chunk_q8(pool_k, k_scale, k,
                                                 block_tables, positions,
-                                                write_floor)
+                                                write_floor, layer)
         pool_v, v_scale = paged_splice_chunk_q8(pool_v, v_scale, v,
                                                 block_tables, positions,
-                                                write_floor)
-        flat_k = pool_k.reshape(P * ps, KV, hd)
-        flat_v = pool_v.reshape(P * ps, KV, hd)
+                                                write_floor, layer)
     else:
         page = jnp.take_along_axis(
             block_tables, jnp.clip(positions // ps, 0, mps - 1), axis=1)
         w_ok = ((page >= 0) & (positions >= write_floor)
                 & (positions >= 0) & (positions < n_rows))
-        w_rows = jnp.where(w_ok, page * ps + positions % ps, P * ps)  # drop
-        flat_k = pool_k.reshape(P * ps, KV, hd)
-        flat_v = pool_v.reshape(P * ps, KV, hd)
-        flat_k = flat_k.at[w_rows].set(k.astype(flat_k.dtype), mode="drop")
-        flat_v = flat_v.at[w_rows].set(v.astype(flat_v.dtype), mode="drop")
-        pool_k = flat_k.reshape(pool_k.shape)
-        pool_v = flat_v.reshape(pool_v.shape)
+        w_rows = page * ps + positions % ps
+        pool_k = paged_write_layer(pool_k, layer, k, w_rows, w_ok)
+        pool_v = paged_write_layer(pool_v, layer, v, w_rows, w_ok)
 
     if impl == "kernel":
         from repro.kernels import ops as kops
@@ -990,20 +1015,21 @@ def attention_prefill_chunk_paged(params, x, dims: AttnDims, pool_k, pool_v,
         tp_mesh, tp_axis = _sp.head_shard_axis(H, KV)
         if quantized:
             out = kops.paged_prefill_q8(q, pool_k, pool_v, k_scale, v_scale,
-                                        block_tables, positions[:, 0],
+                                        block_tables, positions[:, 0], layer,
                                         window=dims.window,
                                         mesh=tp_mesh, shard_axis=tp_axis)
         else:
             out = kops.paged_prefill(q, pool_k, pool_v, block_tables,
-                                     positions[:, 0], window=dims.window,
+                                     positions[:, 0], layer,
+                                     window=dims.window,
                                      mesh=tp_mesh, shard_axis=tp_axis)
         out = out.reshape(B, C, H * hd)
     else:
         G = H // KV
         qg = q.reshape(B, C, KV, G, hd)
         phys, ok = paged_row_indices(block_tables, ps, n_rows)
-        view_k = flat_k[phys]                        # (B, n_rows, KV, hd)
-        view_v = flat_v[phys]
+        view_k = paged_layer_view(pool_k, layer, phys)   # (B, n_rows, KV, hd)
+        view_v = paged_layer_view(pool_v, layer, phys)
         if quantized:
             view_k = dequant_paged_view(view_k, phys, k_scale, ps, q.dtype)
             view_v = dequant_paged_view(view_v, phys, v_scale, ps, q.dtype)
@@ -1219,25 +1245,6 @@ def mla_attention_prefill_chunk(params, x, dims: MLADims, cache_c, start,
     return _mla_out(params, attn, dims, x), cache_c
 
 
-def _latent_write(pool, layer, rows, row_idx, valid):
-    """Scatter latent rows into layer ``layer`` of the layer-stacked pool
-    (L, P, ps, 1, width), in place: rows (..., 1, width) at flattened pool
-    rows ``row_idx`` (...); invalid writes are dropped."""
-    Lr, P, ps = pool.shape[:3]
-    flat = pool.reshape((Lr, P * ps) + pool.shape[3:])
-    idx = jnp.where(valid, row_idx, P * ps)          # OOB -> dropped
-    flat = flat.at[layer, idx].set(rows.astype(flat.dtype), mode="drop")
-    return flat.reshape(pool.shape)
-
-
-def _latent_view(pool, layer, phys, width):
-    """The reference read: layer ``layer``'s rows at flattened pool rows
-    ``phys`` (B, n), cut to the latent's own width."""
-    one = jax.lax.dynamic_index_in_dim(pool, layer, 0, keepdims=False)
-    P, ps = one.shape[:2]
-    return fit_rows(one.reshape((P * ps,) + one.shape[2:])[phys], width)
-
-
 def mla_attention_decode_paged(params, x, dims: MLADims, pool_c, layer,
                                block_tables, cache_pos, positions,
                                impl: str = "einsum"):
@@ -1263,8 +1270,8 @@ def mla_attention_decode_paged(params, x, dims: MLADims, pool_c, layer,
     safe_pos = jnp.clip(cache_pos, 0, n_rows - 1)
     w_row, page_ok = paged_write_target(block_tables, safe_pos, ps)
     w_ok = (cache_pos >= 0) & (cache_pos < n_rows) & page_ok
-    pool_c = _latent_write(pool_c, layer, fit_rows(rows[:, 0], width),
-                           w_row, w_ok)
+    pool_c = paged_write_layer(pool_c, layer, fit_rows(rows[:, 0], width),
+                               w_row, w_ok)
 
     if impl == "kernel":
         from repro.kernels import ops as kops
@@ -1279,7 +1286,8 @@ def mla_attention_decode_paged(params, x, dims: MLADims, pool_c, layer,
     else:
         qg = q.reshape(B, 1, 1, H, dims.latent_dim)
         phys, ok = paged_row_indices(block_tables, ps, n_rows)
-        view = _latent_view(pool_c, layer, phys, dims.latent_dim)
+        view = fit_rows(paged_layer_view(pool_c, layer, phys),
+                        dims.latent_dim)
         k_positions = jnp.where(ok, jnp.arange(n_rows)[None, :], INACTIVE_POS)
         m, l, acc = _decode_sdpa_local(qg, view, view[..., :c],
                                        cache_pos[:, None], k_positions, 0,
@@ -1311,8 +1319,8 @@ def mla_attention_prefill_chunk_paged(params, x, dims: MLADims, pool_c,
         block_tables, jnp.clip(positions // ps, 0, mps - 1), axis=1)
     w_ok = ((page >= 0) & (positions >= write_floor)
             & (positions >= 0) & (positions < n_rows))
-    pool_c = _latent_write(pool_c, layer, fit_rows(rows, width),
-                           page * ps + positions % ps, w_ok)
+    pool_c = paged_write_layer(pool_c, layer, fit_rows(rows, width),
+                               page * ps + positions % ps, w_ok)
 
     if impl == "kernel":
         from repro.kernels import ops as kops
@@ -1326,7 +1334,8 @@ def mla_attention_prefill_chunk_paged(params, x, dims: MLADims, pool_c,
     else:
         qg = q.reshape(B, C, 1, H, dims.latent_dim)
         phys, ok = paged_row_indices(block_tables, ps, n_rows)
-        view = _latent_view(pool_c, layer, phys, dims.latent_dim)
+        view = fit_rows(paged_layer_view(pool_c, layer, phys),
+                        dims.latent_dim)
         scores = jnp.einsum("bqkgh,bskh->bkgqs", qg, view.astype(q.dtype)
                             ).astype(jnp.float32) / math.sqrt(dims.scale_dim)
         k_pos = jnp.where(ok, jnp.arange(n_rows)[None, :], INACTIVE_POS)

@@ -343,10 +343,12 @@ def cache_logical(cfg: ArchConfig):
     return {"k": kv, "v": kv, "pos": ()}
 
 
-# The serving layers. Each takes one layer's cache as a dict of per-layer
-# arrays ("k", and "v" for per-head K/V, "k_scale"/"v_scale" beside int8
-# pools; an MLA latent cache has "k" only) and returns the layer's output,
-# the updated dict and its MoE counts; ``_run_layers`` drives them.
+# The serving layers. Each takes one layer's cache as a dict ("k", and "v"
+# for per-head K/V, "k_scale"/"v_scale" beside int8 pools; an MLA latent
+# cache has "k" only) — per-layer arrays, but for a paged cache's pools,
+# which come layer-stacked with the layer's index — and returns the
+# layer's output, the updated dict and its MoE counts; ``_run_layers``
+# drives them.
 def _ffn(cfg: ArchConfig, lp, h):
     """(y, counts) of the layer's MLP, or of its MoE as serving computes
     it: dropless, only the held experts' part (``layers.moe_dropless``)."""
@@ -367,8 +369,8 @@ def _decode_attn(cfg: ArchConfig, lp, h, c, pos, positions, bt, impl,
                  layer):
     """One decode token's attention against this layer's cache ``c``:
     dense rows, or a page pool read through ``bt`` with the Pallas kernel
-    (``impl='kernel'``) or the masked-einsum reference. A latent page pool
-    comes whole (``_run_layers``' ``whole``), addressed at ``layer``."""
+    (``impl='kernel'``) or the masked-einsum reference. Page pools come
+    layer-stacked, addressed at ``layer``."""
     if _is_mla(cfg):
         if bt is not None:
             out, k = L.mla_attention_decode_paged(
@@ -386,11 +388,12 @@ def _decode_attn(cfg: ArchConfig, lp, h, c, pos, positions, bt, impl,
     if "k_scale" in c:
         out, k, v, ks, vs = L.attention_decode_paged(
             lp["attn"], h, dims, c["k"], c["v"], bt, pos, positions,
-            impl=impl, k_scale=c["k_scale"], v_scale=c["v_scale"])
+            impl=impl, layer=layer, k_scale=c["k_scale"],
+            v_scale=c["v_scale"])
         return out, {"k": k, "v": v, "k_scale": ks, "v_scale": vs}
     out, k, v = L.attention_decode_paged(lp["attn"], h, dims, c["k"],
                                          c["v"], bt, pos, positions,
-                                         impl=impl)
+                                         impl=impl, layer=layer)
     return out, {"k": k, "v": v}
 
 
@@ -410,8 +413,8 @@ def _chunk_attn(cfg: ArchConfig, lp, h, c, start, positions, use_kernel):
 def _chunk_paged_attn(cfg: ArchConfig, lp, h, c, bt, positions, write_floor,
                       impl, layer):
     """A prompt chunk's attention, its rows spliced into the page pool
-    (rows below ``write_floor`` dropped) and read through ``bt``; a latent
-    pool comes whole, addressed at ``layer``."""
+    (rows below ``write_floor`` dropped) and read through ``bt``; the
+    pools come layer-stacked, addressed at ``layer``."""
     if _is_mla(cfg):
         out, k = L.mla_attention_prefill_chunk_paged(
             lp["attn"], h, _mla_dims(cfg), c["k"], layer, bt, positions,
@@ -421,11 +424,12 @@ def _chunk_paged_attn(cfg: ArchConfig, lp, h, c, bt, positions, write_floor,
     if "k_scale" in c:
         out, k, v, ks, vs = L.attention_prefill_chunk_paged(
             lp["attn"], h, dims, c["k"], c["v"], bt, positions, write_floor,
-            impl=impl, k_scale=c["k_scale"], v_scale=c["v_scale"])
+            impl=impl, layer=layer, k_scale=c["k_scale"],
+            v_scale=c["v_scale"])
         return out, {"k": k, "v": v, "k_scale": ks, "v_scale": vs}
     out, k, v = L.attention_prefill_chunk_paged(
         lp["attn"], h, dims, c["k"], c["v"], bt, positions, write_floor,
-        impl=impl)
+        impl=impl, layer=layer)
     return out, {"k": k, "v": v}
 
 
@@ -453,21 +457,28 @@ def _layer_params(stack, j):
 
 # the cache leaves stacked over layers (everything else is per slot)
 _LAYER_LEAVES = ("k", "v", "k_scale", "v_scale")
+# of those, the page pools of a paged cache
+_POOL_LEAVES = ("k", "v")
 
 
-def _run_layers(params, cfg: ArchConfig, x, cache, layer_fn, whole=()):
+def _run_layers(params, cfg: ArchConfig, x, cache, layer_fn):
     """Every decoder layer over ``x``: a fori_loop per stack (the leading
     ``dense_layers``, then ``layers``) carrying x, the cache's layer-
-    stacked leaves and the MoE counts summed over layers. Layer i takes
-    slice i of each stacked leaf and updates it back into the stack: a
-    lax.scan over per-layer cache slices would stack fresh output buffers
-    (a full extra cache copy in HBM), because XLA cannot alias scan ys to
-    donated inputs. The leaves named in ``whole`` go to the layer entire,
-    with the layer's index, and come back entire: that layer writes and
-    reads its rows in the stack, so its slice is not copied out and back.
-    ``layer_fn(lp, x, c, i) -> (x, c, counts)`` with ``c`` one layer's
-    dict of those leaves. Returns (x, stacked leaves, counts)."""
+    stacked leaves and the MoE counts summed over layers. A paged cache
+    (one with ``block_tables``) gives its page pools — K and V, or the
+    latent pool — to every layer whole, with the layer's index, and takes
+    them back whole: the layer writes its new rows into the stack and
+    its kernel reads the layer's pages there, so no layer's pool is copied
+    out of the stack and back. Every other stacked leaf (a dense cache's
+    rows, the int8 pools' few-KB scale tables, which the kernels prefetch
+    whole into scalar memory) goes as slice i, updated back into the
+    stack: a lax.scan over per-layer cache slices would stack fresh output
+    buffers (a full extra cache copy in HBM), because XLA cannot alias
+    scan ys to donated inputs. ``layer_fn(lp, x, c, i) -> (x, c, counts)``
+    with ``c`` layer i's dict of those leaves. Returns (x, stacked leaves,
+    counts)."""
     stacked = {k: cache[k] for k in _LAYER_LEAVES if k in cache}
+    whole = _POOL_LEAVES if "block_tables" in cache else ()
 
     def loop(name, lo, hi, carry):
         def body(i, carry):
@@ -502,16 +513,20 @@ def _decode_layer(cfg: ArchConfig, lp, x, ck, cv, pos, positions,
     """One per-head-K/V decode layer: returns (x, new_ck, new_cv). Exposed
     for roofline probes (launch/probes.py) and the VLM super-layer. When
     ``block_tables`` is given, ck/cv are one layer's (P, ps, KV, hd)
-    page-pool slice and attention goes through the paged path
-    (models/layers.py); ``paged_impl`` selects the Pallas kernel or the
+    page-pool slice, handed to the paged path (models/layers.py) as a
+    stack of one; ``paged_impl`` selects the Pallas kernel or the
     masked-einsum reference read. ``kscale``/``vscale`` are this layer's
     (P, tp) per-page per-kv-head-group dequant scales for int8 pools; when
     given the return grows to (x, ck, cv, kscale, vscale)."""
-    c = {"k": ck, "v": cv}
+    paged = block_tables is not None
+    c = {"k": ck[None], "v": cv[None]} if paged else {"k": ck, "v": cv}
     if kscale is not None:
         c.update(k_scale=kscale, v_scale=vscale)
     x, c, _ = _residual(cfg, lp, x, lambda h: _decode_attn(
-        cfg, lp, h, c, pos, positions, block_tables, paged_impl, None))
+        cfg, lp, h, c, pos, positions, block_tables, paged_impl,
+        0 if paged else None))
+    if paged:
+        c.update(k=c["k"][0], v=c["v"][0])
     if kscale is not None:
         return x, c["k"], c["v"], c["k_scale"], c["v_scale"]
     return x, c["k"], c["v"]
@@ -606,13 +621,15 @@ def _prefill_chunk_layer_paged(cfg: ArchConfig, lp, x, pk, pv, bt, positions,
     incremental splice) and attention reads everything — prior chunks,
     aliased prefix pages, the current chunk — through the block table.
     Same residual structure as ``_prefill_chunk_layer``/``_decode_layer``.
-    Int8 pools carry per-layer (P, tp) per-group scales and the return
-    grows accordingly."""
-    c = {"k": pk, "v": pv}
+    pk/pv are one layer's pools, handed on as a stack of one. Int8 pools
+    carry per-layer (P, tp) per-group scales and the return grows
+    accordingly."""
+    c = {"k": pk[None], "v": pv[None]}
     if kscale is not None:
         c.update(k_scale=kscale, v_scale=vscale)
     x, c, _ = _residual(cfg, lp, x, lambda h: _chunk_paged_attn(
-        cfg, lp, h, c, bt, positions, write_floor, impl, None))
+        cfg, lp, h, c, bt, positions, write_floor, impl, 0))
+    c.update(k=c["k"][0], v=c["v"][0])
     if kscale is not None:
         return x, c["k"], c["v"], c["k_scale"], c["v_scale"]
     return x, c["k"], c["v"]
@@ -664,9 +681,10 @@ def prefill_chunk_paged(params, cfg: ArchConfig, tokens, cache, *, bt_rows,
     the scatter. Every chunk is uniform (no first/continuation split): the
     chunk writes its K/V rows into the group's pages, then attends the
     pages through the block table, so a prefix-cache hit needs NO gather
-    seeding — aliased pages are read in place. An int8-backend cache
-    carries (L, P, tp) per-page per-group scale leaves beside the pools,
-    threaded through the layer loop like the pools. Returns
+    seeding — aliased pages are read in place. The pools go to each layer
+    whole, with its index (``_run_layers``). An int8-backend cache carries
+    (L, P, tp) per-page per-group scale leaves beside the pools, which the
+    layer loop slices per layer. Returns
     (last-position logits (K, 1, Vp) float32, cache with updated pools
     [, MoE counts with ``return_stats``]); the engine advances the group's
     ``pos`` at job completion."""
@@ -709,8 +727,7 @@ def prefill_chunk_paged(params, cfg: ArchConfig, tokens, cache, *, bt_rows,
             params, cfg, x, cache,
             lambda lp, x, c, i: _residual(cfg, lp, x, lambda h: (
                 _chunk_paged_attn(cfg, lp, h, c, bt_rows, positions,
-                                  write_floor, attn_impl, i))),
-            whole=("k",) if _is_mla(cfg) else ())
+                                  write_floor, attn_impl, i))))
 
     out = (_logits(params, cfg, x[:, -1:]), dict(cache, **new))
     return out + (counts,) if return_stats else out
@@ -723,7 +740,8 @@ def decode_step(params, cfg: ArchConfig, token, cache, *, image_embeds=None,
     with ``return_stats``]).
 
     Layers run in a fori_loop carrying the FULL (L,B,S,KV,hd) cache with
-    in-place dynamic updates (``_run_layers``).
+    in-place dynamic updates, a paged cache's pools going to each layer
+    whole (``_run_layers``).
 
     cache["pos"] may be a scalar (lockstep batch) or a (B,) per-slot vector
     (serving engine with continuous batching). A cache carrying a
@@ -732,7 +750,7 @@ def decode_step(params, cfg: ArchConfig, token, cache, *, image_embeds=None,
     through the block-table-indirect attention path — through the Pallas
     kernel with ``paged_attn_impl='kernel'``, the masked-einsum reference
     otherwise. An int8-backend cache carries (L, P, tp) per-page per-group
-    scale leaves that thread through the loop like the pools."""
+    scale leaves, which the layer loop slices per layer."""
     B = token.shape[0]
     pos = cache["pos"]
     bt = cache.get("block_tables")
@@ -771,8 +789,7 @@ def decode_step(params, cfg: ArchConfig, token, cache, *, image_embeds=None,
         x, new, counts = _run_layers(
             params, cfg, x, cache,
             lambda lp, x, c, i: _residual(cfg, lp, x, lambda h: _decode_attn(
-                cfg, lp, h, c, pos, positions, bt, paged_attn_impl, i)),
-            whole=("k",) if _is_mla(cfg) and bt is not None else ())
+                cfg, lp, h, c, pos, positions, bt, paged_attn_impl, i)))
 
     out = (_logits(params, cfg, x), dict(cache, pos=pos + 1, **new))
     return out + (counts,) if return_stats else out

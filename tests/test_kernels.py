@@ -113,16 +113,33 @@ def _paged_setup(key, B, Sq, H, KV, hd, P, ps, mps, fill):
     return q, pk, pv, jnp.asarray(bt)
 
 
-@pytest.mark.parametrize("cfg", [
+def _in_stack(key, pool, layer: int = 1, layers: int = 3):
+    """A ``layers``-deep stack of pools shaped like ``pool``, holding
+    ``pool`` at ``layer`` and other values at every other layer."""
+    noise = [jnp.clip(_rand(k, pool.shape) * 40, -127, 127).astype(pool.dtype)
+             for k in jax.random.split(key, layers - 1)]
+    return jnp.stack(noise[:layer] + [pool] + noise[layer:])
+
+
+def _one_layer_then_stacked(cases, ids):
+    """(case, stacked) params: each case over one layer's pool under its
+    own id, then over a layer-stacked pool as ``<id>-stacked``."""
+    return [pytest.param(c, stacked, id=i + "-stacked" * stacked)
+            for stacked in (False, True) for c, i in zip(cases, ids)]
+
+
+@pytest.mark.parametrize("cfg,stacked", _one_layer_then_stacked([
     dict(Sq=8, H=4, KV=2, ps=8, mps=4, window=0),     # GQA prefill chunk
     dict(Sq=1, H=4, KV=4, ps=8, mps=4, window=0),     # decode shape
     dict(Sq=16, H=8, KV=2, ps=4, mps=8, window=6),    # sliding window
     dict(Sq=8, H=2, KV=1, ps=16, mps=2, window=0),    # page > chunk
-])
-def test_paged_attention_matches_ref(cfg):
+], ["cfg0", "cfg1", "cfg2", "cfg3"]))
+def test_paged_attention_matches_ref(cfg, stacked):
     """Block-table gather + block-skip kernel vs the masked-gather oracle,
     across GQA grouping, decode/prefill query widths, sliding windows, and
-    partially-filled last pages (start positions land mid-page)."""
+    partially-filled last pages (start positions land mid-page). Over a
+    layer-stacked pool at a traced layer the kernels read that layer in
+    place and give bit for bit what they give over its own pool."""
     Sq, H, KV, ps, mps = (cfg["Sq"], cfg["H"], cfg["KV"], cfg["ps"],
                           cfg["mps"])
     B, hd, P = 2, 16, 2 * mps + 3
@@ -134,6 +151,13 @@ def test_paged_attention_matches_ref(cfg):
     want = ref.paged_attention(q, pk, pv, bt, start, window=cfg["window"])
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
+    if stacked:
+        ks = jax.random.split(KEY, 2)
+        from_stack = ops.paged_prefill(
+            q, _in_stack(ks[0], pk), _in_stack(ks[1], pv), bt, start,
+            jnp.int32(1), window=cfg["window"])
+        np.testing.assert_array_equal(np.asarray(from_stack),
+                                      np.asarray(got))
 
 
 def test_paged_attention_skips_unallocated_and_future_pages():
@@ -221,7 +245,7 @@ def _decode_case(H, KV, ps, mps, pos, hole=None):
 
 # blocks are pa.DECODE_BLOCK_KEYS (256) keys: positions 255 and 511 end a
 # block exactly, 256 starts one
-@pytest.mark.parametrize("case", [
+@pytest.mark.parametrize("case,stacked", _one_layer_then_stacked([
     dict(H=4, KV=4, ps=8, mps=48, pos=[19, 300]),             # G 1, part page
     dict(H=10, KV=2, ps=8, mps=48, pos=[255, 256]),           # G 5, boundary
     dict(H=7, KV=1, ps=16, mps=40, pos=[100, 511]),           # G 7, boundary
@@ -230,30 +254,50 @@ def _decode_case(H, KV, ps, mps, pos, hole=None):
     dict(H=4, KV=2, ps=8, mps=96, pos=[511, 300], window=256),
     dict(H=4, KV=2, ps=8, mps=48, pos=[300, 40], hole=(0, 1)),
     dict(H=8, KV=4, ps=8, mps=48, pos=[77, 260], int8=True),
-], ids=["g1", "g5-block-edge", "g7-block-edge", "ragged", "window",
-        "window-block-edge", "unallocated-live-page", "int8"])
-def test_paged_decode_kernel_matches_ref(case):
+    dict(H=8, KV=2, ps=16, mps=40, pos=[77, 300], bf16=True),
+], ["g1", "g5-block-edge", "g7-block-edge", "ragged", "window",
+    "window-block-edge", "unallocated-live-page", "int8", "bf16"]))
+def test_paged_decode_kernel_matches_ref(case, stacked):
     """The decode kernel (one program per slot, a loop over the slot's live
     blocks, every page read once for its whole GQA group) against the
-    masked-gather oracle."""
+    masked-gather oracle. Over a 3-layer stack at a traced layer it DMAs
+    that layer's pages out of the stack and gives bit for bit what it
+    gives over the layer's own pool."""
     from repro.kernels import paged_attention as pa
     q, pk, pv, bt, pos = _decode_case(case["H"], case["KV"], case["ps"],
                                       case["mps"], case["pos"],
                                       case.get("hole"))
     sc = {}
+    tol = 2e-5
     if case.get("int8"):
         pk, pv = (jnp.clip(jnp.round(x * 40), -127, 127).astype(jnp.int8)
                   for x in (pk, pv))
         scale = (1 + jnp.arange(pk.shape[0]) % 3) / 40.0
         sc = dict(k_scale=scale, v_scale=scale * 0.5)
+    if case.get("bf16"):
+        # the oracle is exact on the same bf16 inputs; both round the
+        # output to bf16 once
+        q, pk, pv = (x.astype(jnp.bfloat16) for x in (q, pk, pv))
+        tol = 1e-2
     assert pa.takes_decode_kernel(q, pk)
     w = case.get("window", 0)
-    kernel = jax.jit(pa.paged_decode_attention,
+    kernel = jax.jit(pa.paged_attention,
                      static_argnames=("window", "interpret"))
-    got = kernel(q, pk, pv, bt, pos, window=w, interpret=True, **sc)
+    # one layer's pools: a stack of one, as ops.paged_decode passes them
+    got = kernel(q, pk[None], pv[None], bt, pos, 0, window=w,
+                 interpret=True, **sc)
     want = ref.paged_attention(q, pk, pv, bt, pos, window=w, **sc)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=tol, atol=tol)
+    if stacked:
+        ks = jax.random.split(KEY, 2)
+        stack_k, stack_v = _in_stack(ks[0], pk), _in_stack(ks[1], pv)
+        assert pa.takes_decode_kernel(q, stack_k)
+        from_stack = kernel(q, stack_k, stack_v, bt, pos, jnp.int32(1),
+                            window=w, interpret=True, **sc)
+        np.testing.assert_array_equal(np.asarray(from_stack),
+                                      np.asarray(got))
 
 
 def test_paged_decode_skips_unallocated_and_future_pages():

@@ -10,12 +10,22 @@ of a slot's pages holds exactly the rows the dense cache would, in the same
 logical order, and masked rows contribute exactly 0 — so greedy argmax
 streams must match token-for-token at ANY page size, and bit-for-bit at the
 degenerate one.
+
+The serving steps hand each layer the layer-stacked page pools with the
+layer's index; a layer given one layer's pool instead (the probes, the VLM
+super-layer) computes the same thing, and the tests below pin that the two
+forms agree bit for bit, from one layer's write up to the engine's tokens.
 """
+import dataclasses
+
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro import configs
+from repro.models import layers as L
+from repro.models import transformer
 from repro.models.registry import (cache_capacity, extract_cache_slot,
                                    get_model, reduced_config)
 from repro.serve.engine import PageAllocator, ServeEngine
@@ -140,6 +150,127 @@ def test_paged_vlm_super_layer_equivalence():
     p = paged.submit(np.arange(1, 9, dtype=np.int32), 5)
     paged.run()
     assert d.tokens == p.tokens and len(p.tokens) == 5
+
+
+# ------------------------------------------------- layer-stacked pools
+def _layer_case(step: str, int8: bool):
+    """Attention params, inputs and one layer's pools for a GQA layer (8
+    query heads over 2 kv heads) reading two slots' pages."""
+    dims = L.AttnDims(d_model=32, num_heads=8, num_kv_heads=2, head_dim=16,
+                      qkv_bias=True)
+    ks = jax.random.split(jax.random.PRNGKey(3), 4)
+    P, ps = 10, 8
+    bt = jnp.asarray([[3, 5, -1, -1], [0, 7, 2, -1]], jnp.int32)
+    pools = [jax.random.normal(k, (P, ps, 2, 16)) for k in ks[:2]]
+    sc = {}
+    if int8:
+        pools = [jnp.clip(jnp.round(p * 40), -127, 127).astype(jnp.int8)
+                 for p in pools]
+        scale = ((1 + jnp.arange(P) % 3) / 40.0)[:, None]
+        sc = dict(k_scale=scale, v_scale=scale * 0.5)
+    else:
+        pools = [p.astype(jnp.bfloat16) for p in pools]
+    n = 1 if step == "decode" else 4
+    x = jax.random.normal(ks[2], (2, n, 32))
+    return L.attn_init(ks[3], dims), dims, x, pools, bt, sc
+
+
+def _layer_step(step, params, dims, x, pk, pv, bt, impl, layer, sc):
+    if step == "decode":
+        pos = jnp.asarray([10, 17], jnp.int32)
+        return L.attention_decode_paged(
+            params, x, dims, pk, pv, bt, pos, L.decode_positions(pos, 2),
+            impl=impl, layer=layer, **sc)
+    positions = 12 + jnp.broadcast_to(jnp.arange(4, dtype=jnp.int32), (2, 4))
+    return L.attention_prefill_chunk_paged(
+        params, x, dims, pk, pv, bt, positions, jnp.int32(0), impl=impl,
+        layer=layer, **sc)
+
+
+@pytest.mark.parametrize("impl", ["kernel", "einsum"])
+@pytest.mark.parametrize("pool", ["bf16", "int8"])
+@pytest.mark.parametrize("step", ["decode", "chunk"])
+def test_stacked_pool_step_writes_only_its_layer(step, pool, impl):
+    """A layer handed the 3-layer stack of pools at its (traced) index
+    computes what it computes handed its own pool as a stack of one: the
+    same output, the same rows (and int8 page scales) written into its
+    layer of the stack, and every other layer of the stack left as it
+    was."""
+    params, dims, x, (pk, pv), bt, sc = _layer_case(step, pool == "int8")
+    one = jax.jit(lambda pk, pv, **sc: _layer_step(
+        step, params, dims, x, pk[None], pv[None], bt, impl, 0, sc))(
+            pk, pv, **sc)
+    stacks = [jnp.stack([p[::-1], p, -p]) for p in (pk, pv)]
+    got = jax.jit(lambda sk, sv, i, **sc: _layer_step(
+        step, params, dims, x, sk, sv, bt, impl, i, sc))(
+            *stacks, jnp.int32(1), **sc)
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(one[0]))
+    for stack, new, mine in zip(stacks, got[1:3], one[1:3]):
+        np.testing.assert_array_equal(np.asarray(new[1]),
+                                      np.asarray(mine[0]))
+        assert not np.array_equal(np.asarray(new[1]), np.asarray(stack[1]))
+        for other in (0, 2):
+            np.testing.assert_array_equal(np.asarray(new[other]),
+                                          np.asarray(stack[other]))
+    for new_scale, mine in zip(got[3:], one[3:]):
+        np.testing.assert_array_equal(np.asarray(new_scale),
+                                      np.asarray(mine))
+
+
+@pytest.fixture(scope="module")
+def gqa():
+    cfg = reduced_config(configs.get_config("qwen2.5-32b"))
+    cfg = dataclasses.replace(cfg, num_heads=8, num_kv_heads=2)
+    model = get_model(cfg)
+    return model, model.init(jax.random.PRNGKey(0))
+
+
+@pytest.mark.parametrize("impl", ["kernel", "einsum"])
+@pytest.mark.parametrize("kv_backend", ["paged_fp32", "paged_int8"],
+                         ids=["bf16", "int8"])
+def test_stacked_pools_leave_greedy_tokens_unchanged(gqa, monkeypatch,
+                                                     kv_backend, impl):
+    """The paged engine's greedy tokens, and its pools at the end, are the
+    same whether each layer gets the layer-stacked pools (the served form)
+    or its own pool sliced out of the stack and written back (forced here
+    by giving the layer loop no pool leaves to pass whole, and handing
+    each layer's slice to the attention as a stack of one), for a GQA
+    model on bf16 and on int8 pages, prompts spanning several chunks."""
+    model, params = gqa
+
+    def own_pool(fn):
+        def call(params, x, dims, pk, pv, *rest, layer, **kw):
+            out = fn(params, x, dims, pk[None], pv[None], *rest, layer=0,
+                     **kw)
+            return (out[0], out[1][0], out[2][0]) + tuple(out[3:])
+        return call
+
+    def serve():
+        eng = ServeEngine(model, params, batch_slots=2, s_max=S_MAX,
+                          page_size=8, kv_backend=kv_backend,
+                          cache_dtype=jnp.bfloat16, paged_attn_impl=impl,
+                          prefill_chunk_tokens=8, prefix_cache=False)
+        rng = np.random.default_rng(11)
+        reqs = [eng.submit(rng.integers(0, model.cfg.vocab_size, n), g)
+                for n, g in ((19, 6), (8, 4), (12, 8))]
+        eng.run()
+        return [r.tokens for r in reqs], eng.cache
+
+    jax.clear_caches()
+    stacked_tokens, stacked_cache = serve()
+    monkeypatch.setattr(transformer, "_POOL_LEAVES", ())
+    for name in ("attention_decode_paged", "attention_prefill_chunk_paged"):
+        monkeypatch.setattr(L, name, own_pool(getattr(L, name)))
+    jax.clear_caches()
+    sliced_tokens, sliced_cache = serve()
+    jax.clear_caches()
+    assert stacked_tokens == sliced_tokens
+    assert all(len(t) == g for t, g in zip(stacked_tokens, (6, 4, 8)))
+    for key in ("k", "v", "k_scale", "v_scale"):
+        if key in stacked_cache:
+            np.testing.assert_array_equal(np.asarray(stacked_cache[key]),
+                                          np.asarray(sliced_cache[key]),
+                                          err_msg=key)
 
 
 def test_ssm_family_falls_back_to_dense():
